@@ -63,6 +63,9 @@ def main(argv: list[str] | None = None) -> int:
 
     from asyncrl_tpu.api.trainer import Trainer
     from asyncrl_tpu.cli.common import resolve_config
+    from asyncrl_tpu.utils import runtime
+
+    runtime.enable_compile_cache()
 
     cfg = resolve_config(args.preset, args.overrides, args.steps)
     if cfg.backend != "tpu":

@@ -45,10 +45,10 @@ def main(argv: list[str] | None = None) -> int:
     args = parser.parse_args(argv)
 
     from asyncrl_tpu.api.factory import make_agent
-    from asyncrl_tpu.cli.common import apply_platform_guard, resolve_config
+    from asyncrl_tpu.cli.common import prepare_runtime, resolve_config
 
     cfg = resolve_config(args.preset, args.overrides)
-    apply_platform_guard(cfg)
+    prepare_runtime(cfg)
 
     agent = make_agent(cfg, restore=args.restore)
     try:

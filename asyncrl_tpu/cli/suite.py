@@ -66,7 +66,7 @@ def main(argv: list[str] | None = None) -> int:
     args = parser.parse_args(argv)
 
     from asyncrl_tpu.api.factory import make_agent
-    from asyncrl_tpu.cli.common import apply_platform_guard, resolve_config
+    from asyncrl_tpu.cli.common import prepare_runtime, resolve_config
     from asyncrl_tpu.envs import registered
 
     games = args.games or ATARI_FAMILY
@@ -81,7 +81,7 @@ def main(argv: list[str] | None = None) -> int:
         return 2
 
     base = resolve_config(args.preset, args.overrides, args.steps)
-    apply_platform_guard(base)
+    prepare_runtime(base)
 
     from asyncrl_tpu.envs.registry import make as make_env
     from asyncrl_tpu.utils.metrics import JsonlSink

@@ -241,14 +241,13 @@ pong_selfplay = pong_impala.replace(
     entropy_coef=0.02,
 )
 
-# The 18.0-bar time-to-target recipe (BASELINE.json:2; the tuning history
-# is in BENCH_HISTORY.json: kind=diagnosis showed defense solved and every
+# The 18.0-bar time-to-target recipe (BASELINE.json:2; tuned from a
+# scripts/pong_diagnose.py run that showed defense solved and every
 # game truncation-capped at ~16.3 points scored, so the shaping targets
 # scoring RATE). step_cost=0.01 prices a 184-step point at ~-0.84 shaped
 # reward; gamma=0.995 keeps credit on the setup shots 2-3 court crossings
 # before a winner (0.99^100=0.37 vs 0.995^100=0.61); the entropy floor
-# 1e-4 sharpens late shot selection. Driven by scripts/run_to_target.py
-# via scripts/tpu_window.sh.
+# 1e-4 sharpens late shot selection. Driven by scripts/run_to_target.py.
 pong_t2t = pong_impala.replace(
     step_cost=0.01,
     gamma=0.995,
@@ -299,9 +298,8 @@ pong_t2t_ale = pong_t2t.replace(pong_max_steps=ALE_MAX_STEPS)
 # but reborn as a CURRICULUM phase: the CPU probe showed skip-4
 # training + skip-1 finish crosses the ALE bar at ~6x fewer core frames
 # than pure skip-1 (runs/pong18_skip4_cpu reached=true at 0.74B
-# decisions, confirmation 18.72), so the watcher's pong18_curr arm runs
-# one short skip-4 burst under this preset before finishing under
-# pong_t2t_ale.
+# decisions, confirmation 18.72): one short skip-4 burst under this
+# preset, then a finish under pong_t2t_ale.
 pong_t2t_ale4 = pong_t2t_ale.replace(
     frame_skip=4,
     gamma=0.98,
@@ -336,10 +334,10 @@ pong_t2t_ale4 = pong_t2t_ale.replace(
 # decisions (runs/pong18_tpu metrics.jsonl); pixel representation
 # learning (recovering the 6-dim state from 84x84x4) adds a factor we
 # bound at 1-3x => 18-54B decisions, i.e. ~110-330 chip-hours at the
-# measured 45,984 fps 1024-fit throughput. A multi-ROUND accumulation
-# arm (runs/pong18_pixels): each watcher window banks curve +
-# reached=false rows, and the MFU work (docs/MFU.md) is what shrinks the
-# wall-clock denominator.
+# 45,984 fps the 1024-fit geometry measured on the previous runtime. A
+# multi-session accumulation arm (runs/pong18_pixels): each session banks
+# curve + reached=false rows, and the MFU work (docs/MFU.md) is what
+# shrinks the wall-clock denominator.
 pong_pixels_t2t = pong_t2t.replace(
     env_id="JaxPongPixels-v0",
     torso="impala_cnn",

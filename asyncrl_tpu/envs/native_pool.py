@@ -6,13 +6,16 @@ per call behind a batched C ABI, feeding the Sebulba host path
 so Python actor threads overlap env stepping with device inference.
 
 The library auto-builds via ``make`` on first use (g++ is in the image;
-SURVEY.md §7.0) and is cached under ``native/build/``.
+SURVEY.md §7.0) into ``native/build/``, under a name keyed by what it was
+built from — see :func:`_lib_path`.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -22,14 +25,51 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native",
 )
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libenvpool.so")
 _BUILD_LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 
 
-def _build() -> None:
+def _host_fingerprint() -> str:
+    """What ``-march=native`` resolves against: the machine type and the
+    CPU's feature flags (first ``flags`` line of /proc/cpuinfo; empty
+    where that is unreadable)."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def _lib_path() -> str:
+    """Library path keyed by a hash of the source, the Makefile (the
+    flags) and the host CPU. The build is ``-march=native``, and a tree
+    copied between machines carries ``native/build/`` along: an artifact
+    from another source revision or another CPU must never be the one
+    loaded, which an mtime comparison cannot guarantee."""
+    h = hashlib.sha256()
+    for name in ("envpool.cc", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    h.update(_host_fingerprint().encode())
+    return os.path.join(
+        _NATIVE_DIR, "build", f"libenvpool-{h.hexdigest()[:16]}.so"
+    )
+
+
+def _build(lib_path: str) -> None:
+    """Compile to a private name, then rename: a concurrent process never
+    loads a half-written library."""
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     proc = subprocess.run(
-        ["make", "-C", _NATIVE_DIR],
+        [
+            "make", "-C", _NATIVE_DIR,
+            f"TARGET={os.path.relpath(tmp, _NATIVE_DIR)}",
+        ],
         capture_output=True,
         text=True,
     )
@@ -38,6 +78,7 @@ def _build() -> None:
             f"native env pool build failed (exit {proc.returncode}):\n"
             f"{proc.stdout}\n{proc.stderr}"
         )
+    os.replace(tmp, lib_path)
 
 
 def load_library() -> ctypes.CDLL:
@@ -45,16 +86,14 @@ def load_library() -> ctypes.CDLL:
     global _LIB
     if _LIB is not None:
         return _LIB
+    lib_path = _lib_path()
     with _BUILD_LOCK:
         if _LIB is not None:
             return _LIB
-        src = os.path.join(_NATIVE_DIR, "envpool.cc")
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
-        ):
+        if not os.path.exists(lib_path):
             # lint: blocking-under-lock-ok(serializing the one-time compiler run IS this lock's job: concurrent first callers must block until the .so exists)
-            _build()
-        lib = ctypes.CDLL(_LIB_PATH)
+            _build(lib_path)
+        lib = ctypes.CDLL(lib_path)
         lib.envpool_create.restype = ctypes.c_void_p
         lib.envpool_create.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_uint64,

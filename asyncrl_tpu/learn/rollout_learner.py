@@ -32,6 +32,7 @@ from asyncrl_tpu.learn.learner import (
     fused_smap_opts,
     make_optimizer,
     qlearn_bootstrap,
+    reduce_grads,
     resolve_scan_impl,
     validate_grad_accum_config,
     validate_qlearn_config,
@@ -59,7 +60,6 @@ from asyncrl_tpu.parallel.mesh import (
     axis_size,
     dp_axes,
     dp_size,
-    reduce_grads,
     shard_map,
 )
 from asyncrl_tpu.parallel.timeshard import (
@@ -427,7 +427,7 @@ class RolloutLearner:
                     grads, loss, metrics = accumulate_grads(
                         scaled_loss, state.params, rollout, n_accum
                     )
-                grads = reduce_grads(grads, reduce_axes, impl=config.grad_reduce)
+                grads = reduce_grads(grads, reduce_axes, config)
                 grad_norm = optax.global_norm(grads)
                 updates, opt_state = optimizer.update(
                     grads, state.opt_state, state.params

@@ -27,7 +27,7 @@ Three kernels share the math:
   block-feeds [T, block] tiles into VMEM and double-buffers across grid
   steps itself.
 - :func:`reverse_linear_scan_pallas_dma` — EXPLICIT DMA: inputs stay in
-  ``pltpu.ANY`` (compiler-placed/HBM) memory space and the kernel issues
+  ``pl.ANY`` (compiler-placed/HBM) memory space and the kernel issues
   its own ``pltpu.make_async_copy`` per tile against DMA semaphores
   (start → compute window → wait). Numerically identical to the
   automatic kernel; it exists as the beachhead for the ROADMAP item-2
@@ -66,6 +66,8 @@ from jax.experimental.pallas import tpu as pltpu
 # f32 tiling: sublane multiple of 8, lane multiple of 128.
 _SUBLANE = 8
 _LANE = 128
+# Scoped-VMEM limit the fused kernel declares to Mosaic (the v5e default).
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 def _scan_kernel(a_ref, b_ref, out_ref):
@@ -110,19 +112,13 @@ def mul_no_fma(x, y):
 
 
 def _out_struct(shape: tuple[int, ...], *arrays) -> jax.ShapeDtypeStruct:
-    """Output ShapeDtypeStruct, declaring varying-mesh-axes (vma) where
-    this jax tracks them. Under shard_map's vma semantics (jax >= 0.8,
-    ``jax.typeof``) the kernel output must declare which mesh axes it
-    varies over — exactly as its inputs do (the scan is pointwise in the
-    batch/shard axes). Older jax has neither ``jax.typeof`` nor the
-    ``vma=`` kwarg, so the declaration is skipped entirely there."""
-    typeof = getattr(jax, "typeof", None)
+    """Output ShapeDtypeStruct declaring the varying-mesh-axes (vma) of the
+    inputs: under a checked shard_map a kernel output must say which mesh
+    axes it varies over — exactly as its inputs do (the scan is pointwise
+    in the batch/shard axes). Empty outside shard_map."""
     vma: frozenset = frozenset()
-    if typeof is not None:
-        for x in arrays:
-            vma |= getattr(typeof(x), "vma", frozenset())
-    if not vma:
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
+    for x in arrays:
+        vma |= jax.typeof(x).vma
     return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
 
@@ -235,7 +231,7 @@ def reverse_linear_scan_pallas_dma(
 ) -> jax.Array:
     """The explicit-DMA twin of :func:`reverse_linear_scan_pallas`: same
     recurrence, same padding and VMEM sizing, but the kernel owns its
-    HBM↔VMEM transfers (``pltpu.ANY`` inputs, per-tile
+    HBM↔VMEM transfers (``pl.ANY`` inputs, per-tile
     ``make_async_copy`` + DMA semaphores). Bit-comparable to the
     automatic kernel on every geometry (tests/test_pallas_scan.py);
     ``scripts/validate_pallas_tpu.py`` judges both on a live chip."""
@@ -245,10 +241,10 @@ def reverse_linear_scan_pallas_dma(
         _scan_kernel_dma,
         grid=(B_pad // block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=_out_struct((T_pad, B_pad), a2, b2),
         scratch_shapes=[
             pltpu.VMEM((T_pad, block), jnp.float32),
@@ -325,7 +321,6 @@ def _fused_vtrace_kernel(
     # reference (a per-row formulation of the very same ops was observed
     # to FMA-contract differently and drift by ULPs).
     crho = crho_ref[...]
-    a = a_ref[...]
     rew = rew_ref[...]
     disc = disc_ref[...]
     val = val_ref[...]
@@ -338,12 +333,15 @@ def _fused_vtrace_kernel(
     #   x_t = delta_t + (d_t * cc_t) * x_{t+1}
     # One fused multiply-add per row, identical in structure to the
     # plain scan kernel (bit-pinned against the sequential lax scan).
+    # delta is staged through the adv output tile and read back a row at
+    # a time through the ref: Mosaic has no dynamic_slice of a VALUE at a
+    # traced row ("Unimplemented primitive in Pallas TPU lowering for
+    # KernelType.TC: dynamic_slice", jax 0.9.0), only of a ref.
+    adv_ref[...] = delta
+
     def body(i, x):
         t = block_t - 1 - i
-        x = (
-            jax.lax.dynamic_slice_in_dim(delta, t, 1, 0)
-            + jax.lax.dynamic_slice_in_dim(a, t, 1, 0) * x
-        )
+        x = adv_ref[pl.ds(t, 1), :] + a_ref[pl.ds(t, 1), :] * x
         adv_ref[pl.ds(t, 1), :] = x
         return x
 
@@ -425,11 +423,13 @@ def fused_vtrace_pallas(
     # Time is chunked (pipelined), batch is blocked (gridded). Chunk
     # count first, then the chunk length rounds up to the sublane grid —
     # keeps front-padding below 8 * n_chunks rows instead of up to a
-    # whole chunk. VMEM budget: 8 live tiles (5 in + 3 out) double-
-    # buffered by the pipeline = 16 tiles within ~8 MB of the ~16 MB.
+    # whole chunk. VMEM budget: 8 pipelined tiles (5 in + 3 out), double-
+    # buffered = 16, plus the kernel's own [bt, block] temporaries (vtp1,
+    # delta, vs, vstp1, pg) = 21 tiles within 3/4 of the limit declared
+    # to Mosaic below.
     n_chunks = max(1, -(-T // block_t))
     bt = _round_up(-(-T // n_chunks), _SUBLANE)
-    budget_elems = (8 * 1024 * 1024) // (16 * 4)
+    budget_elems = (3 * _VMEM_LIMIT_BYTES // 4) // (21 * 4)
     fit_b = max(_LANE, (budget_elems // bt) // _LANE * _LANE)
     block = min(block_b, fit_b, _round_up(B, _LANE))
     B_pad = _round_up(B, block)
@@ -464,6 +464,12 @@ def fused_vtrace_pallas(
             pltpu.VMEM((1, block), jnp.float32),
             pltpu.VMEM((1, block), jnp.float32),
         ],
+        # Batch blocks are independent; the time-chunk axis carries the
+        # recurrence through the scratch rows, so it must run in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
     )(*args)
 

@@ -17,7 +17,6 @@ from __future__ import annotations
 import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -25,116 +24,10 @@ DP_AXIS = "dp"  # data parallel: envs + batch sharded, grads all-reduced
 TP_AXIS = "tp"  # reserved: model-parallel axis for future large policies
 TIME_AXIS = "sp"  # reserved: time-axis (sequence) sharding, parallel/timeshard
 
-# ``jax.shard_map`` graduated out of jax.experimental only in newer jax
-# releases; THE import site for the whole framework lives here so every
-# learner/population/timeshard call works on both (keyword call convention
-# — f, mesh=, in_specs=, out_specs= — is identical across the two).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    # Older jax: the experimental namespace is the only spelling, and its
-    # static replication checker is weaker than the vma inference the
-    # bodies here were written against — it cannot see through an optax
-    # update chain that a new param tree derived from psum'd grads is still
-    # replicated, and rejects the P() out_specs. check_rep=True must stay
-    # on (it also enables the transpose rewrite that psums grads of
-    # replicated inputs — the gradient semantics every learner body relies
-    # on), so instead each output subtree whose spec leaves mesh axes
-    # unmentioned is passed through an identity collective (pmean for
-    # floats, pmax for ints/bools): a numeric no-op on genuinely
-    # replicated values that the checker CAN infer.
-    from jax.experimental.shard_map import shard_map as _experimental_smap
-
-    def _assert_replicated(x, axes):
-        if jnp.issubdtype(x.dtype, jnp.inexact):
-            return jax.lax.pmean(x, axes)
-        return jax.lax.pmax(x, axes)
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-        import jax.tree_util as jtu
-
-        if check_vma is False:
-            # The caller explicitly opted out of replication/vma checking
-            # (the Pallas interpreter under shard_map cannot infer vma —
-            # tests/test_pallas_scan.py). Forward the same opt-out; the
-            # identity-collective wrapping below exists only to SATISFY
-            # the checker, so it is skipped along with it.
-            # lint: sharding-ok(explicit check_vma=False forward: caller opted out; wrapping exists only to satisfy the checker being disabled)
-            return _experimental_smap(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
-            )
-
-        axis_names = tuple(mesh.axis_names)
-
-        def wrapped(*args):
-            out = f(*args)
-            spec_leaves, spec_def = jtu.tree_flatten(
-                out_specs, is_leaf=lambda s: isinstance(s, P)
-            )
-            subtrees = spec_def.flatten_up_to(out)
-            fixed = []
-            for spec, sub in zip(spec_leaves, subtrees):
-                named = set()
-                for entry in spec:
-                    if entry is None:
-                        continue
-                    if isinstance(entry, str):
-                        named.add(entry)
-                    else:
-                        named.update(entry)
-                missing = tuple(n for n in axis_names if n not in named)
-                if missing:
-                    sub = jax.tree.map(
-                        lambda x: _assert_replicated(jnp.asarray(x), missing),
-                        sub,
-                    )
-                fixed.append(sub)
-            return jtu.tree_unflatten(spec_def, fixed)
-
-        return _experimental_smap(
-            wrapped, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=True,
-        )
-
-
-def axis_size(axis_name):
-    """Mapped-axis size inside a ``shard_map`` body. ``jax.lax.axis_size``
-    on jax releases that have it; the ``psum(1, axis)`` idiom (which XLA
-    constant-folds) everywhere else. Accepts a name or tuple of names."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def reduce_grads(grads, axes, impl: str = "psum"):
-    """Cross-shard reduction for gradients of REPLICATED params computed by
-    ``jax.grad`` INSIDE a shard_map body. Under jax>=0.8 vma semantics the
-    transpose of the implicit replicated->varying broadcast psums those
-    cotangents automatically (the bodies scale their loss by 1/axis_size to
-    match); the pre-graduation shard_map does no such thing inside the body
-    — each shard would silently keep its LOCAL gradient — so this inserts
-    the reduction explicitly there. No-op on new jax (a second reduction
-    would double-count — which is also why ``impl`` cannot apply there;
-    ``resolve_scan_impl`` rejects ring on that path) and on an unsharded
-    mesh.
-
-    ``impl``: "psum"/"auto" — one compiler-scheduled all-reduce of the
-    whole tree; "ring" — the deterministic-order bidirectional ring
-    schedule over the flattened tree (``ops.ring_reduce``), which exposes
-    2(n-1) chunked neighbor transfers the scheduler can overlap with the
-    tail of the backward pass. Ring sums in a fixed order, so it is
-    run-to-run deterministic but differs from psum within the float
-    summation ULP bound (bit-equal at n=2)."""
-    if not axes or hasattr(jax, "shard_map"):
-        return grads
-    if impl in ("psum", "auto"):
-        return jax.tree.map(lambda g: jax.lax.psum(g, axes), grads)
-    if impl == "ring":
-        from asyncrl_tpu.ops.ring_reduce import ring_all_reduce_grads
-
-        return ring_all_reduce_grads(grads, axes)
-    raise ValueError(f"unknown grad_reduce impl {impl!r}; expected psum|ring")
+# THE import site of shard_map / axis_size for the whole framework (the
+# analyzer's sharding pass resolves call sites through these names).
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def make_mesh(

@@ -171,6 +171,8 @@ class DeviceRolloutQueue:
         # update's handle — the device-tier twin of the staging ring's
         # slab_reuse_waits signal (drain outran the learner).
         self.reuse_waits = 0
+        # Fragments handed to the drain through a lease, ever.
+        self.enqueued = 0
 
     @property
     def slots(self) -> int:
@@ -186,6 +188,7 @@ class DeviceRolloutQueue:
         self._slots[slot] = self._transfer(host_rollout)
         self._gen += 1
         self._slot_gen[slot] = self._gen
+        self.enqueued += 1
         lease = DeviceLease(self, slot, self._gen)
         self._out[slot] = lease
         return lease

@@ -24,7 +24,7 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from bench import cpu_fallback_or_refuse  # noqa: E402
+from asyncrl_tpu.utils import runtime  # noqa: E402
 
 # Single source of truth for the cap pair (ADVICE r4): the env constants,
 # not re-typed numbers — a cap change in envs/pong.py propagates here.
@@ -34,8 +34,6 @@ CAPS = (MAX_STEPS, ALE_MAX_STEPS)  # (repo default, ALE-faithful)
 
 
 def main() -> int:
-    import jax
-
     preset_name = "pong_t2t"
     run_dir = "runs/pong18_tpu"
     episodes = 32
@@ -55,9 +53,11 @@ def main() -> int:
         print(f"eval_caps: no run dir {run_dir!r}", file=sys.stderr)
         return 2
 
-    # CPU is valid evidence here: greedy eval of a fixed policy measures the
-    # POLICY, not the hardware; rows carry platform fields either way.
-    cpu_fallback_or_refuse(jax, "eval_caps")
+    # Greedy eval of a fixed policy measures the POLICY, not the hardware,
+    # so an explicit CPU run (ASYNCRL_FORCE_CPU=1) is valid evidence here;
+    # rows carry platform fields either way.
+    runtime.require_tpu("eval_caps")
+    runtime.enable_compile_cache()
 
     from asyncrl_tpu.api.factory import make_agent
     from asyncrl_tpu.configs import presets

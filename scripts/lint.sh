@@ -107,18 +107,17 @@ EOF
 #   tick, the probe/readmit typestate) — gated explicitly so a future
 #   baseline or package file-set edit can never silently un-gate it.
 # - kernels: the PR-17 device hot path contracts (Pallas DMA start/wait
-#   in the fused scan and RDMA ring, sharding hygiene in the ring's
-#   collectives, the devq-lease typestate in the HBM rollout queue),
-#   explicit for the same un-gating reason.
+#   in the scan kernels, the devq-lease typestate in the HBM rollout
+#   queue), explicit for the same un-gating reason.
 # - requests: the request hop journal's budget arithmetic (deadline flow
 #   into budget_remaining_ms, ms-vs-s unit soundness, the rate-token
 #   refund protocol its gateway call sites participate in) — gated
 #   explicitly so the wire-tracing layer can never silently drift out of
 #   the deadline/refund contract set.
 GATES=(
-    "scripts|configflow,sharding,hostsync,pallas,deadlines,refund,units,races|scripts/*.py bench.py __graft_entry__.py"
+    "scripts|configflow,sharding,hostsync,pallas,deadlines,refund,units,races|scripts/*.py bench.py chip_smoke.py __graft_entry__.py"
     "fleet|protocols,deadlock|asyncrl_tpu/serve/fleet.py"
-    "kernels|pallas,sharding,protocols|asyncrl_tpu/ops/pallas_scan.py asyncrl_tpu/ops/ring_reduce.py asyncrl_tpu/rollout/device_queue.py"
+    "kernels|pallas,sharding,protocols|asyncrl_tpu/ops/pallas_scan.py asyncrl_tpu/rollout/device_queue.py"
     "requests|deadlines,refund,units,protocols|asyncrl_tpu/obs/requests.py"
 )
 for gate in "${GATES[@]}"; do

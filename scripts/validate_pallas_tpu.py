@@ -1,11 +1,10 @@
 """Real-chip validation + microbench for the device hot path's Pallas
 kernels.
 
-``Config.scan_impl='auto'`` resolves to ``associative`` everywhere because
-the Pallas VMEM kernel had never run on actual TPU hardware (utils/config.py
-scan_impl note). This script is the validation gate: on a live chip it
-judges each kernel set against its contract and appends one
-``kind="kernel_validation"`` entry per set to BENCH_HISTORY.json:
+The validation gate for every hand-written kernel: on a live chip it
+judges each kernel set against its contract, prints one JSON line per
+geometry, and appends one ``kind="kernel_validation"`` entry per set to
+the local run log (utils/bench_history.py):
 
 - ``scan`` — ``reverse_linear_scan_pallas`` + its explicit-DMA twin
   (``pallas_dma`` — the ROADMAP item-2 beachhead whose start/wait
@@ -19,13 +18,8 @@ judges each kernel set against its contract and appends one
   same claim tests/test_differential.py pins through the interpreter,
   here on real silicon where the Mosaic compiler (not the interpreter)
   decides FMA contraction.
-- ``ring`` — the RDMA ring all-reduce (``ops/ring_reduce.py``) under a
-  ``check_vma=False`` shard_map: bit-identity vs the lax twin (same
-  schedule, same operand order), the (n-1)-step ULP envelope vs
-  ``psum`` (bit-identity at n=2), replication across devices. Skipped
-  (ok) on a single-device chip — there is no ring to run.
 
-    python scripts/validate_pallas_tpu.py [scan] [fused] [ring]
+    python scripts/validate_pallas_tpu.py [scan] [fused]
 
 No argv = all sets. Exit 0 = every selected set matched (safe to
 promote); exit 1 = mismatch (keep the lax defaults; the ledger entry
@@ -238,109 +232,9 @@ def validate_fused() -> bool:
     return ok
 
 
-def validate_ring() -> bool:
-    """RDMA ring vs lax twin (bit-identity) and psum (ULP envelope), on
-    the real ICI fabric."""
-    from asyncrl_tpu.ops import ring_reduce
-    from asyncrl_tpu.parallel.mesh import make_mesh, shard_map
-    from jax.sharding import PartitionSpec as P
-
-    devices = jax.devices()
-    n = len(devices)
-    if n < 2:
-        print(json.dumps({
-            "kernel": "ring", "ok": True, "skipped": f"{n} device(s)"
-        }))
-        return True
-    mesh = make_mesh((n,), ("dp",), devices=devices)
-
-    def all_reduce(fn, vals, checked):
-        def body(x):
-            return fn(x[0])[None]
-
-        # The pallas_call has no replication rule on jax 0.4.x, so the
-        # kernel (and, for schedule-timing parity, its lax twin) runs
-        # under the check_vma=False wrapper; psum keeps the checked path.
-        kw = {} if checked else {"check_vma": False}
-        return np.asarray(jax.jit(shard_map(
-            body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), **kw
-        ))(vals))
-
-    rng = np.random.default_rng(2)
-    results = []
-    ok = True
-    # Ragged small, lane-aligned mid, and the largest payload the
-    # kernel's VMEM scratch budget admits at this ring size (the
-    # gradient-tree regime: ops/ring_reduce.py _MAX_SUBLANES).
-    for d in (
-        1031,
-        2 * n * 64 * 128,
-        2 * n * ring_reduce._MAX_SUBLANES * 128,
-    ):
-        vals = rng.standard_normal((n, d)).astype(np.float32)
-        entry = {"n": n, "d": d}
-        try:
-            pal = all_reduce(
-                lambda x: ring_reduce.ring_all_reduce_pallas(
-                    x, "dp", axis_size=n
-                ),
-                vals, checked=False,
-            )
-            lax_twin = all_reduce(
-                lambda x: ring_reduce.ring_all_reduce_lax(
-                    x, "dp", axis_size=n
-                ),
-                vals, checked=False,
-            )
-            psum = all_reduce(lambda x: jax.lax.psum(x, "dp"), vals, True)
-        except Exception as e:  # noqa: BLE001 — record, don't crash
-            entry["error"] = str(e)[:300]
-            entry["match"] = False
-            ok = False
-            results.append(entry)
-            print(json.dumps(entry))
-            continue
-        # Twin contract: same schedule, same operand order -> same bits.
-        twin_ok = bool(np.array_equal(pal, lax_twin))
-        # Replication: every device ends with the same bits.
-        rep_ok = all(np.array_equal(pal[0], row) for row in pal[1:])
-        # psum envelope: condition-relative (n-1)-step float-fold bound
-        # (tests/test_ring_reduce.py rationale); bit-identical at n=2.
-        if n == 2:
-            psum_ok = bool(np.array_equal(pal, psum))
-            psum_err = 0.0 if psum_ok else float(
-                np.max(np.abs(pal - psum))
-            )
-        else:
-            cond = np.sum(np.abs(vals), axis=0)
-            psum_err = float(np.max(np.abs(pal - psum)[0] / cond))
-            psum_ok = psum_err < (n - 1) * np.finfo(np.float32).eps
-        match = twin_ok and rep_ok and psum_ok
-        entry.update({
-            "twin_bit_identical": twin_ok,
-            "replicated": rep_ok,
-            "psum_err": psum_err,
-            "match": match,
-        })
-        ok = ok and match
-        results.append(entry)
-        print(json.dumps(entry))
-
-    bench_history.record({
-        "kind": "kernel_validation",
-        "kernel": "ring_all_reduce_pallas",
-        **bench_history.device_entry(),
-        "ok": ok,
-        "geometries": results,
-    })
-    print(json.dumps({"kernel": "ring", "ok": ok, "n": len(results)}))
-    return ok
-
-
 KERNEL_SETS = {
     "scan": validate_scan,
     "fused": validate_fused,
-    "ring": validate_ring,
 }
 
 
@@ -358,6 +252,9 @@ def main() -> int:
         print("validate_pallas_tpu: no accelerator; refusing (the whole "
               "point is real-chip behaviour)", file=sys.stderr)
         return 2
+    from asyncrl_tpu.utils import runtime
+
+    runtime.enable_compile_cache()
     ok = True
     for name in selected:
         ok = KERNEL_SETS[name]() and ok

@@ -2,40 +2,23 @@
 its output shape and provenance labeling must not regress).
 
 The heavy measurement path is stubbed; these tests pin main()'s routing —
-driver mode vs explicit preset, the overrides refusal, and the CPU-fallback
-pixel rider's last-known-good attachment."""
+driver mode vs explicit preset, the overrides refusal, and that a failed
+leg fails the run. The device rule runs for real, under the explicit
+ASYNCRL_FORCE_CPU=1 opt-in."""
 
 import json
 
 import pytest
 
 
-def _write_ledger(tmp_path, rows):
-    p = tmp_path / "ledger.json"
-    p.write_text(json.dumps(rows))
-    return str(p)
-
-
-TPU_PIXEL_ROW = {
-    "ts": "2026-07-31T04:00:00Z",
-    "captured_by": "harness",
-    "kind": "throughput",
-    "preset": "atari_impala",
-    "platform": "tpu",
-    "device_kind": "TPU v5 lite",
-    "device_count": 1,
-    "num_envs": 256,
-    "unroll_len": 32,
-    "updates_per_call": 8,
-    "frames_per_sec": 72480,
-    "vs_baseline": 0.072,
-}
+@pytest.fixture(autouse=True)
+def _explicit_cpu(monkeypatch):
+    monkeypatch.setenv("ASYNCRL_FORCE_CPU", "1")
 
 
 def test_driver_mode_refuses_overrides(monkeypatch):
     import bench
 
-    monkeypatch.setattr(bench, "cpu_fallback_or_refuse", lambda *a, **k: True)
     monkeypatch.setattr("sys.argv", ["bench.py", "num_envs=4096"])
     with pytest.raises(SystemExit) as e:
         bench.main()
@@ -46,7 +29,6 @@ def test_explicit_preset_passes_overrides(monkeypatch, capsys):
     import bench
 
     calls = []
-    monkeypatch.setattr(bench, "cpu_fallback_or_refuse", lambda *a, **k: True)
     monkeypatch.setattr(
         bench,
         "measure_preset",
@@ -66,7 +48,6 @@ def test_fused_ab_mode_routes_with_overrides(monkeypatch, capsys):
     import bench
 
     calls = []
-    monkeypatch.setattr(bench, "cpu_fallback_or_refuse", lambda *a, **k: True)
     monkeypatch.setattr(
         bench,
         "measure_fused_ab",
@@ -80,34 +61,37 @@ def test_fused_ab_mode_routes_with_overrides(monkeypatch, capsys):
     assert out["metric"] == "fused_ab"
 
 
-def test_driver_mode_cpu_attaches_pixel_lkg(monkeypatch, capsys, tmp_path):
-    """On the CPU fallback, driver mode must NOT burn minutes on a fresh
-    pixel CNN run: the pixel rider carries the newest committed TPU row
-    with a single 'not measured' label (no contradictory double label)
-    and a null value."""
+def test_driver_mode_measures_both_flagships_or_fails(monkeypatch, capsys):
+    """Driver mode measures the vector headline AND the pixel rider, on
+    whatever platform the device rule admitted; a pixel leg that fails —
+    a refusal exit included — fails the run and prints nothing."""
     import bench
-
-    ledger = _write_ledger(tmp_path, [TPU_PIXEL_ROW])
-    # The env var is the redirect mechanism and takes precedence over the
-    # module attribute — patch the var itself, or an operator with
-    # ASYNCRL_BENCH_HISTORY exported would have this test read theirs.
-    monkeypatch.setenv("ASYNCRL_BENCH_HISTORY", ledger)
-    monkeypatch.setattr(bench, "cpu_fallback_or_refuse", lambda *a, **k: True)
 
     measured = []
 
     def fake_measure(name, ov):
-        measured.append(name)
+        measured.append((name, ov))
         return {"metric": name, "value": 123, "unit": "frames/sec"}
 
     monkeypatch.setattr(bench, "measure_preset", fake_measure)
     monkeypatch.setattr("sys.argv", ["bench.py"])
     bench.main()
-
-    assert measured == ["pong_impala"]  # pixel NOT freshly measured on CPU
+    assert measured == [
+        ("pong_impala", []),
+        ("atari_impala", ["updates_per_call=8", "num_envs=256"]),
+    ]
     out = json.loads(capsys.readouterr().out.strip())
-    pixel = out["pixel_flagship"]
-    assert pixel["value"] is None
-    assert pixel["metric"].count("[") == 1  # one label, not two
-    assert pixel["last_known_good"]["frames_per_sec"] == 72480
-    assert pixel["last_known_good"]["captured_by"] == "harness"
+    assert out["value"] == 123 and out["pixel_flagship"]["value"] == 123
+    # Nothing rides along that this run did not measure.
+    assert set(out) == {"metric", "value", "unit", "pixel_flagship"}
+
+    def pixel_refuses(name, ov):
+        if name == "atari_impala":
+            raise SystemExit(1)
+        return {"metric": name, "value": 123, "unit": "frames/sec"}
+
+    monkeypatch.setattr(bench, "measure_preset", pixel_refuses)
+    with pytest.raises(SystemExit) as e:
+        bench.main()
+    assert e.value.code == 1
+    assert capsys.readouterr().out == ""

@@ -241,19 +241,16 @@ def test_fused_losses_bit_identical_through_loss_layer():
 
 
 def test_fused_learner_trains_and_matches_lax_sequential():
-    """The full Anakin learner with a fused kernel in the loss tail: the
-    step must TRACE under shard_map (jax 0.4.x has no pallas_call
-    replication rule — fused configs opt out via fused_smap_opts) and
-    walk a bit-identical loss trajectory to the sequential lax path.
+    """The full Anakin learner with a fused kernel in the loss tail, on
+    the 8-device mesh: the step must trace under shard_map and walk a
+    bit-identical loss trajectory to the sequential lax path.
 
-    The reference arm pins smap_check="off" so both arms compile the
-    SAME (unchecked) shard_map wrapper: the replication checker's
-    identity collectives move XLA fusion boundaries, and with the
-    checked wrapper the lax arm's trajectory drifts a final ULP from
-    the fused arm's within a few updates on the 8-device test mesh —
-    wrapper compilation noise, not kernel numerics. With the wrapper
-    held fixed the only varying ingredient is the kernel, and the
-    trajectories must be bit-equal."""
+    The two arms compile DIFFERENT wrappers — the interpreter arm runs
+    unchecked with an explicit gradient psum (learn/learner.py
+    fused_smap_opts / reduce_grads), the lax arm checked with the
+    implicit one — so equality here also pins that the explicit sum
+    reproduces the implicit one (an unreduced gradient diverges from the
+    reference at the second update)."""
     from asyncrl_tpu.api.trainer import Trainer
     from asyncrl_tpu.utils.config import Config
 
@@ -270,6 +267,6 @@ def test_fused_learner_trains_and_matches_lax_sequential():
             t.close()
 
     fused = losses(fused_scan="interpret")
-    ref = losses(fused_scan="lax", scan_impl="sequential", smap_check="off")
+    ref = losses(fused_scan="lax", scan_impl="sequential")
     assert fused and np.all(np.isfinite(fused))
     assert fused == ref

@@ -15,7 +15,6 @@ from asyncrl_tpu.parallel.mesh import (
     DP_AXIS,
     axis_size,
     make_mesh,
-    reduce_grads,
     shard_map,
 )
 from asyncrl_tpu.rollout.buffer import Rollout
@@ -52,15 +51,13 @@ def test_sharded_grads_equal_full_batch_grads(algo, devices):
 
     def sharded_grad(p, r):
         # Same pattern as the learner: scale the per-shard loss by
-        # 1/axis_size; on new jax shard_map's transpose auto-psums grads of
+        # 1/axis_size; the checked shard_map's transpose psums grads of
         # the replicated params (no explicit pmean — that would
-        # double-reduce), and reduce_grads inserts the equivalent psum on
-        # jax versions whose in-body transpose doesn't.
-        g = jax.grad(
+        # double-reduce).
+        return jax.grad(
             lambda q: _algo_loss(cfg, model.apply, q, r, axis_name=DP_AXIS)[0]
             / axis_size(DP_AXIS)
         )(p)
-        return reduce_grads(g, DP_AXIS)
 
     ro_spec = Rollout(
         obs=P(None, DP_AXIS), actions=P(None, DP_AXIS),
@@ -103,6 +100,30 @@ def test_learner_updates_on_8_device_mesh(algo, devices):
         for a, b in zip(jax.tree.leaves(p0), jax.tree.leaves(p1))
     )
     assert changed, "params did not move after 3 updates"
+
+
+@pytest.mark.parametrize("algo", ["a3c", "impala"])
+def test_donated_state_steps_and_matches_undonated(algo, devices):
+    """config.donate_buffers: a donated TrainState may not name one buffer
+    twice (init_state once aliased params/actor_params — "Attempt to
+    donate the same buffer twice in Execute()" on CPU and TPU alike), and
+    donation changes where results land, never what they are."""
+    losses = {}
+    for donate in (True, False):
+        cfg = Config(
+            algo=algo, num_envs=16, unroll_len=4, precision="f32",
+            actor_staleness=2 if algo == "impala" else 1,
+            donate_buffers=donate,
+        )
+        env = CartPole()
+        learner = Learner(cfg, env, build_model(cfg, env.spec), make_mesh())
+        state = learner.init_state(0)
+        out = []
+        for _ in range(3):
+            state, metrics = learner.update(state)
+            out.append(float(metrics["loss"]))
+        losses[donate] = out
+    assert losses[True] == losses[False]
 
 
 def test_learner_deterministic(devices):

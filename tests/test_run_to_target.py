@@ -57,7 +57,6 @@ def _run_protocol(monkeypatch, tmp_path, fake, argv_tail=()):
     ledger = tmp_path / "ledger.json"
     monkeypatch.setenv("ASYNCRL_BENCH_HISTORY", str(ledger))
     monkeypatch.setenv("ASYNCRL_FORCE_CPU", "1")
-    monkeypatch.delenv("BENCH_REQUIRE_ACCELERATOR", raising=False)
     import asyncrl_tpu.api.factory as factory
 
     monkeypatch.setattr(factory, "make_agent", lambda cfg: fake)

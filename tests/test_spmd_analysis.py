@@ -8,7 +8,8 @@ pattern to the three new pass families:
   trips SHD001-SHD004, HSY001-HSY003, and PAL001-PAL004;
 - the passes detect what they guard, ON THE LIVE TREE: renaming a mesh
   axis in parallel/mesh.py onto an existing one (in memory) trips
-  SHD002, flipping its check_rep trips SHD004, wrapping timeshard's
+  SHD002, flipping a checked shard_map's check_rep (a fixture copy of
+  the compat wrapper mesh.py once carried) trips SHD004, wrapping timeshard's
   all_gather in a process_index branch trips HSY001, and deleting a
   ``wait()`` from the explicit-DMA kernel in ops/pallas_scan.py trips
   PAL001 — exactly the pod-hang bug families the multi-host and kernel
@@ -45,6 +46,9 @@ PACKAGE = os.path.dirname(os.path.abspath(asyncrl_tpu.__file__))
 FIXTURES = os.path.join(REPO, "tests", "fixtures", "analysis")
 
 MESH = os.path.join(PACKAGE, "parallel", "mesh.py")
+# The checked compat shard_map wrapper mesh.py once carried, kept as a
+# fixture so its two mutation proofs outlive the wrapper.
+COMPAT_SMAP = os.path.join(FIXTURES, "good_compat_shard_map.py")
 TIMESHARD = os.path.join(PACKAGE, "parallel", "timeshard.py")
 PALLAS_SCAN = os.path.join(PACKAGE, "ops", "pallas_scan.py")
 
@@ -129,10 +133,17 @@ def test_renaming_a_mesh_axis_trips_shd002():
 
 
 def test_flipping_check_rep_trips_shd004():
-    # The comma-suffixed needle targets the CODE kwarg, not the comment
-    # above it that quotes "check_rep=True" in prose.
-    mutated = _mutated(MESH, "check_rep=True,", "check_rep=False,")
-    findings = _check_single(MESH, mutated, ("sharding",))
+    assert not _check_single(
+        COMPAT_SMAP, open(COMPAT_SMAP).read(), ("sharding",)
+    )
+    # The comma-suffixed needle targets the CODE kwarg, not the docstring
+    # above it that quotes "check_rep=True," in prose.
+    src = open(COMPAT_SMAP).read()
+    head, sep, tail = src.rpartition("check_rep=True,")
+    assert sep, "needle not found"
+    findings = _check_single(
+        COMPAT_SMAP, head + "check_rep=False," + tail, ("sharding",)
+    )
     assert any(f.code == "SHD004" for f in findings), (
         "\n".join(f.render() for f in findings)
     )
@@ -140,13 +151,13 @@ def test_flipping_check_rep_trips_shd004():
 
 def test_stripping_the_check_vma_waiver_resurfaces_shd004():
     """The compat shard_map's explicit check_vma=False forward carries
-    the one live sharding-ok waiver; it is load-bearing."""
+    a sharding-ok waiver; it is load-bearing."""
     src = "\n".join(
         line
-        for line in open(MESH).read().split("\n")
+        for line in open(COMPAT_SMAP).read().split("\n")
         if "lint: sharding-ok" not in line
     )
-    findings = _check_single(MESH, src, ("sharding",))
+    findings = _check_single(COMPAT_SMAP, src, ("sharding",))
     assert any(f.code == "SHD004" for f in findings), (
         "\n".join(f.render() for f in findings)
     )
