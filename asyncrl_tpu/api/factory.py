@@ -7,6 +7,8 @@ is the thread-based parity path mirroring the reference's default A3C mode.
 
 from __future__ import annotations
 
+from asyncrl_tpu.obs import introspect
+from asyncrl_tpu.obs import spans as span_names
 from asyncrl_tpu.utils.config import Config
 
 
@@ -39,6 +41,13 @@ def make_agent(
     if config.core not in ("ff", "lstm"):
         raise ValueError(f"unknown core {config.core!r}; expected ff|lstm")
 
+    # The process record's ``setup.agent``: what the program owns of a
+    # process's set-up. The trainers mark its parts themselves.
+    with introspect.phase(span_names.SETUP_AGENT):
+        return _build(config, restore)
+
+
+def _build(config: Config, restore: str | None):
     if config.backend == "tpu":
         from asyncrl_tpu.api.trainer import Trainer
 

@@ -127,18 +127,24 @@ class SebulbaTrainer:
         self._envs_per_actor = config.num_envs // config.actor_threads
 
         # Spec comes from a probe pool (host envs are authoritative here).
-        probe = make_host_pool(config, 1, seed=config.seed)
-        self.spec = spec if spec is not None else _pool_spec(probe, config)
-        _close(probe)
+        with introspect.phase(span_names.SETUP_ENV):
+            probe = make_host_pool(config, 1, seed=config.seed)
+            self.spec = (
+                spec if spec is not None else _pool_spec(probe, config)
+            )
+            _close(probe)
 
-        self.model = (
-            model if model is not None else build_model(config, self.spec)
-        )
-        self.mesh = (
-            mesh
-            if mesh is not None
-            else make_mesh(config.mesh_shape, config.mesh_axes)
-        )
+        with introspect.phase(span_names.SETUP_MODEL):
+            self.model = (
+                model if model is not None
+                else build_model(config, self.spec)
+            )
+        with introspect.phase(span_names.SETUP_MESH):
+            self.mesh = (
+                mesh
+                if mesh is not None
+                else make_mesh(config.mesh_shape, config.mesh_axes)
+            )
 
         # Eager geometry validation, mirroring the Anakin Learner: fail at
         # construction, not with a cryptic sharding error mid-train after
@@ -170,18 +176,25 @@ class SebulbaTrainer:
             unroll=config.unroll_len // sp,
             recurrent=is_recurrent(self.model),
         )
-        self.learner = RolloutLearner(config, self.spec, self.model, self.mesh)
-        self.state: LearnerState = self.learner.init_state(config.seed)
+        with introspect.phase(span_names.SETUP_LEARNER):
+            self.learner = RolloutLearner(
+                config, self.spec, self.model, self.mesh
+            )
+        with introspect.phase(span_names.SETUP_INIT_STATE):
+            self.state: LearnerState = self.learner.init_state(config.seed)
         self.env_steps = 0
 
         # Checkpoint/resume (SURVEY.md §5.4): learner-side state only — host
         # env states are transient by design (actors restart from fresh envs
         # on resume, exactly as after a §5.3 actor restart).
-        from asyncrl_tpu.utils import checkpoint
+        with introspect.phase(span_names.SETUP_CHECKPOINT):
+            # orbax is imported here, and is most of this phase in a
+            # process that neither restores nor saves.
+            from asyncrl_tpu.utils import checkpoint
 
-        self._ckpt, self.state, self.env_steps = checkpoint.setup(
-            config, restore, self.state
-        )
+            self._ckpt, self.state, self.env_steps = checkpoint.setup(
+                config, restore, self.state
+            )
         self.checkpointer = self._ckpt.checkpointer
 
         self._inference_fn = make_inference_fn(self.model, self.spec, config)

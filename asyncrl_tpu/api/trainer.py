@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from asyncrl_tpu import obs
 from asyncrl_tpu.envs import registry
 from asyncrl_tpu.learn.learner import (
     Learner,
@@ -24,6 +25,8 @@ from asyncrl_tpu.learn.learner import (
     validate_train_target,
 )
 from asyncrl_tpu.models.networks import build_model, is_recurrent, reset_core
+from asyncrl_tpu.obs import introspect, trace
+from asyncrl_tpu.obs import spans as span_names
 from asyncrl_tpu.ops.normalize import normalizing_apply
 from asyncrl_tpu.parallel.mesh import make_mesh
 from asyncrl_tpu.utils.config import Config, default_eval_max_steps
@@ -94,35 +97,47 @@ class Trainer:
     def __init__(
         self, config: Config, env=None, model=None, mesh=None, restore=None
     ):
+        # Observability first (asyncrl_tpu/obs/): config.trace /
+        # ASYNCRL_TRACE arm span tracing here as they do on Sebulba, so
+        # the set-up phases below are already spans of THIS agent's rings.
+        self._obs = obs.setup(config)
         # Resolve the ASYNCRL_INTROSPECT override once (env wins over
         # config.introspect, the ASYNCRL_TRACE precedence): the jitted
         # loss aux reads the RESOLVED flag at trace time, never the env.
-        from asyncrl_tpu.obs import introspect
-
         if introspect.enabled(config) != config.introspect:
             config = config.replace(introspect=introspect.enabled(config))
         self.config = config
-        self.env = (
-            env if env is not None else registry.make(config.env_id, config)
-        )
-        self.model = (
-            model if model is not None else build_model(config, self.env.spec)
-        )
-        self.mesh = (
-            mesh
-            if mesh is not None
-            else make_mesh(config.mesh_shape, config.mesh_axes)
-        )
-        self.learner = Learner(config, self.env, self.model, self.mesh)
-        self.state: TrainState = self.learner.init_state(config.seed)
+        with introspect.phase(span_names.SETUP_ENV):
+            self.env = (
+                env if env is not None
+                else registry.make(config.env_id, config)
+            )
+        with introspect.phase(span_names.SETUP_MODEL):
+            self.model = (
+                model if model is not None
+                else build_model(config, self.env.spec)
+            )
+        with introspect.phase(span_names.SETUP_MESH):
+            self.mesh = (
+                mesh
+                if mesh is not None
+                else make_mesh(config.mesh_shape, config.mesh_axes)
+            )
+        with introspect.phase(span_names.SETUP_LEARNER):
+            self.learner = Learner(config, self.env, self.model, self.mesh)
+        with introspect.phase(span_names.SETUP_INIT_STATE):
+            self.state: TrainState = self.learner.init_state(config.seed)
         self.env_steps = 0
         self._eval_fns: dict[tuple[int, int], Callable] = {}
 
-        from asyncrl_tpu.utils import checkpoint
+        with introspect.phase(span_names.SETUP_CHECKPOINT):
+            # orbax is imported here, and is most of this phase in a
+            # process that neither restores nor saves.
+            from asyncrl_tpu.utils import checkpoint
 
-        self._ckpt, self.state, self.env_steps = checkpoint.setup(
-            config, restore, self.state
-        )
+            self._ckpt, self.state, self.env_steps = checkpoint.setup(
+                config, restore, self.state
+            )
         self.checkpointer = self._ckpt.checkpointer
 
     def save_checkpoint(self) -> None:
@@ -130,8 +145,11 @@ class Trainer:
         self._ckpt.save_now(self.state, self.env_steps)
 
     def close(self) -> None:
-        """Flush pending async checkpoint saves and release resources."""
+        """Flush pending async checkpoint saves, export the trace (when
+        tracing is on) and release resources."""
         self._ckpt.close()
+        self._obs.export_trace()
+        self._obs.shutdown()
 
     # ------------------------------------------------------------------ train
 
@@ -168,7 +186,8 @@ class Trainer:
                 self._ckpt.after_update(self.state, self.env_steps)
 
                 if len(pending) >= cfg.log_every or self.env_steps >= target:
-                    drained = jax.device_get(pending)
+                    with trace.span(span_names.LEARNER_METRICS):
+                        drained = jax.device_get(pending)
                     pending = []
                     elapsed = time.perf_counter() - window_start
                     window_start = time.perf_counter()
@@ -207,9 +226,10 @@ class Trainer:
                         and calls - calls_at_eval >= cfg.eval_every
                     ):
                         calls_at_eval = calls
-                        agg["eval_return"] = self.evaluate(
-                            num_episodes=cfg.eval_episodes
-                        )
+                        with trace.span(span_names.LEARNER_EVAL):
+                            agg["eval_return"] = self.evaluate(
+                                num_episodes=cfg.eval_episodes
+                            )
                         self._ckpt.maybe_save_best(
                             self.state, self.env_steps, agg["eval_return"]
                         )

@@ -77,7 +77,8 @@ class FrameStackPixels(Environment):
 
     def init(self, key: jax.Array) -> PixelState:
         core = self._core.init(key)
-        frame = self._render(self._game(core))
+        with jax.named_scope("render"):
+            frame = self._render(self._game(core))
         return PixelState(
             core=core, frames=jnp.repeat(frame[..., None], 4, axis=-1)
         )
@@ -94,17 +95,21 @@ class FrameStackPixels(Environment):
             new_core, ts, prev_core = frame_skip_scan(
                 self._core, state.core, action, key, self._skip
             )
-            frame = self._render(self._game(new_core))
+            with jax.named_scope("render"):
+                frame = self._render(self._game(new_core))
             if self._pool:
                 # ALE 2-frame max pool over the window's last two raw
                 # frames. On an auto-reset boundary new_core is already the
                 # fresh episode — skip pooling there (the done branch below
                 # rebuilds the stack from the fresh frame anyway).
-                pooled = jnp.maximum(frame, self._render(self._game(prev_core)))
+                with jax.named_scope("render"):
+                    prev_frame = self._render(self._game(prev_core))
+                pooled = jnp.maximum(frame, prev_frame)
                 frame = jnp.where(ts.done, frame, pooled)
         else:
             new_core, ts = self._core.step(state.core, action, key)
-            frame = self._render(self._game(new_core))
+            with jax.named_scope("render"):
+                frame = self._render(self._game(new_core))
         shifted = jnp.concatenate(
             [state.frames[..., 1:], frame[..., None]], axis=-1
         )
@@ -113,7 +118,8 @@ class FrameStackPixels(Environment):
         frames = jnp.where(
             ts.done, jnp.repeat(frame[..., None], 4, axis=-1), shifted
         )
-        last_frame = self._render_last(ts.last_obs)
+        with jax.named_scope("render"):
+            last_frame = self._render_last(ts.last_obs)
         last_frames = jnp.concatenate(
             [state.frames[..., 1:], last_frame[..., None]], axis=-1
         )

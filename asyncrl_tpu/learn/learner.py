@@ -29,6 +29,8 @@ from asyncrl_tpu.ops.normalize import (
     update_stats,
 )
 from asyncrl_tpu.models.networks import is_recurrent, reset_core
+from asyncrl_tpu.obs import introspect, trace
+from asyncrl_tpu.obs import spans as span_names
 from asyncrl_tpu.ops.losses import (
     a3c_loss,
     impala_loss,
@@ -1072,6 +1074,7 @@ class Learner:
             ),
             donate_argnums=(0,) if config.donate_buffers else (),
         )
+        self._updated = False
 
     def init_state(self, seed: int) -> TrainState:
         """Build the initial TrainState with proper shardings."""
@@ -1142,5 +1145,15 @@ class Learner:
 
     def update(self, state: TrainState):
         """One train step: rollout + loss + pmean(grads) + Adam. Donates
-        ``state``."""
-        return self._step(state)
+        ``state``. The span covers the dispatch; the first call, which
+        traces, lowers and compiles (or loads) the step, is also the
+        process record's ``setup.first_update``."""
+        if self._updated:
+            with trace.span(span_names.LEARNER_UPDATE):
+                return self._step(state)
+        else:
+            self._updated = True
+            with introspect.phase(span_names.SETUP_FIRST_UPDATE), trace.span(
+                span_names.LEARNER_UPDATE
+            ):
+                return self._step(state)

@@ -557,6 +557,7 @@ class RolloutLearner:
             disc_returns=0.0 if config.normalize_returns else None,
         )
         self._rollout_sharding = rollout_sharding(mesh, template, stacked=K > 1)
+        self._updated = False
 
     # ---------------------------------------------------------------- state
 
@@ -611,6 +612,15 @@ class RolloutLearner:
     def update(self, state: LearnerState, rollout: Rollout):
         """One gradient step on a device-resident fragment. The span
         covers the jitted dispatch (plus, on the CPU backend where
-        dispatch is effectively synchronous, the compute itself)."""
-        with trace.span(span_names.LEARNER_UPDATE):
-            return self._step(state, rollout)
+        dispatch is effectively synchronous, the compute itself); the
+        first call, which traces, lowers and compiles (or loads) the step,
+        is also the process record's ``setup.first_update``."""
+        if self._updated:
+            with trace.span(span_names.LEARNER_UPDATE):
+                return self._step(state, rollout)
+        else:
+            self._updated = True
+            with introspect.phase(span_names.SETUP_FIRST_UPDATE), trace.span(
+                span_names.LEARNER_UPDATE
+            ):
+                return self._step(state, rollout)

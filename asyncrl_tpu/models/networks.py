@@ -87,12 +87,20 @@ class ImpalaCNN(nn.Module):
         # silently forking the checkpoint format).
         block = nn.remat(ResidualBlock) if self.remat else ResidualBlock
         for i, ch in enumerate(self.channels):
-            x = nn.Conv(ch, (3, 3), dtype=self.compute_dtype)(x)
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
-            x = block(ch, self.compute_dtype, name=f"ResidualBlock_{2 * i}")(x)
-            x = block(
-                ch, self.compute_dtype, name=f"ResidualBlock_{2 * i + 1}"
-            )(x)
+            # jax name scopes, for a profile's per-section device time; not
+            # flax scopes, so the param paths (and checkpoints) do not move.
+            with jax.named_scope(f"section{i}"):
+                x = nn.Conv(ch, (3, 3), dtype=self.compute_dtype)(x)
+                with jax.named_scope("max_pool"):
+                    x = nn.max_pool(
+                        x, (3, 3), strides=(2, 2), padding="SAME"
+                    )
+                x = block(
+                    ch, self.compute_dtype, name=f"ResidualBlock_{2 * i}"
+                )(x)
+                x = block(
+                    ch, self.compute_dtype, name=f"ResidualBlock_{2 * i + 1}"
+                )(x)
         x = nn.relu(x)
         x = x.reshape(*x.shape[:-3], -1)
         x = nn.relu(nn.Dense(256, dtype=self.compute_dtype, kernel_init=ORTHO(jnp.sqrt(2)))(x))

@@ -40,6 +40,14 @@ explained-variance as loss metrics when ``config.introspect`` is on):
   monotone ``mem_host_rss_peak_bytes`` watermark) — published as registry
   gauges so every window sink and ``/metrics`` carry them.
 
+- :func:`phase` / :func:`process_record` — the always-on record of the
+  PROCESS, not of an agent: the set-up phases it went through
+  (``setup.*``, a dozen a process) and every program JAX traced, lowered,
+  compiled or loaded from the persistent cache, as JAX itself reports them
+  (one ``jax.monitoring`` listener, which runs only when JAX builds a
+  program). ``obs.setup`` does not clear it. On ``time.perf_counter()``,
+  the clock of the span rings.
+
 Arming: ``config.introspect`` (default on), with ``ASYNCRL_INTROSPECT``
 winning when set — the no-code-change A/B knob, the ``ASYNCRL_TRACE``
 precedence. ``scripts/introspect_smoke.sh`` is the on/off A/B gate
@@ -48,6 +56,7 @@ precedence. ``scripts/introspect_smoke.sh`` is the on/off A/B gate
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -58,6 +67,7 @@ from typing import Any, Callable, Iterable
 import numpy as np
 
 from asyncrl_tpu.obs import registry, trace
+from asyncrl_tpu.obs import spans as span_names
 
 ENV_VAR = "ASYNCRL_INTROSPECT"
 _FALSEY = ("", "0", "false", "no")
@@ -161,10 +171,110 @@ def reset() -> None:
     """Drop pending compile events AND the host-RSS peak watermark (a
     fresh trainer's obs setup — a new agent must never persist a
     predecessor's compiles, nor report a peak its own run never
-    reached, into its run_dir)."""
+    reached, into its run_dir). The process record stays: it is the
+    process's, not an agent's."""
     global _RSS_PEAK
     _LOG.reset()
     _RSS_PEAK = 0.0
+
+
+# ---------------------------------------------------------- process record
+
+# Entries kept of each kind (drop-oldest, counted). JAX reports a trace of
+# every function traced inside another's: one benchmark process of the
+# ``atari_impala`` cell (an agent, its K=8 step, the reference's loss)
+# reports ~3,500 events, nine tenths of them nested traces, and what is
+# read last is the oldest (the programs of ``make_agent``). So four times
+# that; the cap is for a process that never stops building programs.
+PROCESS_RECORD_CAP = 16384
+
+# jax.monitoring duration event -> the span it becomes under an armed tracer.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": span_names.COMPILE_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": span_names.COMPILE_LOWER,
+    "/jax/core/compile/backend_compile_duration": span_names.COMPILE_BACKEND,
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        span_names.COMPILE_CACHE_LOAD,
+}
+
+
+class _ProcessRecord:
+    """What this process did to get ready, for as long as it lives: set-up
+    phases ``(name, t0, t1)`` and compile events ``(event, fun_name, t_end,
+    duration_s)``, both on ``time.perf_counter()``. A fact of the process:
+    agents come and go (``obs.setup`` resets THEIR counters), the programs
+    they made JAX build stay built, and stay recorded."""
+
+    def __init__(self, cap: int = PROCESS_RECORD_CAP) -> None:
+        self._lock = threading.Lock()
+        self._phases: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
+        self._compiles: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
+        self._dropped = 0  # guarded-by: _lock
+        self._listening = False  # guarded-by: _lock
+
+    def listen(self) -> None:
+        """Register the one jax.monitoring listener (first use; a process
+        that never gets here never imports jax on obs's account)."""
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(self.on_event)
+
+    def on_event(self, event: str, duration: float, **kwargs) -> None:
+        """Runs on whichever thread made JAX build a program, and only
+        then: nothing on a steady call."""
+        span = COMPILE_EVENTS.get(event)
+        if span is None:
+            return
+        t_end = time.perf_counter()
+        fun = str(kwargs.get("fun_name", ""))
+        with self._lock:
+            self._dropped += len(self._compiles) == self._compiles.maxlen
+            self._compiles.append((event, fun, t_end, float(duration)))
+        trace.record_span(span, t_end - duration, t_end, meta={"fun": fun})
+
+    def add_phase(self, name: str, t0: float, t1: float) -> None:
+        with self._lock:
+            self._dropped += len(self._phases) == self._phases.maxlen
+            self._phases.append((name, t0, t1))
+
+    def snapshot(self) -> dict[str, Any]:
+        with self._lock:
+            return {
+                "phases": list(self._phases),
+                "compiles": list(self._compiles),
+                "dropped": self._dropped,
+            }
+
+
+_RECORD = _ProcessRecord()
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """One set-up phase of the process (``spans.SETUP_*``): appended to the
+    process record as ``(name, t0, t1)``, and a ``trace.span(name)`` when
+    the tracer is armed. For set-up sites only — a dozen calls a process;
+    the hot path uses ``trace.span``, which costs nothing disarmed."""
+    _RECORD.listen()
+    t0 = time.perf_counter()
+    try:
+        with trace.span(name):
+            yield
+    finally:
+        _RECORD.add_phase(name, t0, time.perf_counter())
+
+
+def process_record() -> dict[str, Any]:
+    """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
+    duration_s)], "dropped": n}``: copies, oldest first, ``perf_counter``
+    stamps (a compile event started at ``t_end - duration_s``). A compile
+    event belongs to the phases whose ``[t0, t1]`` hold its ``t_end``."""
+    _RECORD.listen()
+    return _RECORD.snapshot()
 
 
 def _sig(obj: Any) -> Any:

@@ -52,6 +52,25 @@ LEARNER_UPDATE = "learner.update"            # jitted update dispatch
 LEARNER_METRICS = "learner.metrics_drain"    # device_get of pending metrics
 LEARNER_EVAL = "learner.eval"                # in-training greedy evaluation
 
+# Set-up phases (obs/introspect.py ``phase``): always in the process record,
+# and spans when the tracer is armed. COMPUTE — set-up waits on no stage.
+SETUP_AGENT = "setup.agent"                # api/factory.py: the trainer's construction
+SETUP_ENV = "setup.env"                    # env (Anakin) / probe pool + spec (Sebulba)
+SETUP_MODEL = "setup.model"                # build_model
+SETUP_MESH = "setup.mesh"                  # make_mesh
+SETUP_LEARNER = "setup.learner"            # Learner / RolloutLearner construction
+SETUP_INIT_STATE = "setup.init_state"      # learner.init_state (params, actor state)
+SETUP_CHECKPOINT = "setup.checkpoint"      # checkpoint.setup (restore / auto-resume)
+SETUP_FIRST_UPDATE = "setup.first_update"  # a learner's first update call: trace, lower, compile or load
+
+# What JAX reports of each program it builds (obs/introspect.py's
+# jax.monitoring listener): always in the process record, and already-timed
+# spans (``trace.record_span``, meta ``fun``) when the tracer is armed.
+COMPILE_TRACE = "compile.trace"            # function -> jaxpr
+COMPILE_LOWER = "compile.lower"            # jaxpr -> MLIR module
+COMPILE_BACKEND = "compile.backend"        # backend compile, or the load that stood in for it
+COMPILE_CACHE_LOAD = "compile.cache_load"  # persistent-cache retrieval
+
 # Spans where the thread is blocked on ANOTHER stage of the pipeline.
 WAIT_SPANS = frozenset({
     ACTOR_LEASE_WAIT,

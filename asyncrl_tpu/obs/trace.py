@@ -16,6 +16,11 @@ Design constraints (ISSUE 5 tentpole):
   tracer returns one shared no-op context manager — no allocation, no
   ring registration, one module-global read and a ``None`` check (the
   same compile-away discipline as ``utils.faults.site``).
+- **One name, two clocks.** An armed span is also a
+  ``jax.profiler.TraceAnnotation``: the rings keep ``perf_counter``
+  stamps for the export and the flight recorder, and while a profiler
+  session is active the same name is an event of its trace, beside the
+  device's ops.
 
 Arming mirrors ``utils.faults``: explicit :func:`configure` (the trainer's
 ``config.trace``), or lazily from ``ASYNCRL_TRACE=1`` on first use
@@ -53,21 +58,31 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """One in-flight span: records [enter, exit) into the owning ring."""
+    """One in-flight span: records [enter, exit) into the owning ring, and
+    is a ``jax.profiler.TraceAnnotation`` around it — which records only
+    while a profiler session is active, so under ``--profile DIR`` (or any
+    ``jax.profiler.start_trace``) the span is an event of the profiler's
+    trace, on the device trace's own clock, nested under whatever the
+    caller annotates."""
 
-    __slots__ = ("_ring", "_name", "_t0")
+    __slots__ = ("_ring", "_name", "_t0", "_annotation")
 
-    def __init__(self, ring: "SpanRing", name: str):
+    def __init__(self, ring: "SpanRing", name: str, annotation=None):
         self._ring = ring
         self._name = name
         self._t0 = 0.0
+        self._annotation = None if annotation is None else annotation(name)
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self._ring.record(self._name, self._t0, time.perf_counter())
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -175,6 +190,13 @@ class Tracer:
         # (span.start - anchor_perf) in µs, wall-anchored by anchor_unix.
         self.anchor_perf = time.perf_counter()
         self.anchor_unix = time.time()
+        # jax is imported here, once, and only by a process that arms
+        # tracing; without it the spans stay in the rings alone.
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
 
     # Bound on RETAINED rings: dead threads' rings stay for forensics (a
     # crashed actor's spans must survive into the export/dumps), but
@@ -210,7 +232,7 @@ class Tracer:
         return ring
 
     def span(self, name: str) -> _Span:
-        return _Span(self._ring(), name)
+        return _Span(self._ring(), name, self._annotation)
 
     def tag_thread(self, group: str) -> None:
         """Override the calling thread's group (the trainer tags its drain
@@ -313,7 +335,10 @@ def record_span(name: str, start: float, end: float,
                 meta: dict | None = None) -> None:
     """Record one already-timed span (perf_counter stamps) into the
     calling thread's ring — the request-journal replay path, which emits
-    trace-id-stamped ``request.*`` spans at journal close. No-op when
+    trace-id-stamped ``request.*`` spans at journal close, and the
+    ``compile.*`` events of ``obs/introspect.py``'s listener. Rings only:
+    a ``TraceAnnotation`` stamps the profiler's clock as it is entered and
+    left, so a span that is already over cannot become one. No-op when
     tracing is disabled."""
     tracer = active()
     if tracer is not None:

@@ -174,6 +174,7 @@ def reverse_linear_scan_pallas(
 
     out = pl.pallas_call(
         _scan_kernel,
+        name="reverse_linear_scan_pallas",
         grid=(B_pad // block,),
         in_specs=[
             pl.BlockSpec((T_pad, block), lambda i: (0, i), memory_space=pltpu.VMEM),
@@ -239,6 +240,7 @@ def reverse_linear_scan_pallas_dma(
 
     out = pl.pallas_call(
         _scan_kernel_dma,
+        name="reverse_linear_scan_pallas_dma",
         grid=(B_pad // block,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -450,6 +452,7 @@ def fused_vtrace_pallas(
     args = (crho, a, rew, disc, val, boot)
     vs, adv, pg = pl.pallas_call(
         _fused_vtrace_kernel,
+        name="fused_vtrace_pallas",
         grid=(n_b, n_chunks),
         in_specs=[tile] * 5
         + [pl.BlockSpec((1, block), lambda ib, jt: (0, ib), memory_space=pltpu.VMEM)],

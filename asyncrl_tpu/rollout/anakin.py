@@ -143,47 +143,59 @@ def unroll(
         split = jax.vmap(lambda k: jax.random.split(k, n_keys))(carry.keys)
         next_keys, act_keys, step_keys = split[:, 0], split[:, 1], split[:, 2]
 
-        if recurrent:
-            dist_params, _, core = apply_fn(params, carry.obs, carry.core)
-        else:
-            dist_params, _ = apply_fn(params, carry.obs)
-            core = None
-        if dist_extra is not None:
-            dist_params = jnp.concatenate(
-                [dist_params, dist_extra.astype(dist_params.dtype)], axis=-1
-            )
-        actions = jax.vmap(dist.sample)(act_keys, dist_params)
-        behaviour_logp = dist.logp(dist_params, actions)
-
-        if selfplay:
-            opp_obs = jax.vmap(env.observe_opponent)(carry.env_state)
-            if carry.opp_core is not None:
-                opp_dist_params, _, opp_core = apply_fn(
-                    opponent_params, opp_obs, carry.opp_core
+        # The two scopes split the step's device time in a profile
+        # (metadata only: the compiled ops are the same without them).
+        with jax.named_scope("actor_forward"):
+            if recurrent:
+                dist_params, _, core = apply_fn(
+                    params, carry.obs, carry.core
                 )
             else:
-                opp_dist_params, _ = apply_fn(opponent_params, opp_obs)
-                opp_core = None
+                dist_params, _ = apply_fn(params, carry.obs)
+                core = None
             if dist_extra is not None:
-                # The rival samples under the SAME behaviour knobs as the
-                # agent (e.g. the Q-family's annealed ε) — without this, an
-                # EpsilonGreedy dist would default the opponent to ε=0 and
-                # the frozen snapshot would play deterministic argmax.
-                opp_dist_params = jnp.concatenate(
-                    [
-                        opp_dist_params,
-                        dist_extra.astype(opp_dist_params.dtype),
-                    ],
+                dist_params = jnp.concatenate(
+                    [dist_params, dist_extra.astype(dist_params.dtype)],
                     axis=-1,
                 )
-            opp_actions = jax.vmap(dist.sample)(split[:, 3], opp_dist_params)
-            env_state, ts = jax.vmap(env.step_duel)(
-                carry.env_state, actions, opp_actions, step_keys
-            )
+            actions = jax.vmap(dist.sample)(act_keys, dist_params)
+            behaviour_logp = dist.logp(dist_params, actions)
+
+        if selfplay:
+            with jax.named_scope("actor_forward"):
+                opp_obs = jax.vmap(env.observe_opponent)(carry.env_state)
+                if carry.opp_core is not None:
+                    opp_dist_params, _, opp_core = apply_fn(
+                        opponent_params, opp_obs, carry.opp_core
+                    )
+                else:
+                    opp_dist_params, _ = apply_fn(opponent_params, opp_obs)
+                    opp_core = None
+                if dist_extra is not None:
+                    # The rival samples under the SAME behaviour knobs as
+                    # the agent (e.g. the Q-family's annealed ε) — without
+                    # this, an EpsilonGreedy dist would default the
+                    # opponent to ε=0 and the frozen snapshot would play
+                    # deterministic argmax.
+                    opp_dist_params = jnp.concatenate(
+                        [
+                            opp_dist_params,
+                            dist_extra.astype(opp_dist_params.dtype),
+                        ],
+                        axis=-1,
+                    )
+                opp_actions = jax.vmap(dist.sample)(
+                    split[:, 3], opp_dist_params
+                )
+            with jax.named_scope("env_step"):
+                env_state, ts = jax.vmap(env.step_duel)(
+                    carry.env_state, actions, opp_actions, step_keys
+                )
         else:
-            env_state, ts = jax.vmap(env.step)(
-                carry.env_state, actions, step_keys
-            )
+            with jax.named_scope("env_step"):
+                env_state, ts = jax.vmap(env.step)(
+                    carry.env_state, actions, step_keys
+                )
             opp_core = None
 
         if recurrent:

@@ -36,6 +36,12 @@ def enable_compile_cache() -> str | None:
     the default they would never be cached and every cold start would
     pay for all of them again.
 
+    The cache key includes the ops' metadata (JAX's default strips it):
+    ``jax.named_scope`` names and source lines live there, and a profile
+    reads device time by scope. Without it a program that differs from a
+    cached one only in its scopes loads the cached executable, and its
+    trace carries the names of whichever commit filled the directory.
+
     A CPU backend is left alone: an XLA:CPU executable is specific to
     the CPU it was built for while the directory travels with the tree,
     and on jax 0.9.0 every CPU cache hit logs "Target machine feature
@@ -48,6 +54,7 @@ def enable_compile_cache() -> str | None:
     if jax.default_backend() == "cpu":
         return None
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     cache_dir = os.environ.get(CACHE_DIR_ENV)
     if not cache_dir:
         cache_dir = DEFAULT_CACHE_DIR

@@ -157,6 +157,11 @@ QUICK: dict[str, object] = {
     # are ~15s combined. Tier-1 by the ISSUE 8 acceptance contract
     # (detectors proven to flip /healthz on every PR). Whole file ~20s.
     "test_introspect.py": "all",
+    # The process record, armed spans in a profiler trace, tracing on the
+    # Anakin trainer and the device scopes (ISSUE 24): units are
+    # sub-second; three tiny CartPole agents, one CPU profile and one
+    # compile of a 2-env pixel step, ~25s combined.
+    "test_obs_process_record.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for

@@ -23,6 +23,7 @@ print(json.dumps({
     "before": before, "returned": returned,
     "after": jax.config.jax_compilation_cache_dir,
     "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+    "metadata_in_key": jax.config.jax_compilation_cache_include_metadata_in_key,
 }))
 """
 
@@ -47,6 +48,8 @@ def test_env_var_wins_and_no_other_directory_is_set(tmp_path):
     # JAX read the variable itself; the helper changed no directory.
     assert got["before"] == got["after"] == got["returned"] == placed
     assert got["min_secs"] == 0.0
+    # scope names are part of a program's identity (a profile reads them)
+    assert got["metadata_in_key"] is True
 
 
 def test_default_is_the_fixed_in_checkout_directory():
@@ -72,3 +75,45 @@ def test_cache_entries_counts_programs(tmp_path):
     (tmp_path / "jit_f-abc-cache").write_bytes(b"x")
     (tmp_path / "jit_f-abc-atime").write_bytes(b"x")
     assert runtime.cache_entries(str(tmp_path)) == 1
+
+
+_SCOPES_PROBE = """
+import json, os, sys
+import jax, jax.numpy as jnp
+from asyncrl_tpu.utils import runtime
+jax.default_backend = lambda: "tpu"
+cache_dir = runtime.enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+def make(scope):
+    def scoped_probe(x):
+        with jax.named_scope(scope):
+            return jnp.tanh(x) * 3
+    return jax.jit(scoped_probe)
+
+texts = []
+for scope in ("alpha", "beta"):
+    f = make(scope)
+    f(jnp.ones(4)).block_until_ready()
+    texts.append(f.lower(jnp.ones(4)).compile().as_text())
+print(json.dumps({
+    "entries": sum(n.startswith("jit_scoped_probe-") and n.endswith("-cache")
+                   for n in os.listdir(cache_dir)),
+    "beta_names_beta": "beta/tanh" in texts[1],
+}))
+"""
+
+
+def test_a_program_that_differs_only_in_its_scopes_is_not_a_cache_hit(tmp_path):
+    """A profile reads device time by ``jax.named_scope``: an executable
+    loaded from the cache must carry this source's names, not those of
+    the commit that filled the directory."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _SCOPES_PROBE],
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache")),
+        capture_output=True, text=True, timeout=120, cwd=_ROOT,
+    )
+    assert proc.returncode == 0, proc.stderr
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got == {"entries": 2, "beta_names_beta": True}
