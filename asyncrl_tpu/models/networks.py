@@ -16,6 +16,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from asyncrl_tpu.ops.max_pool import max_pool_3x3_s2
+
 ORTHO = nn.initializers.orthogonal
 
 
@@ -92,9 +94,7 @@ class ImpalaCNN(nn.Module):
             with jax.named_scope(f"section{i}"):
                 x = nn.Conv(ch, (3, 3), dtype=self.compute_dtype)(x)
                 with jax.named_scope("max_pool"):
-                    x = nn.max_pool(
-                        x, (3, 3), strides=(2, 2), padding="SAME"
-                    )
+                    x = max_pool_3x3_s2(x)
                 x = block(
                     ch, self.compute_dtype, name=f"ResidualBlock_{2 * i}"
                 )(x)

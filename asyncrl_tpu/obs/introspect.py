@@ -210,6 +210,7 @@ class _ProcessRecord:
         self._phases: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
         self._compiles: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
         self._dropped = 0  # guarded-by: _lock
+        self._pool_sites = {"kernel": 0, "fallback": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -241,12 +242,17 @@ class _ProcessRecord:
             self._dropped += len(self._phases) == self._phases.maxlen
             self._phases.append((name, t0, t1))
 
+    def count_pool_site(self, path: str) -> None:
+        with self._lock:
+            self._pool_sites[path] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
                 "phases": list(self._phases),
                 "compiles": list(self._compiles),
                 "dropped": self._dropped,
+                "pool_sites": dict(self._pool_sites),
             }
 
 
@@ -270,11 +276,20 @@ def phase(name: str):
 
 def process_record() -> dict[str, Any]:
     """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
-    duration_s)], "dropped": n}``: copies, oldest first, ``perf_counter``
-    stamps (a compile event started at ``t_end - duration_s``). A compile
-    event belongs to the phases whose ``[t0, t1]`` hold its ``t_end``."""
+    duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
+    n}}``: copies, oldest first, ``perf_counter`` stamps (a compile event
+    started at ``t_end - duration_s``). A compile event belongs to the
+    phases whose ``[t0, t1]`` hold its ``t_end``."""
     _RECORD.listen()
     return _RECORD.snapshot()
+
+
+def count_pool_site(path: str) -> None:
+    """One differentiated max-pool site of a program being lowered took the
+    Pallas kernels (``"kernel"``) or ``nn.max_pool``'s own VJP
+    (``"fallback"``): called by ``ops/max_pool.py``, once per site and
+    program, nothing on a steady call."""
+    _RECORD.count_pool_site(path)
 
 
 def _sig(obj: Any) -> Any:

@@ -117,7 +117,7 @@ EOF
 GATES=(
     "scripts|configflow,sharding,hostsync,pallas,deadlines,refund,units,races|scripts/*.py bench.py chip_smoke.py __graft_entry__.py"
     "fleet|protocols,deadlock|asyncrl_tpu/serve/fleet.py"
-    "kernels|pallas,sharding,protocols|asyncrl_tpu/ops/pallas_scan.py asyncrl_tpu/rollout/device_queue.py"
+    "kernels|pallas,sharding,protocols|asyncrl_tpu/ops/pallas_scan.py asyncrl_tpu/ops/max_pool.py asyncrl_tpu/rollout/device_queue.py"
     "requests|deadlines,refund,units,protocols|asyncrl_tpu/obs/requests.py"
 )
 for gate in "${GATES[@]}"; do

@@ -162,6 +162,11 @@ QUICK: dict[str, object] = {
     # sub-second; three tiny CartPole agents, one CPU profile and one
     # compile of a 2-env pixel step, ~25s combined.
     "test_obs_process_record.py": "all",
+    # The max-pool kernels (ops/max_pool.py, ISSUE 25) in the Pallas
+    # interpreter against nn.max_pool, ties everywhere (ten shape x dtype
+    # cases, 2-20s each), the kernel/fallback choice, and one compile of
+    # the learner's geometry for a described v5e (~25s). Whole file ~85s.
+    "test_max_pool.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for

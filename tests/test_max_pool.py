@@ -1,0 +1,189 @@
+"""ops/max_pool.py: the Pallas kernels against ``nn.max_pool`` in the
+interpreter, the choice between them and the fallback, and a compile for a
+described v5e that pins what the ``[H, W, C, N]`` view is supposed to cost:
+nothing."""
+
+import math
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import pytest
+
+from asyncrl_tpu.models.networks import ImpalaCNN
+from asyncrl_tpu.obs import introspect
+from asyncrl_tpu.ops import max_pool as mp
+from asyncrl_tpu.ops.max_pool import max_pool_3x3_s2
+
+SHAPES = [(84, 84, 16), (42, 42, 32), (21, 21, 32), (64, 64, 16), (11, 11, 3)]
+
+
+def reference(x):
+    return nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+
+
+def kernel_value_and_vjp(x, g):
+    y, pos = mp._kernel_fwd(x, interpret=True)
+    return y, mp._kernel_bwd(pos, g, x.shape, interpret=True)
+
+
+def sites():
+    return introspect.process_record()["pool_sites"]
+
+
+def sites_since(before):
+    return {k: v - before[k] for k, v in sites().items()}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hwc", SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_kernels_equal_nn_max_pool_with_ties_everywhere(hwc, dtype):
+    # three integer values: nearly every window ties; integer cotangents,
+    # so the float32 sums of up to four of them are exact in either dtype
+    kx, kg = jax.random.split(jax.random.key(sum(hwc)))
+    x = jax.random.randint(kx, (128, *hwc), -1, 2).astype(dtype)
+    want_y, vjp = jax.vjp(reference, x)
+    g = jax.random.randint(kg, want_y.shape, -3, 4).astype(dtype)
+    y, dx = kernel_value_and_vjp(x, g)
+    assert y.dtype == dx.dtype == dtype
+    assert jnp.array_equal(y, want_y)
+    assert jnp.array_equal(dx, vjp(g)[0])
+
+
+def test_a_nine_way_tie_sends_the_gradient_to_the_first_element():
+    x = jnp.ones((1, 128, 6, 6, 8), jnp.bfloat16)  # leading dims flatten
+    want_y, vjp = jax.vjp(reference, x)
+    g = jnp.arange(9, dtype=jnp.bfloat16).reshape(3, 3, 1) + jnp.ones_like(
+        want_y)
+    y, dx = kernel_value_and_vjp(x, g)
+    assert jnp.array_equal(y, want_y)
+    assert jnp.array_equal(dx, vjp(g)[0])
+    assert jnp.count_nonzero(dx[0, 0, :, :, 0]) == 9
+    assert dx[0, 0, 2, 4, 0] == g[0, 0, 1, 2, 0]  # the window's corner
+
+
+def test_a_call_that_is_not_differentiated_is_reduce_window_max():
+    x = jnp.zeros((128, 8, 8, 4), jnp.bfloat16)
+    (call,) = jax.make_jaxpr(max_pool_3x3_s2)(x).eqns
+    inner = call.params["call_jaxpr"]
+    assert [e.primitive.name for e in inner.eqns] == ["reduce_window_max"]
+    assert str(inner) == str(jax.make_jaxpr(reference)(x))
+    before = sites()
+    lowered = jax.jit(max_pool_3x3_s2).lower(x).as_text()
+    assert "reduce_window" in lowered and "case" not in lowered
+    assert sites_since(before) == {"kernel": 0, "fallback": 0}
+
+
+@pytest.mark.parametrize("n", [128, 96], ids=["lanes_full", "lanes_ragged"])
+def test_off_the_tpu_every_differentiated_site_falls_back(n):
+    # n = 128 fits the kernels and is turned down when lowered for the CPU;
+    # n = 96 (a grad_accum chunk, a PPO minibatch) when traced
+    model = ImpalaCNN(channels=(4, 8, 8), compute_dtype=jnp.bfloat16)
+    obs = jnp.zeros((2, n // 2, 16, 16, 4), jnp.uint8)
+    params = model.init(jax.random.key(0), obs[0, :1])
+    assert mp._kernel_fits((n, 16, 16, 4), jnp.bfloat16) == (n == 128)
+
+    def loss(p):
+        return jnp.sum(model.apply(p, obs).astype(jnp.float32))
+
+    before = sites()
+    text = jax.jit(jax.grad(loss)).lower(params).as_text()
+    assert sites_since(before) == {"kernel": 0, "fallback": 3}
+    assert text.count("stablehlo.select_and_scatter") == 3
+    assert "tpu_custom_call" not in text
+
+
+def test_gradients_under_vmap_equal_nn_max_pool():
+    # PopulationTrainer differentiates under vmap
+    x = jax.random.randint(jax.random.key(1), (3, 128, 6, 6, 8), -1, 2)
+    x = x.astype(jnp.float32)
+
+    def grad_of(pool):
+        return jax.vmap(jax.grad(lambda v: jnp.sum(pool(v) ** 2)))(x)
+
+    assert jnp.array_equal(grad_of(max_pool_3x3_s2), grad_of(reference))
+
+
+@pytest.mark.parametrize("shape,dtype,fits", [
+    ((33, 256, 84, 84, 16), jnp.bfloat16, True),
+    ((8448, 11, 11, 3), jnp.float32, True),
+    ((8, 256, 84, 84, 16), jnp.bfloat16, True),    # 2048 lanes
+    ((33, 64, 84, 84, 16), jnp.bfloat16, False),   # 2112 = 16.5 x 128
+    ((84, 84, 16), jnp.bfloat16, False),           # no batch
+    ((128, 84, 84, 16), jnp.float16, False),
+    ((128, 84, 84, 16), jnp.float32, False),       # 2 x 72 MB of blocks
+    ((128, 256, 256, 32), jnp.bfloat16, False),
+    ((0, 84, 84, 16), jnp.bfloat16, False),
+], ids=str)
+def test_the_shapes_the_kernels_take(shape, dtype, fits):
+    assert mp._kernel_fits(shape, dtype) == fits
+
+
+# ------------------------------------------- compiled for a described v5e
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe a chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a deviceless compile can write the persistent cache, never read it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def test_the_learner_geometry_compiles_to_six_kernels_and_no_copy(one_chip):
+    model = ImpalaCNN(compute_dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 84, 84, 4))))
+    params, obs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.ShapeDtypeStruct((33, 256, 84, 84, 4), jnp.uint8)))
+
+    def loss(p, o):
+        return jnp.sum(model.apply(p, o).astype(jnp.float32) ** 2)
+
+    before = sites()
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, obs).compile().as_text()
+    assert sites_since(before) == {"kernel": 3, "fallback": 0}
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]+)"', text)
+    assert len(calls) == 6
+    for i in range(3):
+        assert sum(f"/section{i}/max_pool/" in c and "max_pool_fwd" in c
+                   and "/jvp(" in c for c in calls) == 1
+        assert sum(f"/section{i}/max_pool/" in c and "max_pool_bwd" in c
+                   and "/transpose(jvp(" in c for c in calls) == 1
+    assert "select-and-scatter" not in text
+    # the [H, W, C, N] view is the layout XLA keeps: nothing the size of a
+    # pre-pool activation, or of a pooled one, is copied or transposed (the
+    # last pooled size is also the Dense layer's input, relaid out anyway)
+    activations = {8448 * h * h * c for h, c in
+                   [(84, 16), (42, 16), (42, 32), (21, 32)]}
+    def size(dims):
+        return math.prod(map(int, dims.split(",")))
+
+    for dims in re.findall(
+            r"= bf16\[([\d,]+)\]\S* (?:copy|transpose)\(", text):
+        assert size(dims) not in activations, dims
+    # and the barrier behind the forward call holds: without it the
+    # residual blocks' x + f(x) is computed in both logical shapes, five
+    # fusions write two activations each, and the backward ones read five
+    # (with nn.max_pool, and with the barrier, one fusion writes two)
+    twice = [outs for outs in re.findall(r"= \((.*?)\) fusion\(", text)
+             if sum(size(d) in activations | {8448 * 11 * 11 * 32}
+                    for d in re.findall(r"bf16\[([\d,]+)\]", outs)) >= 2]
+    assert len(twice) <= 1, twice

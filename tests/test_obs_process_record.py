@@ -266,3 +266,7 @@ def test_the_compiled_step_names_the_scopes_a_profile_reads():
                for n in names)
     assert any("/env_step/vmap(render)/" in n for n in names)
     assert any("/loss_and_grad/" in n and "/section0/max_pool/" in n for n in names)
+    # the pool has a custom_vjp: its backward keeps the call site's scopes
+    # (max_pool_device_ms counts forward and backward)
+    assert any("/loss_and_grad/transpose(" in n and "/section0/max_pool/" in n
+               for n in names)
