@@ -373,6 +373,39 @@ pong_serve = pong_impala.replace(
     ),
 )
 
+# Kimi-Linear-48B-A3B-Instruct as a token-level recurrent policy: on-policy
+# RL of a hybrid linear-attention MoE language model against a programmatic
+# reward, rollout (token by token through the carry) and learner (IMPALA /
+# V-trace over the whole fragment) in the one Anakin device program. The
+# model is one chip's share of layers 1-5 (models/kimi_linear.py SHAPES);
+# 64 envs x 256 tokens = 16,384 tokens an update. 602 M parameters: the
+# step runs donated, and fits one v5e beside its carry only at 16 bytes a
+# parameter (RMSProp) -- see benchmarks/configs/kimi_linear_rl.json.
+kimi_linear_rl = Config(
+    env_id="JaxTokenTask-v0",
+    algo="impala",
+    backend="tpu",
+    seq_model="kimi_linear_5l",
+    # the held vocabulary slice; episodes of 64-1,024 tokens (the model's
+    # position cap), prompts of 8-32
+    token_task=(20480, 64, 1024, 8, 32),
+    num_envs=64,
+    unroll_len=256,
+    total_env_steps=50_000_000,
+    learning_rate=1e-4,
+    entropy_coef=0.001,
+    actor_staleness=2,
+    optimizer="rmsprop",
+    donate_buffers=True,
+)
+# The same path at toy widths (every kind of layer, half the experts held):
+# what the CPU tests drive.
+kimi_linear_tiny = kimi_linear_rl.replace(
+    seq_model="kimi_linear_tiny", token_task=(64, 2, 32, 1, 2),
+    num_envs=8, unroll_len=32,
+    total_env_steps=100_000, learning_rate=1e-3,
+)
+
 PRESETS: dict[str, Config] = {
     "cartpole_a3c": cartpole_a3c,
     "cartpole_a3c_cpu": cartpole_a3c_cpu,
@@ -401,6 +434,8 @@ PRESETS: dict[str, Config] = {
     "mujoco_ant_ppo": mujoco_ant_ppo,
     "mujoco_humanoid_ppo": mujoco_humanoid_ppo,
     "pendulum_native_ppo": pendulum_native_ppo,
+    "kimi_linear_rl": kimi_linear_rl,
+    "kimi_linear_tiny": kimi_linear_tiny,
 }
 
 

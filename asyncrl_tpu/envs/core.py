@@ -64,6 +64,9 @@ class EnvSpec:
     obs_dtype: Any = jnp.float32
     continuous: bool = False
     action_dim: int = 0  # continuous spaces; 0 for discrete envs
+    # Most steps an episode can have, where the env bounds it and a model
+    # sizes something by it (a sequence policy's position cap); 0 = not said.
+    max_episode_steps: int = 0
 
 
 class Environment:

@@ -110,6 +110,10 @@ def _register_builtins() -> None:
         True,
     )
     register("JaxPendulum-v0", Pendulum)
+
+    from asyncrl_tpu.envs.token_task import TokenTask
+
+    register("JaxTokenTask-v0", TokenTask.for_config, True)
     from asyncrl_tpu.envs.gridworlds import Chaser, Maze
     from asyncrl_tpu.envs.minatari import (
         Asterix,

@@ -13,6 +13,7 @@ a pytree select every ``actor_staleness`` updates, staying entirely in HBM
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -22,6 +23,7 @@ from flax import struct
 from jax.sharding import Mesh, PartitionSpec as P
 
 from asyncrl_tpu.envs.core import Environment
+from asyncrl_tpu.ops.distributions import Evaluated
 from asyncrl_tpu.ops.gae import gae
 from asyncrl_tpu.ops.normalize import (
     init_stats,
@@ -309,6 +311,35 @@ def validate_recurrent_config(config: Config, model) -> None:
         )
 
 
+def fragment_form(apply_fn):
+    """The model's whole-fragment form (``models/kimi_linear.py``), where
+    the model behind ``apply_fn`` has one; None for a model that is only
+    ever called one step at a time."""
+    model = getattr(apply_fn, "__self__", None)
+    if not hasattr(model, "fragment"):
+        return None
+    return functools.partial(apply_fn, method="fragment")
+
+
+def _forward_evaluated(fragment, apply_fn, params, rollout: Rollout):
+    """Learner forward through the model's fragment form, from the
+    fragment-initial behaviour carry as ``_forward_fragment`` below:
+    ``((logp, entropy) [T, B] of the fragment's own actions, values
+    [T+1, B], aux)``. The policy head is already evaluated (the
+    [T, B, actions] logits are never whole): ``distributions.Evaluated``
+    reads the pair where a loss asks a distribution."""
+    logp, entropy, values, core_end, aux = fragment(
+        params, rollout.obs, rollout.done, rollout.init_core, rollout.actions
+    )
+    # Every loss stops the gradient at the bootstrap value: stopping it
+    # here spares the backward pass of this step (and what it would keep).
+    _, boot_value, _ = jax.lax.stop_gradient(
+        apply_fn(params, rollout.bootstrap_obs, core_end)
+    )
+    values = jnp.concatenate([values, boot_value[None]], axis=0)
+    return (logp, entropy), values, aux
+
+
 def _forward_fragment(apply_fn, params, rollout: Rollout):
     """Learner forward over one fragment -> (dist_params, values), both
     [T+1, ...] (final entry is the bootstrap step).
@@ -387,10 +418,22 @@ def _algo_loss(
     value, entropy_coef_at); None = the constant."""
     if entropy_coef is None:
         entropy_coef = config.entropy_coef
-    logits, values = _forward_fragment(apply_fn, params, rollout)
-    logits_t, values_t = logits[:-1], values[:-1]
+    fragment = fragment_form(apply_fn)
+    if fragment is not None and config.algo != "qlearn":
+        logits_t, values, aux = _forward_evaluated(
+            fragment, apply_fn, params, rollout
+        )
+        dist = Evaluated()
+    else:
+        logits, values = _forward_fragment(apply_fn, params, rollout)
+        logits_t, aux = logits[:-1], {}
+    values_t = values[:-1]
     bootstrap_value = values[-1]
     discounts = rollout.discounts(config.gamma)
+
+    def with_aux(loss_and_metrics):
+        loss, metrics = loss_and_metrics
+        return loss, {**metrics, **aux}
 
     if config.algo == "qlearn":
         # ``logits`` ARE the online Q-values here (QNetwork head). The
@@ -414,16 +457,16 @@ def _algo_loss(
             huber_delta=config.huber_delta,
         )
     if config.algo == "a3c":
-        return a3c_loss(
+        return with_aux(a3c_loss(
             logits_t, values_t, rollout.actions, rollout.rewards, discounts,
             jax.lax.stop_gradient(bootstrap_value),
             value_coef=config.value_coef, entropy_coef=entropy_coef,
             dist=dist, scan_impl=config.scan_impl,
             fused_scan=config.fused_scan,
             diagnostics=config.introspect,
-        )
+        ))
     if config.algo == "impala":
-        return impala_loss(
+        return with_aux(impala_loss(
             logits_t, values_t, rollout.actions, rollout.behaviour_logp,
             rollout.rewards, discounts, jax.lax.stop_gradient(bootstrap_value),
             value_coef=config.value_coef, entropy_coef=entropy_coef,
@@ -431,7 +474,7 @@ def _algo_loss(
             dist=dist, scan_impl=config.scan_impl,
             fused_scan=config.fused_scan,
             diagnostics=config.introspect,
-        )
+        ))
     if config.algo == "ppo":
         # Single-pass PPO over the fresh fragment (used when
         # ppo_epochs == ppo_minibatches == 1; the multi-epoch minibatched
@@ -441,13 +484,13 @@ def _algo_loss(
             jax.lax.stop_gradient(bootstrap_value), config.gae_lambda,
             scan_impl=config.scan_impl, fused=config.fused_scan,
         )
-        return ppo_loss(
+        return with_aux(ppo_loss(
             logits_t, values_t, rollout.actions, rollout.behaviour_logp,
             adv.advantages, adv.returns,
             clip_eps=config.ppo_clip_eps, value_coef=config.value_coef,
             entropy_coef=entropy_coef, axis_name=axis_name,
             dist=dist, diagnostics=config.introspect,
-        )
+        ))
     raise ValueError(f"unknown algo {config.algo!r}")
 
 
@@ -494,8 +537,14 @@ def _ppo_multipass(
     Recurrent cores stay excluded from sp meshes (rollout_learner's
     eager check; docs/ARCHITECTURE.md).
     """
+    fragment = fragment_form(apply_fn)
     if time_axis is None:
-        _, values_all = _forward_fragment(apply_fn, params, rollout)
+        if fragment is not None:
+            _, values_all, _ = _forward_evaluated(
+                fragment, apply_fn, params, rollout
+            )
+        else:
+            _, values_all = _forward_fragment(apply_fn, params, rollout)
         values_t, bootstrap_value = values_all[:-1], values_all[-1]
         adv = gae(
             rollout.rewards,
@@ -542,7 +591,7 @@ def _ppo_multipass(
     )
     base_key = jax.random.fold_in(base_key, _axis_index(axes))
 
-    def minibatch_step_with(forward):
+    def minibatch_step_with(forward, dist=dist):
         def minibatch_step(carry, batch):
             params, opt_state = carry
 
@@ -581,16 +630,30 @@ def _ppo_multipass(
             "done": rollout.done,
         }  # every leaf [T, B, ...]
 
-        def forward(p, batch):
-            def fwd(core, inputs):
-                obs_t, done_t = inputs
-                dist_params, value, new_core = apply_fn(p, obs_t, core)
-                return reset_core(new_core, done_t), (dist_params, value)
+        if fragment is not None:
+            # The model's own whole-fragment form in place of the scan of
+            # steps; the head comes back evaluated at the batch's actions.
+            dist = Evaluated()
 
-            _, (logits, values) = jax.lax.scan(
-                fwd, batch["init_core"], (batch["obs"], batch["done"])
-            )
-            return logits, values
+            def forward(p, batch):
+                logp, entropy, values, _, _ = fragment(
+                    p, batch["obs"], batch["done"], batch["init_core"],
+                    batch["actions"],
+                )
+                return (logp, entropy), values
+
+        else:
+
+            def forward(p, batch):
+                def fwd(core, inputs):
+                    obs_t, done_t = inputs
+                    dist_params, value, new_core = apply_fn(p, obs_t, core)
+                    return reset_core(new_core, done_t), (dist_params, value)
+
+                _, (logits, values) = jax.lax.scan(
+                    fwd, batch["init_core"], (batch["obs"], batch["done"])
+                )
+                return logits, values
 
         def epoch_step(carry, ekey):
             perm = jax.random.permutation(ekey, B)
@@ -604,7 +667,9 @@ def _ppo_multipass(
                 lambda c: c[perm].reshape(mb, B // mb, *c.shape[1:]),
                 rollout.init_core,
             )
-            return jax.lax.scan(minibatch_step_with(forward), carry, batches)
+            return jax.lax.scan(
+                minibatch_step_with(forward, dist), carry, batches
+            )
 
     else:
         n = T * B

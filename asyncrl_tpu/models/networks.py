@@ -304,13 +304,19 @@ class RecurrentQNetwork(nn.Module):
 
 def reset_core(core, done):
     """Zero the recurrent carry where ``done`` (episode boundary); ``done``
-    is [B] bool/float, core leaves are [B, H]."""
+    is [B] bool/float. An LSTM's ``(c, h)`` leaves are [B, H]; a carry
+    that holds more than one kind of state (``kimi_linear.SeqCore``) says
+    itself what a reset is."""
+    if hasattr(core, "reset"):
+        return core.reset(done.astype(bool))
     keep = 1.0 - done.astype(jnp.float32)
     return jax.tree.map(lambda c: c * keep[:, None], core)
 
 
 def is_recurrent(model) -> bool:
-    return isinstance(model, (RecurrentActorCritic, RecurrentQNetwork))
+    """A model that is called through a carry: ``apply(params, obs, core)``
+    and ``initial_core(batch)``."""
+    return hasattr(model, "initial_core")
 
 
 def build_model(config, env_spec):
@@ -318,6 +324,24 @@ def build_model(config, env_spec):
     compute_dtype = (
         jnp.bfloat16 if config.precision == "bf16_matmul" else jnp.float32
     )
+    if config.seq_model:
+        from asyncrl_tpu.models import kimi_linear
+
+        shape = kimi_linear.SHAPES[config.seq_model]
+        if config.algo == "qlearn" or env_spec.num_actions != shape.vocab:
+            raise ValueError(
+                f"seq_model={config.seq_model!r} is a policy over its "
+                f"{shape.vocab}-token vocabulary for the policy-gradient "
+                f"algorithms; got algo={config.algo!r} on an env with "
+                f"{env_spec.num_actions} actions"
+            )
+        if not 0 < env_spec.max_episode_steps <= shape.max_positions:
+            raise ValueError(
+                f"seq_model={config.seq_model!r} holds {shape.max_positions} "
+                f"positions of an episode; the env's episodes have up to "
+                f"{env_spec.max_episode_steps or 'an unstated number of'} steps"
+            )
+        return kimi_linear.SeqPolicy(shape, compute_dtype)
     if config.algo == "qlearn":
         if env_spec.continuous:
             raise ValueError(

@@ -211,6 +211,7 @@ class _ProcessRecord:
         self._compiles: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
         self._dropped = 0  # guarded-by: _lock
         self._pool_sites = {"kernel": 0, "fallback": 0}  # guarded-by: _lock
+        self._kda_sites = {"step": 0, "chunk": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -246,6 +247,10 @@ class _ProcessRecord:
         with self._lock:
             self._pool_sites[path] += 1
 
+    def count_kda_site(self, form: str) -> None:
+        with self._lock:
+            self._kda_sites[form] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -253,6 +258,7 @@ class _ProcessRecord:
                 "compiles": list(self._compiles),
                 "dropped": self._dropped,
                 "pool_sites": dict(self._pool_sites),
+                "kda_sites": dict(self._kda_sites),
             }
 
 
@@ -277,9 +283,10 @@ def phase(name: str):
 def process_record() -> dict[str, Any]:
     """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
-    n}}``: copies, oldest first, ``perf_counter`` stamps (a compile event
-    started at ``t_end - duration_s``). A compile event belongs to the
-    phases whose ``[t0, t1]`` hold its ``t_end``."""
+    n}, "kda_sites": {"step": n, "chunk": n}}``: copies, oldest first,
+    ``perf_counter`` stamps (a compile event started at ``t_end -
+    duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
+    hold its ``t_end``."""
     _RECORD.listen()
     return _RECORD.snapshot()
 
@@ -290,6 +297,13 @@ def count_pool_site(path: str) -> None:
     (``"fallback"``): called by ``ops/max_pool.py``, once per site and
     program, nothing on a steady call."""
     _RECORD.count_pool_site(path)
+
+
+def count_kda_site(form: str) -> None:
+    """One KDA site of a program being traced took the one-token recurrence
+    (``"step"``) or the chunked fragment form (``"chunk"``): called by
+    ``ops/kda.py``, once per site and trace, nothing on a steady call."""
+    _RECORD.count_kda_site(form)
 
 
 def _sig(obj: Any) -> Any:

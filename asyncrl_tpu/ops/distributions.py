@@ -169,6 +169,21 @@ class EpsilonGreedy:
         return jnp.argmax(q, axis=-1)
 
 
+@dataclasses.dataclass(frozen=True)
+class Evaluated:
+    """A policy head already evaluated at the fragment's own actions: the
+    "parameters" are the pair ``(logp, entropy)`` [T, B] that a model's
+    fragment form returns in place of [T, B, actions] logits it never holds
+    whole (``models/kimi_linear.py``). What a loss asks of a distribution,
+    it reads off the pair."""
+
+    def logp(self, params, actions) -> jax.Array:
+        return params[0]
+
+    def entropy(self, params) -> jax.Array:
+        return params[1]
+
+
 def for_spec(spec) -> Categorical | DiagGaussian:
     """Distribution matching an ``EnvSpec``."""
     if getattr(spec, "continuous", False):

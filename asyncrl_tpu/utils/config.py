@@ -43,6 +43,14 @@ class Config:
     # (sebulba/cpu_async), resetting at episode boundaries.
     core: str = "ff"
     core_size: int = 256
+    # A token-level sequence policy in place of torso + core: the name of a
+    # model-shape record in ``models/kimi_linear.py SHAPES`` (published
+    # widths and the cut to one chip's share). "" = torso + core above.
+    seq_model: str = ""
+    # JaxTokenTask-v0 (envs/token_task.py): (vocab, min_len, max_len,
+    # min_prompt, max_prompt) -- the vocabulary, the range an episode's
+    # length is drawn from (log-uniform) and the range of its prompt's.
+    token_task: tuple[int, ...] = (64, 4, 32, 1, 2)
 
     # --- optimization ---
     # "adam" (the reference's Learner optimizer, BASELINE.json:5) or

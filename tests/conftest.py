@@ -167,6 +167,9 @@ QUICK: dict[str, object] = {
     # cases, 2-20s each), the kernel/fallback choice, and one compile of
     # the learner's geometry for a described v5e (~25s). Whole file ~85s.
     "test_max_pool.py": "all",
+    # The Kimi-Linear sequence policy against its plain reference at the
+    # tiny preset (ISSUE 26): forms, carry, shares, chunked scan, training.
+    "test_kimi_linear.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for
@@ -316,6 +319,20 @@ QUICK: dict[str, object] = {
 }
 
 
+# One assertion of an accepted benchmark test that ISSUE 26 §5 made false:
+# `render/section0/max_pool_device_ms` read scopes only the IMPALA-CNN's
+# step has, so with the sequence cell they list the cells that have them,
+# and the case's last line ("workloads" not in entry) no longer holds.
+# That file is a `benchmark` PR's to change, so the three cases are
+# expected to fail here, strictly: the PR that repairs the assertion
+# makes them pass, which fails until these marks go. What else they held
+# is asserted in tests/benchmarks/test_benchmark_seq.py.
+SUPERSEDED = {
+    f"test_benchmark_program_metrics.py::test_metric_resolves_to_its_reader[{m}]"
+    for m in ("render_device_ms", "section0_device_ms", "max_pool_device_ms")
+}
+
+
 def pytest_collection_modifyitems(config, items):
     slow = pytest.mark.slow
     seen_files: set[str] = set()
@@ -323,6 +340,11 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         fname = item.fspath.basename
         seen_files.add(fname)
+        if item.nodeid.split("/")[-1] in SUPERSEDED:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="ISSUE 26 §5: the metric lists the atari cells",
+            ))
         entry = QUICK.get(fname)
         if entry == "all":
             continue

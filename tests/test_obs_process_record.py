@@ -270,3 +270,40 @@ def test_the_compiled_step_names_the_scopes_a_profile_reads():
     # (max_pool_device_ms counts forward and backward)
     assert any("/loss_and_grad/transpose(" in n and "/section0/max_pool/" in n
                for n in names)
+
+
+def test_the_sequence_policy_step_names_the_scopes_a_profile_reads():
+    """The same check for ``kimi_linear_rl``'s layers (forward and backward
+    ops), and the record of which form each KDA site lowered as."""
+    before = introspect.process_record()["kda_sites"]
+    cfg = presets.get("kimi_linear_tiny").replace(
+        num_envs=len(jax.devices()), unroll_len=16, fused_scan="interpret")
+    agent = make_agent(cfg)
+    try:
+        text = agent.learner._step.lower(agent.state).compile().as_text()
+    finally:
+        agent.close()
+    names = re.findall(r'op_name="([^"]+)"', text)
+    components = {c for name in names for c in name.split("/")}
+    for scope in ("rollout", "loss_and_grad", "actor_forward", "env_step",
+                  "kda", "kda_step", "kda_chunk", "mla", "moe", "moe_router",
+                  "moe_experts", "lm_head", "core_reset"):
+        assert scope in components, scope
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    # the rollout runs the one-token forms, the learner the fragment forms
+    assert some("/rollout/", "/actor_forward/", "/kda/kda_step/")
+    assert some("/rollout/", "/actor_forward/", "/mla/")
+    assert some("/rollout/", "/actor_forward/", "/moe/moe_router/")
+    assert some("/rollout/", "/actor_forward/", "/lm_head/")
+    assert some("/rollout/", "/core_reset/")
+    assert not some("/rollout/", "kda_chunk")
+    assert some("/loss_and_grad/", "/kda/kda_chunk/")
+    assert some("/loss_and_grad/", "/moe/moe_experts/")
+    # the backward pass keeps the scopes (the *_device_ms metrics count it)
+    for scope in ("/kda/kda_chunk/", "/mla/", "/moe/moe_experts/", "/lm_head/"):
+        assert some("/loss_and_grad/", "transpose(", scope), scope
+    after = introspect.process_record()["kda_sites"]
+    assert after["step"] > before["step"] and after["chunk"] > before["chunk"]
