@@ -331,10 +331,11 @@ def _forward_evaluated(fragment, apply_fn, params, rollout: Rollout):
     logp, entropy, values, core_end, aux = fragment(
         params, rollout.obs, rollout.done, rollout.init_core, rollout.actions
     )
-    # Every loss stops the gradient at the bootstrap value: stopping it
-    # here spares the backward pass of this step (and what it would keep).
-    _, boot_value, _ = jax.lax.stop_gradient(
-        apply_fn(params, rollout.bootstrap_obs, core_end)
+    # Every loss stops the gradient at the bootstrap value: stopping it on
+    # the way in spares this step's backward pass, what it would keep, and
+    # the tracing of either (its kernels then lower as in the rollout).
+    _, boot_value, _ = apply_fn(
+        *jax.lax.stop_gradient((params, rollout.bootstrap_obs, core_end))
     )
     values = jnp.concatenate([values, boot_value[None]], axis=0)
     return (logp, entropy), values, aux

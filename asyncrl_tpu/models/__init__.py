@@ -7,6 +7,7 @@ from asyncrl_tpu.models.networks import (
     build_model,
     is_recurrent,
     reset_core,
+    settle_core,
 )
 
 __all__ = [
@@ -18,4 +19,5 @@ __all__ = [
     "build_model",
     "is_recurrent",
     "reset_core",
+    "settle_core",
 ]

@@ -8,7 +8,8 @@ One function in two forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
 
 - ``apply(params, tokens [B], core) -> (logits [B, V], value [B], core)``:
   one token through the carry, the rollout's form. The CALLER resets the
-  carry where an episode ends (``models.networks.reset_core``).
+  carry where an episode ends (``models.networks.reset_core``) and settles
+  it before anything but the policy reads it (``settle_core``).
 - ``apply(params, tokens [T, B], done [T, B], core, actions [T, B],
   method="fragment") -> (logp, entropy, values [T, B], core, aux)``: the
   same function over a whole fragment from the fragment-initial carry, the
@@ -18,9 +19,14 @@ One function in two forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
   in the backward pass. ``actions=None`` returns the logits instead (tests).
 
 The carry (``SeqCore``) is a tuple with one entry per layer: a KDA layer's
-``{"S" [B, H, dk, dv] float32, "conv" [B, 3, 3*H*dk]}``, an MLA layer's
-``{"kv" [B, L, kv_lora + rope], "len" [B] int32}`` -- two kinds of state in
-one pytree, every leaf with the env axis first.
+``{"S" [B, H, dk, dv] float32, "conv" [B, 3, 3*H*dk], "fresh" [B] bool}``,
+an MLA layer's ``{"kv" [B, L, kv_lora + rope], "len" [B] int32}`` -- two
+kinds of state in one pytree, every leaf with the env axis first. A reset
+does not pass over ``S`` (134 MB a layer at the published widths): it sets
+``fresh``, and the next read of ``S``, in either form, takes zero there, as
+``len`` empties a latent cache whose rows stay. ``settle()`` spends the
+pending resets; a carry that leaves ``rollout.anakin.unroll`` or the
+fragment form is settled (``docs/ARCHITECTURE.md`` "Sequence policy").
 
 Precision: operands of the matrix products in ``compute_dtype``; KDA state,
 decays, cumulative sums, softmax, router scores, norms and the head's
@@ -107,18 +113,33 @@ class SeqCore:
 
     def reset(self, done):
         """The carry the next token starts from: zero where ``done`` [B].
-        A latent cache is emptied by its length; its rows stay."""
+        A latent cache is emptied by its length and a KDA state by
+        ``fresh``, both on their next read: the rows and the state stay."""
         with jax.named_scope("core_reset"):
-            def zero(x):
-                return jnp.where(
-                    done.reshape(-1, *([1] * (x.ndim - 1))), jnp.zeros_like(x), x
-                )
-
             return SeqCore(tuple(
-                {**layer, "len": zero(layer["len"])} if "kv" in layer
-                else jax.tree.map(zero, layer)
+                {**layer, "len": _zero_where(done, layer["len"])}
+                if "kv" in layer else
+                {**layer, "conv": _zero_where(done, layer["conv"]),
+                 "fresh": layer["fresh"] | done}
                 for layer in self.layers
             ))
+
+    def settle(self):
+        """The same carry with no reset pending: ``S`` zero where ``fresh``,
+        ``fresh`` all false. For whoever reads ``"S"`` and is neither form
+        of the mixer (the learner's ``S0`` is safe either way)."""
+        with jax.named_scope("core_reset"):
+            return SeqCore(tuple(
+                layer if "kv" in layer else
+                {**layer, "S": _zero_where(layer["fresh"], layer["S"]),
+                 "fresh": jnp.zeros_like(layer["fresh"])}
+                for layer in self.layers
+            ))
+
+
+def _zero_where(done, x):
+    return jnp.where(
+        done.reshape(-1, *([1] * (x.ndim - 1))), jnp.zeros_like(x), x)
 
 
 # ------------------------------------------------------------------ pieces
@@ -180,15 +201,18 @@ def _kda_mixer(p, x, state, done, shape: SeqShape, dtype):
             rate.reshape(*x.shape[:-1], H, dk)
         )
         beta = jax.nn.sigmoid(_dot(x, p["beta"], dtype))
+        fresh = state["fresh"]
         if done is None:
-            S, o = kda.kda_step(state["S"], q, k, v, g, beta)
+            S, o = kda.kda_step(state["S"], q, k, v, g, beta, fresh)
         else:
             S, o = kda.kda_chunk(
-                state["S"], q, k, v, g, beta, done, chunk=shape.chunk, dtype=dtype
+                _zero_where(fresh, state["S"]), q, k, v, g, beta, done,
+                chunk=shape.chunk, dtype=dtype,
             )
         gate = jax.nn.sigmoid(_dot(_dot(x, p["g_down"], dtype), p["g_up"], dtype))
         o = _rms_norm(o, p["o_norm"], shape.eps).reshape(*x.shape[:-1], H * dk)
-        return _dot(o * gate, p["o"], dtype), {"S": S, "conv": conv}
+        return _dot(o * gate, p["o"], dtype), {
+            "S": S, "conv": conv, "fresh": jnp.zeros_like(fresh)}
 
 
 def _mla_project(p, x, shape: SeqShape, dtype):
@@ -376,6 +400,7 @@ class SeqPolicy:
                         (batch_size, s.kda_heads, s.kda_head_dim, s.kda_head_dim), F32
                     ),
                     "conv": jnp.zeros((batch_size, s.conv_width - 1, n), F32),
+                    "fresh": jnp.zeros((batch_size,), bool),
                 })
             else:
                 layers.append({
@@ -514,7 +539,7 @@ class SeqPolicy:
         T, B = tokens.shape
         h, core, loads = self._trunk(params, tokens, core, done)
         values = self._value(params, h)
-        core = core.reset(done[-1])
+        core = core.reset(done[-1]).settle()
         loads = jnp.stack(loads).astype(F32)  # [expert layers, held]
         aux = {
             "moe_load_max": jnp.max(loads),

@@ -313,6 +313,15 @@ def reset_core(core, done):
     return jax.tree.map(lambda c: c * keep[:, None], core)
 
 
+def settle_core(core):
+    """The carry with no reset pending. ``reset_core`` may leave a reset to
+    the policy's next read of the carry (``kimi_linear.SeqCore`` does, for
+    its KDA states); whoever hands a carry to anything but the policy (a
+    rollout's exit, a recorded ``init_core``) settles it first. The
+    identity for a carry that resets eagerly (an LSTM's ``(c, h)``)."""
+    return core.settle() if hasattr(core, "settle") else core
+
+
 def is_recurrent(model) -> bool:
     """A model that is called through a carry: ``apply(params, obs, core)``
     and ``initial_core(batch)``."""

@@ -211,7 +211,7 @@ class _ProcessRecord:
         self._compiles: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
         self._dropped = 0  # guarded-by: _lock
         self._pool_sites = {"kernel": 0, "fallback": 0}  # guarded-by: _lock
-        self._kda_sites = {"step": 0, "chunk": 0}  # guarded-by: _lock
+        self._kda_sites = {"step": 0, "step_kernel": 0, "chunk": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -283,8 +283,8 @@ def phase(name: str):
 def process_record() -> dict[str, Any]:
     """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
-    n}, "kda_sites": {"step": n, "chunk": n}}``: copies, oldest first,
-    ``perf_counter`` stamps (a compile event started at ``t_end -
+    n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n}}``: copies,
+    oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
     _RECORD.listen()
@@ -300,9 +300,11 @@ def count_pool_site(path: str) -> None:
 
 
 def count_kda_site(form: str) -> None:
-    """One KDA site of a program being traced took the one-token recurrence
-    (``"step"``) or the chunked fragment form (``"chunk"``): called by
-    ``ops/kda.py``, once per site and trace, nothing on a steady call."""
+    """One KDA site of a program took the one-token recurrence as its Pallas
+    kernel (``"step_kernel"``) or in plain ``jax.numpy`` (``"step"``), or
+    the chunked fragment form (``"chunk"``): called by ``ops/kda.py``, once
+    per site and trace (where the platform chose, once per site and program
+    lowered), nothing on a steady call."""
     _RECORD.count_kda_site(form)
 
 

@@ -45,10 +45,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.extend.core import Primitive
-from jax.interpreters import batching, mlir
 
 from asyncrl_tpu.obs import introspect
+from asyncrl_tpu.ops.site import site_primitive
 
 _LANE = 128
 # Sublane rows of one (rows, 128) tile, by itemsize.
@@ -288,23 +287,8 @@ def _kernel_bwd(pos: jax.Array, g: jax.Array, in_shape: tuple[int, ...],
 
 # -------------------------------------------------------------- the counter
 
-# Identity whose lowering is the one place that knows which path a
-# differentiated site ended on: the shape says it at trace time, the
-# platform only when the program is lowered, and then only the branch that
-# is kept is lowered at all.
-_site_p = Primitive("max_pool_site")
-_site_p.def_impl(lambda x, *, path: x)
-_site_p.def_abstract_eval(lambda x, *, path: x)
-batching.primitive_batchers[_site_p] = lambda args, dims, *, path: (
-    _site_p.bind(*args, path=path), dims[0])
-
-
-def _site_lowering(ctx, x, *, path):
-    introspect.count_pool_site(path)
-    return [x]
-
-
-mlir.register_lowering(_site_p, _site_lowering)
+# Which path a differentiated site ended on is known where it is lowered.
+_site_p = site_primitive("max_pool_site", introspect.count_pool_site)
 
 
 # ------------------------------------------------------------ the function

@@ -21,7 +21,11 @@ import jax.numpy as jnp
 from flax import struct
 
 from asyncrl_tpu.envs.core import Environment
-from asyncrl_tpu.models.networks import is_recurrent, reset_core
+from asyncrl_tpu.models.networks import (
+    is_recurrent,
+    reset_core,
+    settle_core,
+)
 from asyncrl_tpu.rollout.buffer import EpisodeStats, Rollout
 
 
@@ -244,6 +248,14 @@ def unroll(
         return new_carry, out
 
     final_state, outs = jax.lax.scan(step_fn, actor_state, None, length=unroll_len)
+    if recurrent:
+        # Inside the scan a reset may wait for the policy's next read of the
+        # carry; what leaves is settled (the next fragment's ``init_core``,
+        # ``agent.state``, a checkpoint). The identity for an LSTM's carry.
+        final_state = final_state.replace(
+            core=settle_core(final_state.core),
+            opp_core=settle_core(final_state.opp_core),
+        )
     (obs, actions, behaviour_logp, rewards, terminated, truncated,
      done_returns, done_lengths, dones, disc_returns) = outs
 

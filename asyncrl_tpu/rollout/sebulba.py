@@ -31,7 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from asyncrl_tpu.envs.core import Environment, EnvSpec
-from asyncrl_tpu.models.networks import is_recurrent, reset_core
+from asyncrl_tpu.models.networks import (
+    is_recurrent,
+    reset_core,
+    settle_core,
+)
 from asyncrl_tpu.ops import distributions
 from asyncrl_tpu.ops.normalize import normalize
 from asyncrl_tpu.obs import spans as span_names
@@ -612,7 +616,7 @@ class ActorThread(threading.Thread):
             # (the jitted inference applies the reset; mirror it here so the
             # recorded carry is the one the fragment actually starts from).
             if core is not None:
-                core = reset_core(core, jnp.asarray(done_prev))
+                core = settle_core(reset_core(core, jnp.asarray(done_prev)))
                 done_prev = np.zeros((B,), bool)
                 init_core = jax.tree.map(np.asarray, core)
             while not buffer.full:

@@ -4,6 +4,8 @@ ops/moe.py, envs/token_task.py) against its plain reference
 preset's sizes, in float32."""
 
 import dataclasses
+import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +17,7 @@ from asyncrl_tpu.configs import presets
 from asyncrl_tpu.envs import registry
 from asyncrl_tpu.learn import learner as learner_mod
 from asyncrl_tpu.models import kimi_linear
-from asyncrl_tpu.models.networks import build_model, reset_core
+from asyncrl_tpu.models.networks import build_model, reset_core, settle_core
 from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops import distributions, kda, moe
 from asyncrl_tpu.rollout.anakin import actor_init, unroll
@@ -194,6 +196,11 @@ def test_step_form_through_reset_core_matches_the_fragment_form(policy):
 
     core_s, (logits_s, values_s) = jax.lax.scan(
         step, model.initial_core(B), (tokens, done))
+    # env 1 ended on the last token: its reset waits for the next read, and
+    # whoever reads "S" instead settles the carry first
+    np.testing.assert_array_equal(core_s.layers[0]["fresh"], done[-1])
+    assert float(jnp.max(jnp.abs(core_s.layers[0]["S"][1]))) > 0
+    core_s = settle_core(core_s)
     logits_f, values_f, core_f, _ = model.apply(
         variables, tokens, done, model.initial_core(B), method="fragment")
     np.testing.assert_allclose(logits_s, logits_f, atol=2e-4)
@@ -298,6 +305,212 @@ def test_chunked_kda_matches_the_recurrence(chunk):
     grads_c = jax.grad(scalar(chunked), argnums=range(6))(S0, q, k, v, g, beta)
     for a, b in zip(grads_c, grads_r):
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))) + 1e-6)
+
+
+# (e') the one-token recurrence as a kernel, and the reset taken on the read
+def step_operands(B, H, d, seed=11):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    norm = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    S = jax.random.normal(keys[0], (B, H, d, d))
+    q = norm(jax.random.normal(keys[1], (B, H, d))) * d ** -0.5
+    k = norm(jax.random.normal(keys[2], (B, H, d)))
+    v = jax.random.normal(keys[3], (B, H, d))
+    g = -jnp.exp(jax.random.uniform(keys[4], (B, H, d), minval=-7.0, maxval=1.6))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (B, H)))
+    return S, q, k, v, g, beta
+
+
+@pytest.mark.parametrize("B, H, fresh", [
+    (3, 8, (False, True, False)),  # a slice of the published heads
+    (2, 32, (True, False)),  # the published tile: 32 heads of [128, 128]
+    (4, 16, (False, False, False, False)),
+    (2, 8, (True, True)),
+])
+def test_the_step_kernel_is_the_plain_form_to_float32_rounding(B, H, fresh):
+    operands = step_operands(B, H, 128)
+    fresh = jnp.asarray(fresh)
+    S_k, o_k = kda._kernel_step(*operands, fresh, interpret=True)
+    S_p, o_p = kda._plain_step(*operands, fresh)
+    for mine, ref in ((S_k, S_p), (o_k, o_p)):
+        assert float(jnp.max(jnp.abs(mine - ref))) <= 1e-6 * float(jnp.max(jnp.abs(ref)))
+    # a fresh env starts from zero whatever S held: its state is k u^T alone
+    _, q, k, v, g, beta = operands
+    from_zero = kda._plain_step(jnp.zeros_like(S_p), q, k, v, g, beta, None)[0]
+    for b in np.flatnonzero(np.asarray(fresh)):
+        np.testing.assert_allclose(S_k[b], from_zero[b], atol=1e-6)
+    assert S_k.dtype == o_k.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("shape, dtype, fits", [
+    ((64, 32, 128, 128), jnp.float32, True),  # kimi_linear_rl's
+    ((1, 8, 256, 128), jnp.float32, True),
+    ((8, 2, 16, 16), jnp.float32, False),  # kimi_linear_tiny's
+    ((64, 32, 128, 64), jnp.float32, False),
+    ((64, 12, 128, 128), jnp.float32, False),  # an [H, dk] block of half tiles
+    ((64, 32, 128, 128), jnp.bfloat16, False),  # the state is float32
+    ((64, 512, 128, 128), jnp.float32, False),  # one env's block over VMEM
+    ((0, 32, 128, 128), jnp.float32, False),
+])
+def test_the_shapes_the_step_kernel_takes(shape, dtype, fits):
+    assert kda._kernel_fits(shape, dtype) == fits
+
+
+def kda_sites_since(before):
+    now = introspect.process_record()["kda_sites"]
+    return {k: now[k] - before[k] for k in now}
+
+
+@pytest.mark.parametrize("d, differentiated", [
+    (16, False), (16, True), (128, False), (128, True)])
+def test_off_the_tpu_and_at_small_widths_the_step_is_the_plain_form(d, differentiated):
+    """The fallback: by shape when the call is traced (d = 16), by platform
+    when it is lowered (d = 128, here a CPU), differentiated or not, with
+    the plain form's values and gradients, and counted as ``"step"``."""
+    operands = step_operands(2, 8, d)
+    fresh = jnp.asarray([True, False])
+    mix = jax.random.normal(jax.random.PRNGKey(3), (2, 8, d))
+
+    def scalar(f):
+        return lambda *a: jnp.sum(f(*a, fresh)[1] * mix) + jnp.sum(f(*a, fresh)[0])
+
+    wrap = (lambda f: jax.grad(scalar(f), argnums=range(6))) if differentiated \
+        else (lambda f: lambda *a: f(*a, fresh))
+    before = introspect.process_record()["kda_sites"]
+    mine = jax.jit(wrap(kda.kda_step))(*operands)
+    assert kda_sites_since(before) == {
+        "step": 2 if differentiated else 1, "step_kernel": 0, "chunk": 0}
+    ref = jax.jit(wrap(kda._plain_step))(*operands)
+    for a, b in zip(mine, ref):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+
+
+def test_the_kernels_vjp_is_the_plain_forms():
+    """What a differentiated call on a TPU runs: the kernel forward (here in
+    the interpreter), the plain form's backward."""
+    operands = step_operands(2, 8, 128)
+    fresh = jnp.asarray([False, True])
+    mix = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 128))
+    scalar = lambda f: lambda *a: jnp.sum(f(*a, fresh)[1] * mix) + jnp.sum(f(*a, fresh)[0])
+    with mock.patch.object(
+            kda, "_kernel_step", functools.partial(kda._kernel_step, interpret=True)):
+        mine = jax.grad(scalar(kda._kernel_step_vjp), argnums=range(6))(*operands)
+    ref = jax.grad(scalar(kda._plain_step), argnums=range(6))(*operands)
+    for a, b in zip(mine, ref):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+    # no gradient flows into a fresh env's old state
+    assert float(jnp.max(jnp.abs(mine[0][1]))) == 0 < float(jnp.max(jnp.abs(mine[0][0])))
+
+
+LAZY_RESET = kimi_linear.SeqCore.reset
+
+
+def eager_reset(self, done):
+    """What ``SeqCore.reset`` was before the reset moved to the read."""
+    return LAZY_RESET(self, done).settle()
+
+
+@pytest.fixture(scope="module")
+def fragments_ending_on_a_done(policy):
+    """Two consecutive fragments of the program's rollout, the first with
+    episode ends inside it **and on its last token**, under the lazy reset
+    and under an eager one. Run op by op: XLA's choice of fused
+    multiply-adds follows the fusion, so two programs agree to the last
+    bit only where neither is fused."""
+    env, model, variables = policy
+    dist = distributions.for_config(CFG, env.spec)
+
+    def roll(actor):
+        return unroll(model.apply, variables, env, actor, CFG.unroll_len, dist=dist)[:2]
+
+    def rollouts(seed, roll=roll, n=2):
+        actor = actor_init(env, CFG.num_envs, jax.random.PRNGKey(seed), model=model)
+        out = []
+        for _ in range(n):
+            actor, r = roll(actor)
+            out.append((actor, r))
+        return out
+
+    jitted = jax.jit(roll)
+    seed = next(  # the env draws the lengths: the policy ends no episode
+        s for s in range(1, 200)
+        if bool(jnp.any(rollouts(s, jitted, 1)[0][1].done[-1]))
+    )
+    with jax.disable_jit():
+        lazy = rollouts(seed)
+        with mock.patch.object(kimi_linear.SeqCore, "reset", eager_reset):
+            eager = rollouts(seed)
+    return lazy, eager
+
+
+def test_unroll_with_the_reset_on_the_read_is_the_eager_resets_to_the_last_bit(
+        policy, fragments_ending_on_a_done):
+    _, model, variables = policy
+    lazy, eager = fragments_ending_on_a_done
+    last = np.asarray(lazy[0][1].done[-1])
+    inside = np.asarray(lazy[0][1].done[:-1])
+    assert last.any() and not last.all() and inside.any()
+    for (actor_l, r_l), (actor_e, r_e) in zip(lazy, eager):
+        # the final carry, the next fragment's init_core, the log-probs
+        for mine, ref in zip(jax.tree.leaves((actor_l.core, r_l)),
+                             jax.tree.leaves((actor_e.core, r_e))):
+            np.testing.assert_array_equal(mine, ref)
+        resets = [
+            float(model.apply(variables, r.obs, r.done, r.init_core, r.actions,
+                              method="fragment")[4]["episode_resets"])
+            for r in (r_l, r_e)
+        ]
+        assert resets[0] == resets[1] == float(jnp.sum(r_l.done))
+        # nothing pending in what leaves unroll
+        for layer in actor_l.core.layers:
+            if "S" in layer:
+                assert not bool(jnp.any(layer["fresh"]))
+    # the carry that left the first fragment is exactly zero where its last
+    # token ended an episode, and only there
+    for layer in lazy[1][1].init_core.layers:
+        if "S" in layer:
+            gone = np.asarray(jnp.max(jnp.abs(layer["S"]), axis=(1, 2, 3))) == 0
+            np.testing.assert_array_equal(gone, last)
+            assert float(jnp.max(jnp.abs(layer["conv"][last]))) == 0
+
+
+def test_the_fragment_form_from_a_settled_carry_is_the_scanned_step_form(
+        policy, fragments_ending_on_a_done):
+    """``kda_chunk`` takes the settled ``init_core`` as it is: the learner's
+    recompute of both fragments' log-probs is the rollout's."""
+    _, model, variables = policy
+    lazy, _ = fragments_ending_on_a_done
+    for _, r in lazy:
+        logp, _, _, _, _ = model.apply(
+            variables, r.obs, r.done, r.init_core, r.actions, method="fragment")
+        np.testing.assert_allclose(logp, r.behaviour_logp, atol=2e-5)
+
+
+def test_both_forms_read_a_carry_with_a_reset_pending_as_zero(policy):
+    """A carry recorded mid-stream (``reset_core`` and no ``settle_core``)
+    is never read stale: the fragment form takes ``fresh`` on its ``S0``,
+    the step form on its read."""
+    _, model, variables = policy
+    T, B = 8, 3
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (T, B), 0, TINY.vocab)
+    done = jnp.zeros((T, B), bool)
+    core = model.initial_core(B)
+    for t in range(4):  # a carry with something in it
+        _, _, core = model.apply(variables, tokens[t], core)
+    pending = reset_core(core, jnp.asarray([False, True, False]))
+    settled = settle_core(pending)
+    assert float(jnp.max(jnp.abs(pending.layers[0]["S"][1]))) > 0
+    assert float(jnp.max(jnp.abs(settled.layers[0]["S"][1]))) == 0
+    assert bool(jnp.all(pending.layers[0]["fresh"] == jnp.asarray([False, True, False])))
+    # a second reset before any read keeps the first
+    again = reset_core(pending, jnp.zeros((B,), bool))
+    np.testing.assert_array_equal(again.layers[0]["fresh"], pending.layers[0]["fresh"])
+    for form in (
+        lambda c: model.apply(variables, tokens, done, c, method="fragment")[:3],
+        lambda c: model.apply(variables, tokens[0], c),
+    ):
+        for mine, ref in zip(jax.tree.leaves(form(pending)),
+                             jax.tree.leaves(form(settled))):
+            np.testing.assert_array_equal(mine, ref)
 
 
 # (f) the preset trains on the normal path

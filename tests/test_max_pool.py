@@ -187,3 +187,51 @@ def test_the_learner_geometry_compiles_to_six_kernels_and_no_copy(one_chip):
              if sum(size(d) in activations | {8448 * 11 * 11 * 32}
                     for d in re.findall(r"bf16\[([\d,]+)\]", outs)) >= 2]
     assert len(twice) <= 1, twice
+
+
+# The other kernel of the main path compiled for the described chip lives in
+# this file too: only one process may describe a chip, and the workers of a
+# test run are given whole files.
+def test_the_one_token_kda_step_compiles_to_one_in_place_kernel_under_its_scope(
+        one_chip):
+    """``kimi_linear_rl``'s KDA widths, one layer: the rollout's one-token
+    form lowers the recurrence as the Mosaic kernel, under ``/kda/kda_step/``
+    (``kda_step_device_ms``, ``kda_device_ms`` and ``kda_step_roofline``
+    read that path), updating the state in place."""
+    from asyncrl_tpu.models import kimi_linear
+
+    shape = kimi_linear.SeqShape(
+        hidden=256, vocab=512, layers=("kda+dense",),
+        kda_heads=32, kda_head_dim=128,
+        mla_heads=2, qk_nope=16, qk_rope=8, v_head=16, kv_lora=24,
+        dense_ffn=256, expert_ffn=32, num_experts=8, held_experts=(0,),
+        top_k=2, routed_scale=1.0, max_positions=32,
+    )
+    model = kimi_linear.SeqPolicy(shape, compute_dtype=jnp.bfloat16)
+    B = 64  # 537 MB of state: XLA cannot park it in VMEM around the call
+    variables, core = jax.eval_shape(
+        lambda: (model.init(jax.random.key(0)), model.initial_core(B)))
+    variables, tokens, core = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables, jax.ShapeDtypeStruct((B,), jnp.int32), core))
+
+    def sites():
+        return introspect.process_record()["kda_sites"]
+
+    before = sites()
+    text = jax.jit(model.apply, donate_argnums=2).lower(
+        variables, tokens, core).compile().as_text()
+    assert {k: v - before[k] for k, v in sites().items()} == {
+        "step": 0, "step_kernel": 1, "chunk": 0}
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 1
+    (call,) = calls
+    assert re.search(r'op_name="[^"]*/kda/kda_step/[^"]*pallas_call', call), call
+    # the state's operand is the state's result
+    assert "output_to_operand_aliasing" in call, call
+    # and nothing else passes over it: no copy, no select the size of the state
+    state = f"f32[{B},32,128,128]"
+    passes = re.findall(rf"= {re.escape(state)}\S* (\S+?)\(", text)
+    assert "get-tuple-element" in passes  # the kernel's own result
+    assert set(passes) <= {"parameter", "get-tuple-element", "bitcast"}, passes

@@ -307,3 +307,31 @@ def test_the_sequence_policy_step_names_the_scopes_a_profile_reads():
         assert some("/loss_and_grad/", "transpose(", scope), scope
     after = introspect.process_record()["kda_sites"]
     assert after["step"] > before["step"] and after["chunk"] > before["chunk"]
+
+
+def test_kda_sites_count_the_one_token_form_once_per_site_and_program():
+    """``kda_sites``: ``step_kernel`` where the Pallas kernel was lowered,
+    ``step`` where the plain form was: by shape when traced, by platform
+    when lowered (sites of one shape are still counted each: the site's
+    lowering is not cached), nothing on a steady call."""
+    from asyncrl_tpu.ops import kda
+
+    def operands(d):
+        z = jnp.zeros((2, 8, d))
+        return jnp.zeros((2, 8, d, d)), z, z, z, z, jnp.zeros((2, 8))
+
+    def two_sites(S, *xs):
+        S, o = kda.kda_step(S, *xs)
+        return kda.kda_step(S, *xs, jnp.asarray([True, False]))[0], o
+
+    def since(before):
+        now = introspect.process_record()["kda_sites"]
+        return {k: now[k] - before[k] for k in now}
+
+    for d in (16, 128):  # the tiny preset's width; the published one, on a CPU
+        before = introspect.process_record()["kda_sites"]
+        step = jax.jit(two_sites)
+        step(*operands(d))
+        assert since(before) == {"step": 2, "step_kernel": 0, "chunk": 0}, d
+        step(*operands(d))  # a steady call counts nothing
+        assert since(before) == {"step": 2, "step_kernel": 0, "chunk": 0}, d
