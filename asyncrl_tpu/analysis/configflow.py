@@ -42,21 +42,15 @@ from asyncrl_tpu.analysis.core import Finding, Project, SourceModule
 KNOWN_ENV_VARS = {
     "ASYNCRL_FAULTS",         # utils/faults.py — fault-injection grammar
     "ASYNCRL_DEBUG_SYNC",     # utils/debug.py — runtime invariant checks
-    "ASYNCRL_BENCH_HISTORY",  # utils/bench_history.py — ledger redirect
-    "ASYNCRL_FORCE_CPU",      # bench.py — device selection override
-    "ASYNCRL_SMOKE_RECORD",   # scripts/perf_smoke.sh — ledger opt-in
-    "ASYNCRL_SMOKE_UPDATES",  # scripts/perf_smoke harness sizing
-    "ASYNCRL_SMOKE_TOLERANCE",  # scripts/perf_smoke pass threshold
-    "ASYNCRL_FUSED_AB_TOLERANCE",  # bench.py fused_ab pass threshold
+    "ASYNCRL_FORCE_CPU",      # utils/runtime.py require_tpu — explicit CPU opt-in
+    "ASYNCRL_SMOKE_UPDATES",  # scripts/*_smoke.sh harness sizing
     "ASYNCRL_CHAOS_STEPS",    # scripts/chaos_smoke.sh harness sizing
     "ASYNCRL_TRACE",          # obs/trace.py — arm pipeline tracing
     "ASYNCRL_TRACE_RING",     # obs/trace.py — per-thread ring capacity
     "ASYNCRL_REQUEST_TRACE",  # obs/requests.py — request hop journaling
     "ASYNCRL_RUN_DIR",        # obs/__init__.py — observability output dir
-    "ASYNCRL_TRACE_TOLERANCE",  # scripts/trace_smoke.sh overhead threshold
     "ASYNCRL_REPLAY",         # api/sebulba_trainer.py — replay-ring depth
     "ASYNCRL_SERVE",          # api/sebulba_trainer.py — serve-core toggle
-    "ASYNCRL_SERVE_TOLERANCE",  # scripts/serve_smoke.sh throughput budget
     "ASYNCRL_SERVE_P95_MS",   # scripts/serve_smoke.sh p95 latency gate
     "ASYNCRL_OBS_PORT",       # obs/http.py — exposition endpoint port
     "ASYNCRL_OBS_HOST",       # obs/http.py — exposition bind host
@@ -64,7 +58,6 @@ KNOWN_ENV_VARS = {
     "ASYNCRL_GATEWAY_QPS",    # scripts/gateway_smoke.sh load-gen rate
     "ASYNCRL_GATEWAY_P99_MS",  # scripts/gateway_smoke.sh p99 latency gate
     "ASYNCRL_INTROSPECT",     # obs/introspect.py — training introspection
-    "ASYNCRL_INTROSPECT_TOLERANCE",  # scripts/introspect_smoke.sh budget
     "ASYNCRL_ELASTIC",        # api/sebulba_trainer.py — elastic-runtime toggle
     "ASYNCRL_RESUME",         # runtime/durability.py — crash-consistent resume
     "ASYNCRL_DRAIN_GRACE_S",  # runtime/durability.py — preemption drain budget

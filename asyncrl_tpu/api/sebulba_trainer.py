@@ -261,7 +261,7 @@ class SebulbaTrainer:
         # fragments straight into preallocated [K, T, B, ...] slabs and
         # the drain transfers whole slabs, double-buffered against the
         # learner's compute. config.overlap_h2d=False keeps the legacy
-        # copy-and-stack path (A/B-compared by scripts/perf_smoke.sh).
+        # copy-and-stack path (A/B-compared by tests/test_perf_smoke.py).
         # Under elasticity the ring sits behind a RingSwapHolder so a
         # fleet-scale event can install a right-sized ring while in-flight
         # leases finish on the old one.

@@ -52,7 +52,7 @@ atari_impala = pong_impala.replace(
     env_id="JaxPongPixels-v0", num_envs=1024, torso="impala_cnn"
 )
 # Wide-channel variant (64/128/128 vs the parity 16/32/32): the IMPALA-CNN's
-# narrow output channels cap MXU lane utilization at ~22% (docs/MFU.md), so
+# narrow output channels leave most of the MXU's 128 lanes empty, so
 # per-chip pixel throughput at high MFU requires a wider torso. NOT a parity
 # config — it trains a bigger model — but the principled option when raw
 # pixel fps/chip is the goal rather than reference-equivalent training.
@@ -229,7 +229,7 @@ pendulum_native_ppo = Config(
 # of the agent itself, promoted every selfplay_refresh updates; greedy eval
 # still measures vs the calibrated scripted tracker (the 18.0-bar metric).
 # EXPERIMENTAL — measured NET-NEGATIVE for the flagship 18.0 metric at a
-# matched budget (BENCH_HISTORY selfplay_vs_direct: ladder 2.0 vs direct
+# matched budget (scripts/selfplay_experiment.py: ladder 2.0 vs direct
 # 11.5 at 400M frames). Do not use for time-to-target work; see
 # docs/ARCHITECTURE.md "Self-play" for the descope decision.
 pong_selfplay = pong_impala.replace(
@@ -336,7 +336,7 @@ pong_t2t_ale4 = pong_t2t_ale.replace(
 # bound at 1-3x => 18-54B decisions, i.e. ~110-330 chip-hours at the
 # 45,984 fps the 1024-fit geometry measured on the previous runtime. A
 # multi-session accumulation arm (runs/pong18_pixels): each session banks
-# curve + reached=false rows, and the MFU work (docs/MFU.md) is what
+# curve + reached=false rows, and the MFU work (ROADMAP A1) is what
 # shrinks the wall-clock denominator.
 pong_pixels_t2t = pong_t2t.replace(
     env_id="JaxPongPixels-v0",

@@ -96,8 +96,8 @@ def _arm_requests(config, run_dir: str | None) -> None:
 
 
 def _platform() -> str | None:
-    """The JAX backend platform for the timeseries meta (doctor matches
-    BENCH_HISTORY rows on it). Lazy + failure-tolerant: obs must stay
+    """The JAX backend platform for the timeseries meta (the doctor's
+    report names it). Lazy + failure-tolerant: obs must stay
     importable (and setup must succeed) without a working jax install."""
     # lint: broad-except-ok(metadata enrichment only; a broken jax backend must not break observability setup)
     try:

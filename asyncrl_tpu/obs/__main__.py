@@ -7,8 +7,8 @@ section is analyzed). ``validate`` checks the trace_event schema
 (``obs.export.validate_trace``) and exits 1 on any violation — the gate
 ``scripts/trace_smoke.sh`` runs. ``doctor`` replays a recorded run_dir's
 timeseries + forensics into a health report (detector timeline,
-bottleneck attribution, BENCH_HISTORY regression verdict) and exits 1 on
-a throughput regression — the gate ``scripts/health_smoke.sh`` runs.
+bottleneck attribution) and exits 1 when a detector fired — the gate
+``scripts/health_smoke.sh`` runs.
 ``explain`` renders request hop journals from a run_dir's
 ``requests.jsonl`` as budget waterfalls — one journal by trace id, or
 the ``--worst N`` set (non-200 verdicts first, then by latency); exits 2
@@ -66,27 +66,11 @@ def main(argv: list[str] | None = None) -> int:
     p_doctor = sub.add_parser(
         "doctor",
         help="offline run-health report for a recorded run_dir "
-        "(detector timeline + bottleneck attribution + BENCH_HISTORY "
-        "regression verdict; exits 1 on regression)",
+        "(detector timeline + bottleneck attribution; exits 1 when a "
+        "detector fired)",
     )
     p_doctor.add_argument(
         "run_dir", help="run directory holding timeseries.jsonl"
-    )
-    p_doctor.add_argument(
-        "--preset", default=None,
-        help="BENCH_HISTORY preset to compare against (default: inferred "
-        "from the run's env_id/algo)",
-    )
-    p_doctor.add_argument(
-        "--fps-tolerance", type=float,
-        default=doctor_mod.DEFAULT_FPS_TOLERANCE,
-        help="regression bar: run best fps must reach this fraction of "
-        "the baseline row (default %(default)s)",
-    )
-    p_doctor.add_argument(
-        "--bench-history", default=None,
-        help="ledger path (default: BENCH_HISTORY.json, or "
-        "ASYNCRL_BENCH_HISTORY when set)",
     )
     p_explain = sub.add_parser(
         "explain",
@@ -116,12 +100,7 @@ def main(argv: list[str] | None = None) -> int:
         return code
 
     if args.cmd == "doctor":
-        text, code = doctor_mod.diagnose(
-            args.run_dir,
-            preset=args.preset,
-            tolerance=args.fps_tolerance,
-            history_path=args.bench_history,
-        )
+        text, code = doctor_mod.diagnose(args.run_dir)
         print(text, file=sys.stderr if code == 2 else sys.stdout)
         return code
 

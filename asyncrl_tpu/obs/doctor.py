@@ -24,14 +24,10 @@ span export) — into one report:
 4. **Bottleneck attribution**: the stall-attribution table from the run's
    newest trace export (falling back to the newest flight dump's embedded
    trace) — the ``obs report`` analysis inlined.
-5. **Regression verdict**: the run's best window throughput against the
-   matching BENCH_HISTORY.json rows (preset- and platform-matched,
-   newest row wins) with a tolerance fraction — "did this PR regress
-   perf" as a command, not archaeology.
 
-Exit code: 0 clean (or no baseline to compare against — absence of
-evidence is reported, never treated as regression), 1 when the regression
-verdict fires, 2 when the run_dir has no readable timeseries.
+Exit code: 0 clean, 1 when a detector fired (recorded live or replayed),
+2 when the run_dir has no readable timeseries. Speed is not judged here:
+that is the benchmark's (``benchmarks/``, ``PERF.md``).
 """
 
 from __future__ import annotations
@@ -43,88 +39,12 @@ from typing import Any
 
 from asyncrl_tpu.obs import health, report, timeseries
 
-# A run "regresses" when its best window fps falls below this fraction of
-# the baseline row. Generous by default: shared/noisy hosts swing real
-# throughput run to run (see perf_smoke.sh); tighten on quiet hardware.
-DEFAULT_FPS_TOLERANCE = 0.5
-
 
 def load_run(run_dir: str) -> dict[str, Any]:
     """{"meta", "samples", "events"} from ``<run_dir>/timeseries.jsonl``.
     Raises FileNotFoundError when the run recorded no timeseries."""
     path = os.path.join(run_dir, timeseries.FILENAME)
     return timeseries.read_jsonl(path)
-
-
-def _infer_preset(meta: dict[str, Any]) -> str | None:
-    """The preset whose (env_id, algo) matches the run's — how doctor
-    joins a run_dir to BENCH_HISTORY rows without the run knowing its
-    preset name. First declaration order wins on ties."""
-    env_id, algo = meta.get("env_id"), meta.get("algo")
-    if not env_id or not algo:
-        return None
-    from asyncrl_tpu.configs import presets
-
-    for name, cfg in presets.PRESETS.items():
-        if cfg.env_id == env_id and cfg.algo == algo:
-            return name
-    return None
-
-
-def best_fps(samples: list[dict[str, Any]]) -> float:
-    """The run's best window throughput — best-of-N, the same discipline
-    every smoke harness uses against scheduler noise."""
-    values = timeseries.series_of(samples, "fps")
-    return max(values) if values else 0.0
-
-
-def regression_verdict(
-    meta: dict[str, Any],
-    samples: list[dict[str, Any]],
-    preset: str | None = None,
-    tolerance: float = DEFAULT_FPS_TOLERANCE,
-    history_path: str | None = None,
-) -> dict[str, Any]:
-    """Compare the run against its matching BENCH_HISTORY rows.
-
-    verdict: "ok" | "regressed" | "no-baseline" (no matching row, or the
-    run recorded no fps — reported, never conflated with regression).
-    """
-    from asyncrl_tpu.utils import bench_history
-
-    preset = preset or _infer_preset(meta)
-    run_fps = best_fps(samples)
-    out: dict[str, Any] = {
-        "verdict": "no-baseline",
-        "preset": preset,
-        "platform": meta.get("platform"),
-        "run_fps": round(run_fps),
-        "tolerance": tolerance,
-        "baseline_fps": None,
-        "baseline_ts": None,
-    }
-    if preset is None or run_fps <= 0:
-        return out
-    rows = [
-        row for row in bench_history.load(history_path)
-        if row.get("kind") == "throughput"
-        and row.get("preset") == preset
-        and (
-            meta.get("platform") is None
-            or row.get("platform") == meta.get("platform")
-        )
-        and isinstance(row.get("frames_per_sec"), (int, float))
-    ]
-    if not rows:
-        return out
-    baseline = rows[-1]  # newest matching row: the last known good
-    out["baseline_fps"] = baseline["frames_per_sec"]
-    out["baseline_ts"] = baseline.get("ts")
-    out["verdict"] = (
-        "ok" if run_fps >= tolerance * float(baseline["frames_per_sec"])
-        else "regressed"
-    )
-    return out
 
 
 def _latest_trace_doc(run_dir: str) -> tuple[dict[str, Any] | None, str | None]:
@@ -292,12 +212,7 @@ def _timeline(
     return out
 
 
-def diagnose(
-    run_dir: str,
-    preset: str | None = None,
-    tolerance: float = DEFAULT_FPS_TOLERANCE,
-    history_path: str | None = None,
-) -> tuple[str, int]:
+def diagnose(run_dir: str) -> tuple[str, int]:
     """(report text, exit code) for a recorded run_dir."""
     try:
         run = load_run(run_dir)
@@ -375,31 +290,10 @@ def diagnose(
                 "in the pipeline blocked long enough to attribute"
             )
 
-    lines.append("")
-    lines.append("== regression verdict (vs BENCH_HISTORY) ==")
-    verdict = regression_verdict(
-        meta, samples, preset=preset, tolerance=tolerance,
-        history_path=history_path,
-    )
-    if verdict["verdict"] == "no-baseline":
-        lines.append(
-            f"no baseline: preset={verdict['preset']} "
-            f"platform={verdict['platform']} matched no throughput row "
-            f"(run best fps {verdict['run_fps']:,})"
-        )
-    else:
-        lines.append(
-            f"preset={verdict['preset']} platform={verdict['platform']}: "
-            f"run best fps {verdict['run_fps']:,} vs baseline "
-            f"{verdict['baseline_fps']:,} ({verdict['baseline_ts']}), "
-            f"tolerance {verdict['tolerance']:g}x -> {verdict['verdict'].upper()}"
-        )
-
-    code = 1 if verdict["verdict"] == "regressed" else 0
+    code = 1 if timeline else 0
     lines.append("")
     lines.append(
-        f"verdict: {'REGRESSED' if code else 'CLEAN'} "
-        f"({len(timeline)} health event(s), "
-        f"throughput {verdict['verdict']})"
+        f"verdict: {'DEGRADED' if code else 'CLEAN'} "
+        f"({len(timeline)} health event(s))"
     )
     return "\n".join(lines), code

@@ -250,7 +250,7 @@ class Config:
     # slabs (no per-fragment emit copy, no per-drain np.stack) and the
     # drain thread transfers slab i+1 while the learner computes update i
     # (double-buffered H2D). Off = the legacy copy-and-stack path, kept for
-    # A/B measurement (scripts/perf_smoke.sh) and as the paranoia fallback;
+    # A/B comparison (tests/test_perf_smoke.py) and as the paranoia fallback;
     # both paths are bit-identical on fragment content (tests/test_staging).
     overlap_h2d: bool = True
     # Staging-ring depth in SLABS (each slab holds updates_per_call
@@ -548,7 +548,7 @@ class Config:
     # V-trace/GAE reverse-scan implementation (ops/scan.py). "auto"
     # resolves to "associative" everywhere. The Pallas VMEM kernel IS
     # real-chip validated (scripts/validate_pallas_tpu.py on TPU v5 lite,
-    # 2026-07-31, BENCH_HISTORY kind=kernel_validation: accuracy on par
+    # 2026-07-31, its kernel_validation lines: accuracy on par
     # with the associative tree against a float64 truth on all five preset
     # geometries) — it stays OPT-IN because its measured win is only
     # ~1.0-1.2x on a scan that is itself a small slice of the update, not

@@ -76,7 +76,7 @@ def require_tpu(tool: str) -> str:
     exit nonzero. CPU only when asked for explicitly (``ASYNCRL_FORCE_CPU=1``
     — tier-1 and the CPU smokes use it); the returned platform is then
     ``"cpu"`` and every metric the caller prints carries that label
-    (``bench_history.device_entry``). Never a silent switch."""
+    (``device_entry``). Never a silent switch."""
     import jax
 
     if os.environ.get(FORCE_CPU_ENV, "") not in ("", "0"):
@@ -97,3 +97,16 @@ def require_tpu(tool: str) -> str:
         )
         sys.exit(4)
     return platform
+
+
+def device_entry() -> dict:
+    """Platform/device fields of the current JAX backend: the label on
+    every record an entry point prints."""
+    import jax
+
+    d = jax.devices()[0]
+    return {
+        "platform": d.platform,
+        "device_kind": d.device_kind,
+        "device_count": jax.device_count(),
+    }

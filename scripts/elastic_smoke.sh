@@ -16,19 +16,13 @@
 #      crash), and /healthz — read over HTTP from the live exposition
 #      endpoint — reporting ok after the transitions.
 #
-# ASYNCRL_SMOKE_RECORD=1 appends a kind="robustness" probe="elastic_ab"
-# row to BENCH_HISTORY.json with the static-vs-elastic fps and the
-# transition counts.
-#
 # Usage: scripts/elastic_smoke.sh                  # CPU, ~2 min
 #        ASYNCRL_SMOKE_UPDATES=48 scripts/elastic_smoke.sh
-#        ASYNCRL_SMOKE_RECORD=1 scripts/elastic_smoke.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 UPDATES="${ASYNCRL_SMOKE_UPDATES:-24}"
-RECORD="${ASYNCRL_SMOKE_RECORD:-0}"
 OUT_DIR="$(mktemp -d)"
 trap 'rm -rf "$OUT_DIR"' EXIT
 
@@ -76,7 +70,7 @@ def run(elastic: bool):
     return steps / elapsed, history
 
 
-# Discarded in-process warm-up (the introspect_smoke/perf_smoke
+# Discarded in-process warm-up (the test_perf_smoke.py
 # methodology): without it the first arm pays the JIT compile cost and
 # the second runs on the warm cache, writing a phantom fps gap into the
 # recorded ledger row for an identical workload.
@@ -196,12 +190,12 @@ with open(f"{out_dir}/elastic.json", "w") as f:
 EOF
 unset ASYNCRL_FAULTS
 
-# --------------------------------------------------------------- ledger
-python - "$UPDATES" "$OUT_DIR" "$RECORD" <<'EOF'
+# -------------------------------------------------------------- summary
+python - "$OUT_DIR" <<'EOF'
 import json
 import sys
 
-updates, out_dir, record = sys.argv[1], sys.argv[2], sys.argv[3]
+out_dir = sys.argv[1]
 identity = json.load(open(f"{out_dir}/identity.json"))
 scaled = json.load(open(f"{out_dir}/elastic.json"))
 print(
@@ -210,21 +204,4 @@ print(
     f"{scaled['fps_elastic_scaled']:,.0f} fps "
     f"({scaled['scale_up']} up / {scaled['scale_down']} down)"
 )
-if record not in ("", "0"):
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "robustness",
-        "probe": "elastic_ab",
-        "preset": "cartpole_impala(sebulba tiny)",
-        **bench_history.device_entry(),
-        "updates": int(updates),
-        "fps_static": round(identity["fps_static"]),
-        "fps_elastic_quiet": round(identity["fps_elastic_quiet"]),
-        "fps_elastic_scaled": round(scaled["fps_elastic_scaled"]),
-        "scale_up": scaled["scale_up"],
-        "scale_down": scaled["scale_down"],
-        "healthz": "ok",
-    })
-    print("elastic_smoke: recorded", entry["ts"])
 EOF

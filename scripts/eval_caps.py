@@ -61,12 +61,11 @@ def main() -> int:
 
     from asyncrl_tpu.api.factory import make_agent
     from asyncrl_tpu.configs import presets
-    from asyncrl_tpu.utils import bench_history
     from asyncrl_tpu.utils.config import override
 
     if any(o.startswith("pong_max_steps=") for o in overrides):
         # The script's whole contract is the fixed both-cap sweep; an
-        # override would run some third cap while the ledger rows still
+        # override would run some third cap while the printed rows still
         # claim the loop's caps.
         print(
             "eval_caps: pong_max_steps is set by the sweep itself and "
@@ -75,7 +74,7 @@ def main() -> int:
         )
         return 2
 
-    dev = bench_history.device_entry()
+    dev = runtime.device_entry()
     for cap in CAPS:
         # Overrides first, the sweep's own fields last — a user override
         # must never displace the cap the row's metadata records.
@@ -98,22 +97,20 @@ def main() -> int:
         finally:
             trainer.close()
         returns = np.asarray(returns, np.float64)
-        entry = bench_history.record(
-            {
-                "kind": "eval_cap",
-                "preset": preset_name,
-                **dev,
-                "run_dir": run_dir,
-                "pong_max_steps": cap,
-                "ale_faithful_cap": cap >= 27_000,
-                "episodes": int(returns.size),
-                "eval_return": round(float(returns.mean()), 3),
-                "eval_return_std": round(float(returns.std()), 3),
-                "eval_return_min": round(float(returns.min()), 3),
-                "eval_return_max": round(float(returns.max()), 3),
-                "frac_ge_18": round(float((returns >= 18.0).mean()), 3),
-            }
-        )
+        entry = {
+            "kind": "eval_cap",
+            "preset": preset_name,
+            **dev,
+            "run_dir": run_dir,
+            "pong_max_steps": cap,
+            "ale_faithful_cap": cap >= 27_000,
+            "episodes": int(returns.size),
+            "eval_return": round(float(returns.mean()), 3),
+            "eval_return_std": round(float(returns.std()), 3),
+            "eval_return_min": round(float(returns.min()), 3),
+            "eval_return_max": round(float(returns.max()), 3),
+            "frac_ge_18": round(float((returns >= 18.0).mean()), 3),
+        }
         print(json.dumps(entry))
     return 0
 

@@ -31,23 +31,16 @@
 #     version, zero generation mixing throughout, and the client sees no
 #     availability gap beyond the failover budget (sheds allowed,
 #     unavailability not).
-#   Act 5 — request tracing (asyncrl_tpu/obs/requests.py): two scenes.
-#     Scene A: journaling ARMED over a replicated fleet under two-tenant
+#   Act 5 — request tracing (asyncrl_tpu/obs/requests.py):
+#     journaling ARMED over a replicated fleet under two-tenant
 #     QPS with a replica KILL mid-run; gates: the kill fired, journals
 #     persisted to requests.jsonl, `obs explain --worst 5` renders, and
 #     every worst-5 journal names a known deciding stage with its level-0
-#     segments summing to its latency within tolerance. Scene B: an
-#     on/off A/B of the same sequential wire load; gate: armed-vs-
-#     disarmed median latency ratio under ASYNCRL_TRACE_AB_MAX (default
-#     1.15x — a noise bar, not a budget: the journal is a few dict
-#     appends per request). ASYNCRL_SMOKE_RECORD=1 appends the A/B as a
-#     kind="observability" probe="request_trace_ab" BENCH_HISTORY row.
+#     segments summing to its latency within tolerance.
 #
 # Usage: scripts/gateway_smoke.sh                  # CPU, ~2-3 min
 #        ASYNCRL_SMOKE_UPDATES=32 scripts/gateway_smoke.sh
 #        ASYNCRL_GATEWAY_QPS=100 ASYNCRL_GATEWAY_P99_MS=500 ...
-#        ASYNCRL_SMOKE_RECORD=1 scripts/gateway_smoke.sh  # append the A/B
-#          as a kind="robustness" probe="gateway_ab" BENCH_HISTORY row
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -55,9 +48,8 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 UPDATES="${ASYNCRL_SMOKE_UPDATES:-24}"
 QPS="${ASYNCRL_GATEWAY_QPS:-50}"
 P99_BUDGET_MS="${ASYNCRL_GATEWAY_P99_MS:-1500}"
-RECORD="${ASYNCRL_SMOKE_RECORD:-0}"
 
-python - "$UPDATES" "$QPS" "$P99_BUDGET_MS" "$RECORD" <<'EOF'
+python - "$UPDATES" "$QPS" "$P99_BUDGET_MS" <<'EOF'
 import json
 import sys
 import tempfile
@@ -75,10 +67,8 @@ from asyncrl_tpu.serve import (
 
 updates, qps = int(sys.argv[1]), float(sys.argv[2])
 p99_budget_ms = float(sys.argv[3])
-record = sys.argv[4] not in ("", "0")
 NUM_ENVS, UNROLL, THREADS = 16, 16, 2
 steps = updates * NUM_ENVS * UNROLL
-ledger = {}
 
 
 def base_cfg(**overrides):
@@ -128,7 +118,6 @@ if not any(k.startswith("gateway") for k in hist_idle[-1]):
     sys.exit("gateway_smoke FAILED (act 1): mounted gateway exported no keys")
 print(f"gateway_smoke act 1 OK: {len(hist_off)} windows loss-bit-identical; "
       "off leaks zero gateway keys")
-ledger["act1_bit_identical"] = True
 
 
 # --------------------------------------------------- act 2: sustained QPS
@@ -173,7 +162,7 @@ class LoadGen:
         """Client-observed p99 over the steady state: the first requests
         pay the one-time jit compile of the external batch shape (a
         cold-start cost, not a serving-latency property) and are
-        excluded, the perf_smoke warm-up discipline applied per wire."""
+        excluded, the test_perf_smoke.py warm-up discipline applied per wire."""
         steady = self.latencies_ms[warmup:]
         if not steady:
             return 0.0
@@ -245,14 +234,6 @@ if last.get("gateway_breaker_opened", 0) > 0:
     sys.exit("gateway_smoke FAILED (act 2): a circuit breaker opened")
 print("gateway_smoke act 2 OK: sustained QPS under SLO while training, "
       "weights swapping live")
-ledger.update({
-    "act2_fps": round(fps),
-    "act2_served": served,
-    "act2_generations": len(generations),
-    "act2_gold_p99_ms": round(gold_p99, 2),
-    "act2_bulk_p99_ms": round(bulk_p99, 2),
-    "p99_budget_ms": p99_budget_ms,
-})
 
 
 # ---------------------------------------------------- act 3: netfault chaos
@@ -351,26 +332,8 @@ run_netfault("malformed", ",max=4")
 run_netfault("slowloris", ",max=2,stall_s=1.5")
 run_netfault("crash", ",max=1")
 print("gateway_smoke act 3 OK: every netfault mode recovered to /healthz ok")
-ledger["act3_modes"] = ["disconnect", "malformed", "slowloris", "crash"]
 
 print("gateway_smoke OK: acts 1-3 green")
-
-if record:
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "robustness",
-        "probe": "gateway_ab",
-        "preset": "pong_serve(sebulba tiny)",
-        **bench_history.device_entry(),
-        "num_envs": NUM_ENVS,
-        "actor_threads": THREADS,
-        "unroll_len": UNROLL,
-        "updates": updates,
-        "qps_offered": qps,
-        **ledger,
-    })
-    print("gateway_smoke: recorded", entry["ts"])
 EOF
 
 # ------------------------------------------------- act 4: replicated fleet
@@ -589,12 +552,11 @@ print("gateway_smoke act 4 OK: promotion, kill-mid-canary rollback, "
 EOF
 
 # -------------------------------------------- act 5: request tracing
-# Scene A: journaling armed over a replicated fleet under two-tenant QPS
-# with a replica kill; the persisted journals must survive the `obs
-# explain --worst 5` gate. Scene B: on/off A/B of the same wire load.
+# Journaling armed over a replicated fleet under two-tenant QPS with a
+# replica kill; the persisted journals must survive the `obs explain
+# --worst 5` gate.
 QPS5="${ASYNCRL_GATEWAY_QPS:-50}"
-AB_MAX="${ASYNCRL_TRACE_AB_MAX:-1.15}"
-python - "$QPS5" "$AB_MAX" "$RECORD" <<'EOF'
+python - "$QPS5" <<'EOF'
 import sys
 import tempfile
 import threading
@@ -611,8 +573,6 @@ from asyncrl_tpu.serve import (
 from asyncrl_tpu.utils import faults
 
 qps = float(sys.argv[1])
-ab_max = float(sys.argv[2])
-record = sys.argv[3] not in ("", "0")
 TENANT_SPEC = "gold:shed:rps=1000,burst=500;bulk:shed:rps=1000,burst=500"
 DECIDED = {
     getattr(obs_requests, name)
@@ -671,7 +631,7 @@ class TraceLoad:
             time.sleep(self.period)
 
 
-# ---- scene A: armed journaling + two-tenant QPS + replica kill
+# ---- armed journaling + two-tenant QPS + replica kill
 run_dir = tempfile.mkdtemp(prefix="gwsmoke-trace-")
 # The kill sleeps for its first 50 tick-calls (~1 s at the 0.02 s tick),
 # then takes out one replica mid-load; the supervisor rebuilds it.
@@ -706,7 +666,7 @@ finally:
     faults.disarm()
 
 served = sum(ld.served for ld in loaders)
-print(f"gateway_smoke act 5 scene A: served={served} "
+print(f"gateway_smoke act 5: served={served} "
       f"shed={sum(ld.shed for ld in loaders)} "
       f"failed={sum(ld.failed for ld in loaders)} restarts={restarts}")
 if served < 20:
@@ -747,70 +707,8 @@ if not any(
 ):
     sys.exit("gateway_smoke FAILED (act 5): no fleet.attempt hop in any "
              "journal — fleet-level tracing is dark")
-print(f"gateway_smoke act 5 scene A OK: {len(docs)} journals persisted, "
+print(f"gateway_smoke act 5 OK: {len(docs)} journals persisted, "
       "worst-5 waterfalls sum to their latencies and name their stages")
-
-# ---- scene B: on/off A/B on a clean fleet (no chaos)
-obs_requests.disarm()
-fleet, router, gateway = build_fleet(2)
-client = GatewayClient(
-    f"http://127.0.0.1:{gateway.port}", tenant="gold", deadline_ms=2000,
-    retries=0,
-)
-
-
-def median_latency_ms(n=150, warmup=20):
-    obs = np.zeros((2, 4), np.float32)
-    lat = []
-    for i in range(n + warmup):
-        t0 = time.perf_counter()
-        try:
-            client.act(obs)
-        except GatewayShed:
-            continue
-        dt = 1e3 * (time.perf_counter() - t0)
-        if i >= warmup:
-            lat.append(dt)
-    if not lat:
-        sys.exit("gateway_smoke FAILED (act 5 A/B): nothing served")
-    return float(np.median(np.asarray(lat)))
-
-
-try:
-    p50_off = median_latency_ms()
-    obs_requests.arm(run_dir=run_dir, meta={"smoke": "gateway_act5_ab"})
-    p50_on = median_latency_ms()
-finally:
-    gateway.stop()
-    router.close()
-    fleet.close()
-    obs_requests.disarm()
-
-ratio = p50_on / max(p50_off, 1e-9)
-print(f"gateway_smoke act 5 scene B: p50 off={p50_off:.2f}ms "
-      f"on={p50_on:.2f}ms ratio={ratio:.3f}x (bar {ab_max:.2f}x)")
-if ratio > ab_max:
-    sys.exit(f"gateway_smoke FAILED (act 5 A/B): journaling costs "
-             f"{ratio:.3f}x on the serving path (bar {ab_max:.2f}x)")
-print("gateway_smoke act 5 OK: traced kill-run journals gate, tracing "
-      "overhead inside the noise bar")
-
-if record:
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "observability",
-        "probe": "request_trace_ab",
-        "preset": "fleet(standalone)",
-        **bench_history.device_entry(),
-        "qps_offered": qps,
-        "p50_off_ms": round(p50_off, 3),
-        "p50_on_ms": round(p50_on, 3),
-        "trace_overhead_x": round(ratio, 4),
-        "ab_bar_x": ab_max,
-        "journals_persisted": len(docs),
-    })
-    print("gateway_smoke: recorded", entry["ts"])
 EOF
 
 echo "gateway_smoke OK: all five acts green"

@@ -11,7 +11,7 @@
 # so its guarded-by/thread-entry annotations gate like the rest of the
 # concurrency substrate. Focused gates beyond the package run live in
 # the GATES manifest below — one loop, no hand-maintained command
-# blocks: the entry points (scripts/*.py, bench.py, __graft_entry__.py)
+# blocks: the entry points (scripts/*.py, chip_smoke.py, __graft_entry__.py)
 # under configflow + the SPMD passes + the wire-budget trio (a smoke
 # script that sleeps a millisecond value or drops a deadline guard gates
 # here), and the serve/kernel files whose gating must survive any future
@@ -24,8 +24,8 @@
 #                              # unchanged tree (the verify skill's loop).
 #                              # The skip keys on the PACKAGE manifest, so
 #                              # ruff findings in tests/, scripts/, or
-#                              # bench.py edits are deferred to the next
-#                              # full run — CI uses plain lint.sh.
+#                              # chip_smoke.py edits are deferred to the
+#                              # next full run — CI uses plain lint.sh.
 #   scripts/lint.sh path.py    # lint specific files (fixtures exit nonzero)
 #
 # The package run is incremental (--cache-dir .analysis-cache: a second
@@ -50,9 +50,9 @@ fi
 
 run_ruff() {
     if command -v ruff >/dev/null 2>&1; then
-        ruff check asyncrl_tpu tests scripts bench.py || rc=1
+        ruff check asyncrl_tpu tests scripts chip_smoke.py || rc=1
     elif python -c "import ruff" >/dev/null 2>&1; then
-        python -m ruff check asyncrl_tpu tests scripts bench.py || rc=1
+        python -m ruff check asyncrl_tpu tests scripts chip_smoke.py || rc=1
     else
         echo "lint.sh: ruff not installed; skipping ruff (analysis passes still gate)" >&2
     fi
@@ -115,7 +115,7 @@ EOF
 #   explicitly so the wire-tracing layer can never silently drift out of
 #   the deadline/refund contract set.
 GATES=(
-    "scripts|configflow,sharding,hostsync,pallas,deadlines,refund,units,races|scripts/*.py bench.py chip_smoke.py __graft_entry__.py"
+    "scripts|configflow,sharding,hostsync,pallas,deadlines,refund,units,races|scripts/*.py chip_smoke.py __graft_entry__.py"
     "fleet|protocols,deadlock|asyncrl_tpu/serve/fleet.py"
     "kernels|pallas,sharding,protocols|asyncrl_tpu/ops/pallas_scan.py asyncrl_tpu/ops/max_pool.py asyncrl_tpu/rollout/device_queue.py"
     "requests|deadlines,refund,units,protocols|asyncrl_tpu/obs/requests.py"

@@ -143,25 +143,6 @@ def main() -> int:
         "episode_len_mean": round(float(recs["alive"].sum(axis=1).mean()), 1),
     }
     print(json.dumps(out))
-    # Persist the diagnosis in the run log: plateau-breaking recipe changes
-    # (e.g. the pong_t2t scoring-rate recipe) cite these numbers.
-    from asyncrl_tpu.utils import bench_history
-
-    try:
-        bench_history.record(
-            {
-                "kind": "diagnosis",
-                "name": "pong_points_decomposition",
-                "run_dir": run_dir,
-                # NOT device_entry(): this analysis tool pins the CPU
-                # backend, so those fields would mislabel a TPU-trained
-                # checkpoint's diagnosis as CPU evidence.
-                "analysis_platform": "cpu",
-                **out,
-            }
-        )
-    except OSError as e:
-        print(f"bench_history: could not persist: {e}", file=sys.stderr)
     trainer.close()
     return 0
 

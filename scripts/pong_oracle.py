@@ -249,22 +249,6 @@ def main() -> int:
         **({"frame_skip": skip} if skip > 1 else {}),
     }
     print(json.dumps(out))
-    # Evidence trail: the oracle result is the reachability proof for the
-    # 18.0 bar — persist it like pong_diagnose's rows (analysis host, not
-    # training hardware).
-    from asyncrl_tpu.utils import bench_history
-
-    try:
-        bench_history.record(
-            {
-                "kind": "feasibility",
-                "name": "pong_oracle_lookahead",
-                "analysis_platform": "cpu",
-                **out,
-            }
-        )
-    except OSError as e:
-        print(f"bench_history: could not persist: {e}", file=sys.stderr)
     return 0
 
 

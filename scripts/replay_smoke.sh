@@ -15,15 +15,8 @@
 #      (>= half; both recorded verbatim), and every window carrying the
 #      replay telemetry.
 #
-# ASYNCRL_SMOKE_RECORD=1 appends a kind="perf" probe="replay_ab" row to
-# BENCH_HISTORY.json with the stall fractions, reduction ratio, evals,
-# and fps — and, because a throughput row should land with every perf
-# probe (the ledger's freshness discipline), also runs
-# `python bench.py pong_impala` for a fresh pong_impala row on this box.
-#
 # Usage: scripts/replay_smoke.sh                   # CPU, ~1-2 min
 #        ASYNCRL_SMOKE_UPDATES=400 scripts/replay_smoke.sh
-#        ASYNCRL_SMOKE_RECORD=1 scripts/replay_smoke.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -33,7 +26,6 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 # below ~300 the greedy eval of a still-near-uniform policy is noise and
 # the sample-efficiency comparison meaningless.
 UPDATES="${ASYNCRL_SMOKE_UPDATES:-800}"
-RECORD="${ASYNCRL_SMOKE_RECORD:-0}"
 OUT_DIR="$(mktemp -d)"
 trap 'rm -rf "$OUT_DIR"' EXIT
 
@@ -194,12 +186,12 @@ with open(f"{out_dir}/replay.json", "w") as f:
     }, f)
 EOF
 
-# --------------------------------------------------------------- ledger
-python - "$OUT_DIR" "$RECORD" <<'EOF'
+# -------------------------------------------------------------- summary
+python - "$OUT_DIR" <<'EOF'
 import json
 import sys
 
-out_dir, record = sys.argv[1], sys.argv[2]
+out_dir = sys.argv[1]
 replay = json.load(open(f"{out_dir}/replay.json"))
 print(
     f"replay_smoke OK: stall {replay['stall_off']:.3f} -> "
@@ -207,26 +199,4 @@ print(
     f"{replay['eval_off']:.1f} -> {replay['eval_on']:.1f}, fps "
     f"{replay['fps_off']:,.0f} -> {replay['fps_on']:,.0f}"
 )
-if record not in ("", "0"):
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "perf",
-        "probe": "replay_ab",
-        "preset": "cartpole_impala(sebulba tiny, replay 4x3)",
-        **bench_history.device_entry(),
-        **replay,
-        "notes": (
-            "fixed-env-step A/B on this box: replay_slabs=4 "
-            "replay_passes=3 target_update_period=16 vs replay off; "
-            "stall = mean learner_stall_frac over the run"
-        ),
-    })
-    print(f"replay_smoke: ledger row appended ({entry['ts']})")
 EOF
-
-# A perf probe should land next to a fresh throughput row (the ledger
-# had none since 2026-08-03): bench.py self-records pong_impala.
-if [ "$RECORD" != "0" ] && [ -n "$RECORD" ]; then
-    python bench.py pong_impala
-fi

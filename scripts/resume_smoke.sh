@@ -18,13 +18,8 @@
 #      must trigger the quarantine→rollback path and the run must return
 #      to /healthz ok and a finite loss WITHOUT human intervention.
 #
-# ASYNCRL_SMOKE_RECORD=1 appends a kind="robustness" probe="resume_ab"
-# row to BENCH_HISTORY.json with the control-vs-resumed fps and the
-# drain/rollback evidence.
-#
 # Usage: scripts/resume_smoke.sh                  # CPU, ~3 min
 #        ASYNCRL_SMOKE_UPDATES=48 scripts/resume_smoke.sh
-#        ASYNCRL_SMOKE_RECORD=1 scripts/resume_smoke.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -33,7 +28,6 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 # root must be on sys.path explicitly (nothing installs the package).
 export PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}"
 UPDATES="${ASYNCRL_SMOKE_UPDATES:-24}"
-RECORD="${ASYNCRL_SMOKE_RECORD:-0}"
 OUT_DIR="$(mktemp -d)"
 trap 'rm -rf "$OUT_DIR"' EXIT
 
@@ -285,12 +279,12 @@ with open(f"{out_dir}/rollback.json", "w") as f:
     json.dump(rollback, f)
 EOF
 
-# --------------------------------------------------------------- ledger
-python - "$UPDATES" "$OUT_DIR" "$RECORD" <<'EOF'
+# -------------------------------------------------------------- summary
+python - "$OUT_DIR" <<'EOF'
 import json
 import sys
 
-updates, out_dir, record = sys.argv[1], sys.argv[2], sys.argv[3]
+out_dir = sys.argv[1]
 control = json.load(open(f"{out_dir}/control.json"))
 resumed = json.load(open(f"{out_dir}/resumed.json"))
 rollback = json.load(open(f"{out_dir}/rollback.json"))
@@ -300,22 +294,4 @@ print(
     f"{resumed['updates_restored']} -> {resumed['updates_final']} updates; "
     f"rollback probe {rollback['restores']} restore(s)"
 )
-if record not in ("", "0"):
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "robustness",
-        "probe": "resume_ab",
-        "preset": "cartpole_impala(sebulba tiny)",
-        **bench_history.device_entry(),
-        "updates": int(updates),
-        "fps_control": round(control["fps"]),
-        "fps_resumed": round(resumed["fps"]),
-        "updates_restored": resumed["updates_restored"],
-        "updates_final": resumed["updates_final"],
-        "rollback_restores": rollback["restores"],
-        "nan_guard_skips": rollback["nan_skips"],
-        "healthz": "ok",
-    })
-    print("resume_smoke: recorded", entry["ts"])
 EOF

@@ -1,8 +1,8 @@
 """Wall-clock-to-target runner: the north-star OUTCOME measurement
 (BASELINE.md: wall-clock to 18.0 mean Pong reward, target < 10 min on TPU;
 VERDICT.md round 1, Missing #2). Trains a preset until the in-training
-greedy eval reaches the target return, then appends a ``time_to_target``
-record to the local run log (utils/bench_history.py).
+greedy eval reaches the target return, then prints a ``time_to_target``
+record as one JSON line on stdout.
 
     python scripts/run_to_target.py pong_impala \
         [--target 18.0] [--budget-seconds 3600] [key=value ...]
@@ -86,7 +86,6 @@ def main() -> int:
 
     from asyncrl_tpu.api.factory import make_agent
     from asyncrl_tpu.configs import presets
-    from asyncrl_tpu.utils import bench_history
     from asyncrl_tpu.utils.config import override
 
     cfg = presets.get(preset_name)
@@ -171,12 +170,12 @@ def main() -> int:
     # make_agent dispatches on cfg.backend — a sebulba/cpu_async preset must
     # be measured on ITS architecture, not silently retimed on Anakin.
     trainer = make_agent(cfg)
-    dev = bench_history.device_entry()
+    dev = runtime.device_entry()
     status = {"reached": False, "seconds": None, "eval_return": None}
     # Confirmation state lives next to status because save_elapsed (a
     # closure called on every metrics drain) persists the failed-crossing
     # count: a SIGKILL'd session's rejected lucky crossing must survive
-    # into the next session's ledger row, not vanish with the process.
+    # into the next session's row, not vanish with the process.
     confirm = {"return": None, "failed": 0}
     fps_log: list[float] = []
     t0 = time.perf_counter()
@@ -185,9 +184,9 @@ def main() -> int:
         return prior["seconds"] + time.perf_counter() - t0
 
     def save_elapsed(reached: bool = False) -> None:
-        # Atomic (tmp + rename, like bench_history), and OSError-tolerant
-        # like bench_history.record: a full/read-only checkpoint volume must
-        # degrade the accumulation, never abort the measurement itself.
+        # Atomic (tmp + rename), and OSError-tolerant: a full/read-only
+        # checkpoint volume must degrade the accumulation, never abort
+        # the measurement itself.
         if not elapsed_path:
             return
         payload = {
@@ -291,8 +290,8 @@ def main() -> int:
                     # one (64 episodes vs 32) — on a memory-edge geometry
                     # it can fail where training did not. The attempt must
                     # still become a visible reached=false row with the
-                    # crossing's provenance, not a crash with no ledger
-                    # entry ("failed attempts are visible history").
+                    # crossing's provenance, not a crash with no entry
+                    # ("failed attempts are visible history").
                     status["confirm_error"] = str(e)[:300]
                     status["seconds"] = crossing_seconds
                     print(
@@ -412,10 +411,6 @@ def main() -> int:
         # time_to_target, so instead the marker makes a rerun refuse
         # (clear the checkpoint dir to start a new measurement).
         save_elapsed(reached=True)
-    try:
-        entry = bench_history.record(entry)
-    except OSError as e:  # the measurement must outlive a read-only ledger
-        print(f"run_to_target: could not persist: {e}", file=sys.stderr)
     print(json.dumps(entry))
     return 0 if status["reached"] else 1
 

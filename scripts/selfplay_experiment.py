@@ -11,8 +11,8 @@ this for the self-play agent too).
 
     python scripts/selfplay_experiment.py [frames] [key=value ...]
 
-Appends a ``kind="experiment"`` entry to BENCH_HISTORY.json with both
-scores and prints it. Interpretation guidance (docs/ARCHITECTURE.md):
+Prints a ``kind="experiment"`` entry with both scores. Interpretation
+guidance (docs/ARCHITECTURE.md):
 direct training exploits THE tracker; self-play learns general play that
 must transfer — at small budgets direct usually wins the tracker metric,
 so the ladder earns its keep only if this experiment shows otherwise.
@@ -37,7 +37,6 @@ runtime.enable_compile_cache()
 
 from asyncrl_tpu.api.trainer import Trainer
 from asyncrl_tpu.configs import presets
-from asyncrl_tpu.utils import bench_history
 from asyncrl_tpu.utils.config import override
 
 
@@ -90,16 +89,12 @@ def main() -> int:
     entry = {
         "kind": "experiment",
         "name": "selfplay_vs_direct",
-        **bench_history.device_entry(),
+        **runtime.device_entry(),
         "env_frames_each": frames,
         "direct": direct,
         "selfplay": ladder,
         "metric": "mean greedy return vs scripted tracker, 32 episodes",
     }
-    try:
-        entry = bench_history.record(entry)
-    except OSError as e:
-        print(f"selfplay_experiment: could not persist: {e}", file=sys.stderr)
     print(json.dumps(entry))
     return 0
 

@@ -1,32 +1,20 @@
 #!/usr/bin/env bash
 # Trace smoke: run a short CPU sebulba pipeline with tracing ON, validate
 # the exported Perfetto JSON against the schema (python -m asyncrl_tpu.obs
-# validate), print the stall-attribution report, and A/B throughput
-# against tracing OFF — failing if the traced run is more than
-# ASYNCRL_TRACE_TOLERANCE (default 1.05 = 5%) slower.
+# validate) and print the stall-attribution report. What tracing costs is
+# the benchmark's to say (PERF.md), not a CPU run's.
 #
-# This is the operator-facing gate for the ISSUE 5 overhead budget: the
-# span rings must be cheap enough to leave on. Same measurement
-# discipline as perf_smoke.sh (the first training run in a process is
-# systematically slow): discard a process warm-up run, then alternate
-# off/on/off/on and take best-of-two per mode.
-#
-# Usage: scripts/trace_smoke.sh                    # CPU, ~1-2 min
+# Usage: scripts/trace_smoke.sh                    # CPU, under a minute
 #        ASYNCRL_SMOKE_UPDATES=64 scripts/trace_smoke.sh
-#        ASYNCRL_TRACE_TOLERANCE=1.10 scripts/trace_smoke.sh  # noisy box
-#        ASYNCRL_SMOKE_RECORD=1 scripts/trace_smoke.sh  # append the A/B as
-#          a kind="observability" probe="trace_ab" row to BENCH_HISTORY.json
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 UPDATES="${ASYNCRL_SMOKE_UPDATES:-24}"
-TOLERANCE="${ASYNCRL_TRACE_TOLERANCE:-1.05}"
-RECORD="${ASYNCRL_SMOKE_RECORD:-0}"
 RUN_DIR="$(mktemp -d /tmp/trace_smoke.XXXXXX)"
 trap 'rm -rf "$RUN_DIR"' EXIT
 
-python - "$UPDATES" "$TOLERANCE" "$RECORD" "$RUN_DIR" <<'EOF'
+python - "$UPDATES" "$RUN_DIR" <<'EOF'
 import glob
 import sys
 import time
@@ -34,19 +22,18 @@ import time
 from asyncrl_tpu import make_agent
 from asyncrl_tpu.configs import presets
 
-updates, tolerance = int(sys.argv[1]), float(sys.argv[2])
-record = sys.argv[3] not in ("", "0")
-run_dir = sys.argv[4]
+updates = int(sys.argv[1])
+run_dir = sys.argv[2]
 NUM_ENVS, UNROLL = 16, 16
 steps = updates * NUM_ENVS * UNROLL
 
 
-def run(traced: bool):
+def run():
     cfg = presets.get("pong_impala").replace(
         backend="sebulba", host_pool="jax", num_envs=NUM_ENVS,
         actor_threads=1, unroll_len=UNROLL, precision="f32", log_every=4,
         seed=3, hidden_sizes=(64, 64), actor_staleness=1_000_000,
-        trace=traced, run_dir=run_dir,
+        trace=True, run_dir=run_dir,
     )
     agent = make_agent(cfg)
     try:
@@ -57,25 +44,15 @@ def run(traced: bool):
     finally:
         agent.close()
     fps = steps / elapsed
-    label = "trace=on " if traced else "trace=off"
     last = history[-1]
     print(
-        f"trace_smoke {label}: fps={fps:12,.0f}  "
+        f"trace_smoke: fps={fps:12,.0f}  "
         f"spans={int(last.get('trace_spans', 0))}  "
         f"dropped={int(last.get('trace_dropped_spans', 0))}"
     )
-    return fps
 
 
-# Best-of-three per mode, alternating: the 1-core box's scheduler noise
-# swings identical configs run to run (see perf_smoke.sh), and best-of-N
-# alternation is the discipline that converges on the true ceiling.
-run(True)  # discarded process warm-up
-fps_off = max(run(False) for _ in range(1))
-fps_on = max(run(True) for _ in range(1))
-for _ in range(2):
-    fps_off = max(fps_off, run(False))
-    fps_on = max(fps_on, run(True))
+run()
 
 traces = sorted(glob.glob(f"{run_dir}/trace-*.json"))
 if not traces:
@@ -89,32 +66,5 @@ if obs_main(["validate", traces[-1]]) != 0:
     sys.exit("trace_smoke FAILED: exported trace violates the schema")
 if obs_main(["report", traces[-1]]) != 0:
     sys.exit("trace_smoke FAILED: obs report errored on the export")
-
-if fps_on * tolerance < fps_off:
-    sys.exit(
-        f"trace_smoke FAILED: tracing overhead above budget "
-        f"({fps_on:,.0f} vs {fps_off:,.0f} fps, tolerance {tolerance}x)"
-    )
-print(
-    f"trace_smoke OK: traced {fps_on:,.0f} fps vs untraced "
-    f"{fps_off:,.0f} fps ({fps_on / fps_off:.3f}x, budget {tolerance}x)"
-)
-
-if record:
-    from asyncrl_tpu.utils import bench_history
-
-    entry = bench_history.record({
-        "kind": "observability",
-        "probe": "trace_ab",
-        "preset": "pong_impala(sebulba tiny)",
-        **bench_history.device_entry(),
-        "num_envs": NUM_ENVS,
-        "actor_threads": 1,
-        "unroll_len": UNROLL,
-        "updates": updates,
-        "fps_traced": round(fps_on),
-        "fps_untraced": round(fps_off),
-        "trace_overhead": round(fps_off / fps_on, 3),
-    })
-    print("trace_smoke: recorded", entry["ts"])
+print("trace_smoke OK: export validates and reports")
 EOF

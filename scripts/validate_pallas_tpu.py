@@ -2,9 +2,8 @@
 kernels.
 
 The validation gate for every hand-written kernel: on a live chip it
-judges each kernel set against its contract, prints one JSON line per
-geometry, and appends one ``kind="kernel_validation"`` entry per set to
-the local run log (utils/bench_history.py):
+judges each kernel set against its contract and prints one JSON line per
+geometry and one per set:
 
 - ``scan`` — ``reverse_linear_scan_pallas`` + its explicit-DMA twin
   (``pallas_dma`` — the ROADMAP item-2 beachhead whose start/wait
@@ -22,8 +21,8 @@ the local run log (utils/bench_history.py):
     python scripts/validate_pallas_tpu.py [scan] [fused]
 
 No argv = all sets. Exit 0 = every selected set matched (safe to
-promote); exit 1 = mismatch (keep the lax defaults; the ledger entry
-records which geometry); exit 2 = no accelerator / bad argv.
+promote); exit 1 = mismatch (keep the lax defaults; the geometry's line
+says which); exit 2 = no accelerator / bad argv.
 """
 
 from __future__ import annotations
@@ -42,7 +41,6 @@ import numpy as np
 import functools
 
 from asyncrl_tpu.ops.scan import reverse_linear_scan
-from asyncrl_tpu.utils import bench_history
 
 # (T, B): preset fragment shapes (unroll_len x num_envs) plus a long-horizon
 # sequence-parallel shape (SURVEY.md §5.7) and a ragged-tile edge case.
@@ -130,7 +128,7 @@ def validate_scan() -> bool:
             entry[f"{name}_speedup"] = round(
                 entry["associative_us"] / max(t_k * 1e6, 1e-9), 2
             )
-        # Back-compat aliases consumed by obs doctor / older tooling.
+        # Back-compat aliases for older tooling.
         if "rms_rel_err_pallas" in entry:
             entry["rms_rel_err"] = entry["rms_rel_err_pallas"]
             entry["speedup"] = entry["pallas_speedup"]
@@ -139,14 +137,6 @@ def validate_scan() -> bool:
         results.append(entry)
         print(json.dumps(entry))
 
-    entry = {
-        "kind": "kernel_validation",
-        "kernel": "reverse_linear_scan_pallas",
-        **bench_history.device_entry(),
-        "ok": ok,
-        "geometries": results,
-    }
-    bench_history.record(entry)
     print(json.dumps({"kernel": "scan", "ok": ok, "n": len(results)}))
     return ok
 
@@ -221,13 +211,6 @@ def validate_fused() -> bool:
         results.append(entry)
         print(json.dumps(entry))
 
-    bench_history.record({
-        "kind": "kernel_validation",
-        "kernel": "fused_vtrace_pallas",
-        **bench_history.device_entry(),
-        "ok": ok,
-        "geometries": results,
-    })
     print(json.dumps({"kernel": "fused", "ok": ok, "n": len(results)}))
     return ok
 

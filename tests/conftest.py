@@ -10,7 +10,7 @@ an importer that touched jax first cannot leave the suite on a chip.
 import os
 import sys
 
-# Repo root on sys.path: `import bench` (and other root-level entry
+# Repo root on sys.path: `import chip_smoke` (and other root-level entry
 # points) must resolve under plain `pytest` too, not only `python -m
 # pytest` from the root — same guard the scripts/ entry points carry.
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -32,69 +32,78 @@ import pytest  # noqa: E402
 
 # ---------------------------------------------------------------- quick tier
 #
-# `pytest -m "not slow"` is the BOUNDED quick tier: a curated correctness
-# slice that must stay green in < 5 minutes on this 1-core box (VERDICT.md
-# round 1, Next #6 — a judge/CI needs a red/green signal in bounded time).
-# Everything NOT on this allowlist is auto-marked `slow` at collection, so
-# a new test defaults into the full suite and must be promoted here
-# deliberately (with an eye on its measured cost; per-file wall times from
-# the 2026-07-30 sweep are noted). The FULL suite (~35 min) remains the
-# completeness bar: `python -m pytest tests/ -q`.
+# `pytest -m "not slow"` is tier-1: what the driver runs on every PR, on
+# the CPU, with six workers (`-n 6 --dist loadfile`: a file's tests stay
+# on one worker) inside a 1,470 s limit. Everything NOT on this allowlist
+# is auto-marked `slow` at collection, so a new test defaults into the
+# full suite and is promoted here deliberately. What the list is for, in
+# order: (1) the measured path — the code the benchmark's cells run
+# (learn/learner.py, rollout/anakin.py, models/, ops/, the pixel Pong
+# and token envs, parallel/mesh.py, make_agent -> Trainer, the process
+# record): its tests belong here whenever they pass under the driver's
+# command and cost under ~20 s each; (2) the acceptance contracts of the
+# host path, serving, observability and the analyzer, one curated core a
+# file. The FULL suite (`python -m pytest tests/ -q`) remains the
+# completeness bar; ROADMAP.md lists the `slow` remainder by file.
 #
-# "all" keeps the whole file; a set keeps only those test functions
-# (parametrized variants included).
+# "all" keeps the whole file (tests marked `slow` in the file itself stay
+# slow); a set keeps only those test functions (parametrized variants
+# included).
 QUICK: dict[str, object] = {
     # Pure numerics / fast units (whole files).
-    "test_vtrace.py": "all",  # 5s
-    "test_gae.py": "all",  # 4s
-    "test_scan.py": "all",  # 14s
-    "test_losses.py": "all",  # 15s
-    "test_distributions.py": "all",  # 13s
-    "test_envs.py": "all",  # 4s
-    "test_bench_history.py": "all",  # 5s (one bench.py refusal subprocess)
-    "test_bench_cli.py": "all",  # 1s (measurement stubbed)
-    "test_runtime.py": "all",  # 10s (three jax-importing subprocesses)
+    "test_vtrace.py": "all",
+    "test_gae.py": "all",
+    "test_scan.py": "all",
+    "test_losses.py": "all",
+    "test_distributions.py": "all",
+    "test_envs.py": "all",
+    "test_runtime.py": "all",  # jax-importing subprocesses
+    # The measured path (ISSUE 28): the Learner every cell's step is
+    # built by, the networks, the pixel Pong env the atari cells render
+    # on the device.
+    "test_learner.py": "all",
+    "test_pong.py": "all",
+    # test_remat_is_numerically_invisible stays slow: ~30 s under six
+    # workers, and no cell sets `remat`.
+    "test_models.py": {
+        "test_mlp_flattens_image_observations",
+        "test_cnn_torsos_shapes",
+        "test_outputs_float32_under_bf16_compute",
+    },
     # chip_smoke.py's refusal + the CPU rehearsal of its three legs at
     # tiny sizes through the Pallas interpreter (~70s): what keeps the
     # chip check's control flow and assertions alive between chip runs.
     "test_chip_smoke.py": "all",
     "test_multiprocess.py": "all",  # (slow-marked inside already)
-    "test_differential.py": "all",  # 12s
-    "test_metrics.py": "all",  # 13s
-    "test_breakout.py": "all",  # 10s
-    "test_anakin.py": "all",  # 16s
-    "test_cpu_async.py": "all",  # 16s
+    "test_differential.py": "all",
+    "test_metrics.py": "all",
+    "test_breakout.py": "all",
+    "test_anakin.py": "all",
+    "test_cpu_async.py": "all",
     # Curated cores of the heavier files.
     "test_timeshard.py": {
-        "test_vtrace_timesharded_matches_single_device",  # 6s
-        "test_gae_timesharded_matches_single_device",  # 6s
+        "test_vtrace_timesharded_matches_single_device",
+        "test_gae_timesharded_matches_single_device",
     },
-    "test_learner.py": {
-        "test_sharded_grads_equal_full_batch_grads",  # 3 algos, ~25s
-        "test_impala_actor_staleness",  # 9s
-        "test_unknown_optimizer_rejected",
-        "test_donated_state_steps_and_matches_undonated",  # 2 algos, ~10s
-    },
-    "test_qlearn.py": {"test_huber_td_loss_fixture"},  # 11s
+    "test_qlearn.py": {"test_huber_td_loss_fixture"},
     "test_sebulba.py": {
         "test_param_store_versioning",
         "test_jax_host_pool_contract",
-        "test_rollout_learner_improves_on_fixed_fragment",  # 3s
-        "test_fused_host_updates_match_sequential",  # 5s
+        "test_rollout_learner_improves_on_fixed_fragment",
+        "test_fused_host_updates_match_sequential",
     },
-    "test_checkpoint.py": {"test_save_restore_bit_exact_next_step"},  # 16s
+    "test_checkpoint.py": {"test_save_restore_bit_exact_next_step"},
+    # make_agent -> Trainer. test_pong_pixels_t2t_preset_trains (47 s)
+    # stays slow, and so does test_train_smoke_learns_a_bit: under six
+    # workers its ~470 all-reduced updates on the 8-device CPU mesh
+    # aborted the worker in 2 runs of 8 (ROADMAP C12).
     "test_api.py": {
         "test_config_override_parsing",
         "test_presets_exist",
         "test_make_agent_unknown_backend",
         "test_make_agent_rejects_bad_enums_eagerly",
-        "test_make_agent_train_smoke",  # 13s
-    },
-    "test_pong.py": {
-        "test_pong_scoring_and_serve",
-        "test_pong_agent_bounce",
-        "test_pong_episode_ends_at_win_score",
-        "test_pong_opponent_validation",
+        "test_make_agent_train_smoke",
+        "test_in_training_eval_cadence",
     },
     "test_race_debug.py": {
         "test_paramstore_detects_removed_lock",  # the §5.2b proof
@@ -115,16 +124,16 @@ QUICK: dict[str, object] = {
         "test_corrupt_poisons_payload_deterministically",
         "test_max_fires_caps_and_counts",
         "test_stall_wakes_on_stop_predicate",
-        "test_single_crash_in_actor_path_is_recovered",  # 3 sites, ~20s
-        "test_eval_pools_step_unarmed",  # 3s
-        "test_server_crash_is_recovered_and_counted",  # 7s
-        "test_serve_core_crash_is_rebuilt_without_dropping_fleet",  # 2 sites, ~12s
-        "test_watchdog_restarts_stalled_actor",  # 8s
-        "test_restart_storm_aborts_instead_of_churning",  # 4s
+        "test_single_crash_in_actor_path_is_recovered",  # 3 sites
+        "test_eval_pools_step_unarmed",
+        "test_server_crash_is_recovered_and_counted",
+        "test_serve_core_crash_is_rebuilt_without_dropping_fleet",  # 2 sites
+        "test_watchdog_restarts_stalled_actor",
+        "test_restart_storm_aborts_instead_of_churning",
         "test_native_pool_close_is_idempotent",
         "test_native_pool_close_safe_after_failed_init",
         "test_recovery_counters_flow_through_sinks",
-        "test_threads_are_named_and_fault_messages_identify_threads",  # 2s
+        "test_threads_are_named_and_fault_messages_identify_threads",
     },
     # Serving core (asyncrl_tpu/serve/, ISSUE 6): params/router/SLO units
     # are sub-second; the dispatch/routing/storm tests are a few seconds
@@ -176,7 +185,7 @@ QUICK: dict[str, object] = {
     # the SHD/HSY/PAL families, version-bump invalidation, JSON round
     # trip. ~10s, two CLI subprocess runs included. Tier-1 by the
     # ISSUE 13 acceptance contract (deletion proofs pass on every PR).
-    "test_spmd_analysis.py": "all",  # 10s
+    "test_spmd_analysis.py": "all",
     # The explicit-DMA scan kernel must stay bit-identical to the
     # automatic kernel (the PAL pass guards its start/wait discipline
     # statically; this guards its numerics). ~8s in the interpreter.
@@ -186,7 +195,7 @@ QUICK: dict[str, object] = {
     # grammar hardness, warm-cache soundness, stats zeros. ~10s, two CLI
     # subprocess runs included. Tier-1 by the ISSUE 11 acceptance
     # contract (deletion proofs pass on every PR).
-    "test_protocols.py": "all",  # 10s
+    "test_protocols.py": "all",
     # Static checker (asyncrl_tpu/analysis/): pure-AST, no training; the
     # whole file (package-gates-clean + fixture corpus + lock/edge
     # deletion detection + cache correctness/speedup + baseline + JSON +
@@ -194,7 +203,7 @@ QUICK: dict[str, object] = {
     # included. Tier-1 by the ISSUE 3/4 acceptance contracts: the
     # package must gate clean (modulo the checked-in baseline) on every
     # PR, and the warm cache must stay >= 3x faster than cold.
-    "test_analysis.py": "all",  # 25s
+    "test_analysis.py": "all",
     # Zero-copy staging pipeline (rollout/staging.py): ring/lease units
     # are sub-second; the bit-identity A/B is ~25s (two tiny trainings).
     # The two training smokes (chaos crash recovery, recurrent slabs)
@@ -295,7 +304,7 @@ QUICK: dict[str, object] = {
     "test_perf_smoke.py": "all",
     "test_ppo_multipass.py": {
         "test_ppo_multipass_minibatch_divisibility_error",
-        "test_ppo_multipass_dp_consistency",  # 8s
+        "test_ppo_multipass_dp_consistency",
     },
     "test_wrappers.py": {
         "test_frame_skip_sums_rewards_and_freezes_at_done",
@@ -303,11 +312,30 @@ QUICK: dict[str, object] = {
         "test_host_pool_refuses_unhonorable_knobs",
         "test_registry_applies_knobs",
     },
-    "test_recurrent.py": {"test_recurrent_apply_and_reset"},
+    # The carry, `reset_core` and the fragment-initial core the sequence
+    # cell's policy lives on, through the Anakin Learner and Trainer; the
+    # host-path and PPO-multipass cases stay slow.
+    "test_recurrent.py": {
+        "test_build_model_dispatch",
+        "test_recurrent_apply_and_reset",
+        "test_recurrent_learner_update_and_determinism",
+        "test_recurrent_fragment_forward_resets_core_mid_fragment",
+        "test_recurrent_eval_and_checkpoint",
+        "test_recurrent_guards",
+    },
+    # Observation / return normalisation where it runs inside the Anakin
+    # rollout and step and across the mesh.
+    "test_normalize.py": {
+        "test_sharded_stats_equal_global_batch",
+        "test_anakin_normalize_obs_end_to_end",
+        "test_disc_return_stream_matches_manual_recurrence",
+        "test_anakin_return_normalization_scales_learner_rewards",
+        "test_return_normalization_gamma_zero_degrades_gracefully",
+    },
     "test_run_to_target.py": {
         # In-process protocol tests (fake trainer, no training): the
         # reached=true confirmation gate must stay on the quick signal.
-        "test_unconfirmed_crossing_is_not_banked",  # 2s
+        "test_unconfirmed_crossing_is_not_banked",
         "test_crossing_banked_only_after_confirmation",
     },
     "test_selfplay.py": {

@@ -270,3 +270,23 @@ def test_fused_learner_trains_and_matches_lax_sequential():
     ref = losses(fused_scan="lax", scan_impl="sequential")
     assert fused and np.all(np.isfinite(fused))
     assert fused == ref
+
+
+@pytest.mark.parametrize("fused_scan, resolved", [
+    ("auto", "lax"), ("lax", "lax"), ("interpret", "interpret"),
+    ("mosaic", ValueError),
+])
+def test_fused_scan_resolves_by_the_mesh_platform(fused_scan, resolved):
+    """``fused_scan="auto"`` is the lax tail on a CPU mesh (the kernel is
+    the TPU's), a concrete choice is kept, an unknown one is refused; and
+    ``scan_impl="auto"`` is gone after the resolution either way."""
+    from asyncrl_tpu.learn.learner import resolve_scan_impl
+    from asyncrl_tpu.parallel.mesh import make_mesh
+
+    cfg, mesh = matched_cfg("tpu").replace(fused_scan=fused_scan), make_mesh()
+    if resolved is ValueError:
+        with pytest.raises(ValueError, match="unknown fused_scan"):
+            resolve_scan_impl(cfg, mesh)
+        return
+    got = resolve_scan_impl(cfg, mesh)
+    assert (got.fused_scan, got.scan_impl) == (resolved, "associative")

@@ -6,7 +6,7 @@ Two guarantees, one A/B:
   slab drain feeds the learner the same bytes in the same order).
 - PERFORMANCE: the overlapped path is not slower. Wall-clock on a shared
   1-core CI box is noisy, so the in-tree assertion keeps a generous margin
-  (the strict comparison is scripts/perf_smoke.sh, run on quiet hardware);
+  (a strict comparison is a benchmark cell's to make: ROADMAP A2);
   a structural regression (overlap path serializing, slab waits on every
   fragment) still fails it.
 """

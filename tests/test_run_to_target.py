@@ -1,7 +1,7 @@
 """run_to_target's cross-session accounting (the time-to-target rows are
 the framework's north-star evidence — their provenance fields must not
-regress). Runs the real script in a subprocess against a throwaway ledger
-(ASYNCRL_BENCH_HISTORY) and checkpoint dir."""
+regress). Runs the real script against a throwaway checkpoint dir and
+reads the row it prints on stdout."""
 
 import importlib.util
 import json
@@ -53,9 +53,12 @@ class _FakeTrainer:
         self.closed = True
 
 
-def _run_protocol(monkeypatch, tmp_path, fake, argv_tail=()):
-    ledger = tmp_path / "ledger.json"
-    monkeypatch.setenv("ASYNCRL_BENCH_HISTORY", str(ledger))
+def _rows(stdout):
+    rows = [json.loads(ln) for ln in stdout.splitlines() if ln.startswith("{")]
+    return [r for r in rows if r.get("kind") == "time_to_target"]
+
+
+def _run_protocol(monkeypatch, capsys, fake, argv_tail=()):
     monkeypatch.setenv("ASYNCRL_FORCE_CPU", "1")
     import asyncrl_tpu.api.factory as factory
 
@@ -68,11 +71,10 @@ def _run_protocol(monkeypatch, tmp_path, fake, argv_tail=()):
     )
     mod = _load_script()
     rc = mod.main()
-    rows = json.loads(ledger.read_text()) if ledger.exists() else []
-    return rc, [r for r in rows if r["kind"] == "time_to_target"]
+    return rc, _rows(capsys.readouterr().out)
 
 
-def test_unconfirmed_crossing_is_not_banked(monkeypatch, tmp_path):
+def test_unconfirmed_crossing_is_not_banked(monkeypatch, tmp_path, capsys):
     """A lucky in-training crossing whose fresh-seed confirmation eval
     disagrees must NOT produce reached=true (VERDICT r4 Next #3), and the
     rejected crossing must survive into later sessions' rows."""
@@ -80,7 +82,7 @@ def test_unconfirmed_crossing_is_not_banked(monkeypatch, tmp_path):
     ckpt.mkdir()
     fake = _FakeTrainer(evals=[20.0], confirms=[10.0])
     rc, rows = _run_protocol(
-        monkeypatch, tmp_path, fake, argv_tail=(f"checkpoint_dir={ckpt}",)
+        monkeypatch, capsys, fake, argv_tail=(f"checkpoint_dir={ckpt}",)
     )
     assert rc == 1  # not reached
     (row,) = rows
@@ -102,7 +104,7 @@ def test_unconfirmed_crossing_is_not_banked(monkeypatch, tmp_path):
     (ckpt / "checkpoint_marker").write_text("x")  # make the resume real
     fake2 = _FakeTrainer(evals=[19.0], confirms=[18.5])
     rc2, rows2 = _run_protocol(
-        monkeypatch, tmp_path, fake2, argv_tail=(f"checkpoint_dir={ckpt}",)
+        monkeypatch, capsys, fake2, argv_tail=(f"checkpoint_dir={ckpt}",)
     )
     assert rc2 == 0
     row2 = rows2[-1]
@@ -110,11 +112,11 @@ def test_unconfirmed_crossing_is_not_banked(monkeypatch, tmp_path):
     assert row2["unconfirmed_crossings"] == 1  # carried from session 1
 
 
-def test_crossing_banked_only_after_confirmation(monkeypatch, tmp_path):
+def test_crossing_banked_only_after_confirmation(monkeypatch, capsys):
     """First crossing fails confirmation and training resumes; the second
     crossing confirms and banks reached=true with both numbers."""
     fake = _FakeTrainer(evals=[20.0, 19.5], confirms=[10.0, 19.0])
-    rc, rows = _run_protocol(monkeypatch, tmp_path, fake)
+    rc, rows = _run_protocol(monkeypatch, capsys, fake)
     assert rc == 0, rows
     (row,) = rows
     assert row["reached"] is True
@@ -126,14 +128,8 @@ def test_crossing_banked_only_after_confirmation(monkeypatch, tmp_path):
     assert fake.confirm_calls[0][1] != fake.confirm_calls[1][1]
 
 
-def _run(tmp_path, ckpt_dir, budget="8"):
-    ledger = tmp_path / "ledger.json"
-    env = dict(
-        os.environ,
-        ASYNCRL_FORCE_CPU="1",
-        ASYNCRL_BENCH_HISTORY=str(ledger),
-        JAX_PLATFORMS="cpu",
-    )
+def _run(ckpt_dir, budget="8"):
+    env = dict(os.environ, ASYNCRL_FORCE_CPU="1", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [
             sys.executable,
@@ -154,16 +150,15 @@ def _run(tmp_path, ckpt_dir, budget="8"):
         timeout=240,
         cwd=REPO,
     )
-    rows = json.loads(ledger.read_text()) if ledger.exists() else []
-    return proc, rows
+    return proc, _rows(proc.stdout)
 
 
 @pytest.mark.slow
 def test_cross_platform_resume_is_labeled(tmp_path):
     ckpt = tmp_path / "arm"
-    proc, rows = _run(tmp_path, ckpt)
+    proc, rows = _run(ckpt)
     assert proc.returncode == 1, proc.stderr  # budget exhausted, not reached
-    (row,) = [r for r in rows if r["kind"] == "time_to_target"]
+    (row,) = rows
     assert row["reached"] is False
     assert "platforms" not in row  # single-platform run: no mixed flag
 
@@ -178,9 +173,9 @@ def test_cross_platform_resume_is_labeled(tmp_path):
     # CPU must then label the blended stats.
     sidecar["platforms"] = ["tpu"]
     (ckpt / "run_to_target_elapsed.json").write_text(json.dumps(sidecar))
-    proc2, rows2 = _run(tmp_path, ckpt)
+    proc2, rows2 = _run(ckpt)
     assert proc2.returncode == 1, proc2.stderr
-    row2 = [r for r in rows2 if r["kind"] == "time_to_target"][-1]
+    (row2,) = rows2
     assert row2["platforms"] == ["cpu", "tpu"]
     assert row2["mean_fps_mixed_platforms"] is True
     assert row2["resumed_sessions"] == 1

@@ -10,6 +10,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _PROBE = """
@@ -75,6 +77,22 @@ def test_cache_entries_counts_programs(tmp_path):
     (tmp_path / "jit_f-abc-cache").write_bytes(b"x")
     (tmp_path / "jit_f-abc-atime").write_bytes(b"x")
     assert runtime.cache_entries(str(tmp_path)) == 1
+
+
+def test_require_tpu_runs_on_cpu_only_when_asked(monkeypatch, capsys):
+    """The one device rule (utils/runtime.py): refuse without a TPU;
+    ASYNCRL_FORCE_CPU=1 is the explicit opt-in, announced on stderr."""
+    from asyncrl_tpu.utils import runtime
+
+    monkeypatch.delenv("ASYNCRL_FORCE_CPU", raising=False)
+    with pytest.raises(SystemExit) as e:
+        runtime.require_tpu("tool")
+    assert e.value.code == 4
+    assert "tool: no TPU" in capsys.readouterr().err
+    monkeypatch.setenv("ASYNCRL_FORCE_CPU", "1")
+    assert runtime.require_tpu("tool") == "cpu"
+    assert "running on CPU" in capsys.readouterr().err
+    assert runtime.device_entry()["platform"] == "cpu"
 
 
 _SCOPES_PROBE = """

@@ -85,10 +85,10 @@ def test_package_gates_clean_under_spmd_passes():
 
 
 def test_entry_points_gate_clean_under_spmd_passes():
-    """The lint.sh entry-point run (scripts/*.py + bench.py +
+    """The lint.sh entry-point run (scripts/*.py + chip_smoke.py +
     __graft_entry__.py) is clean under the same passes it gates with."""
     paths = [os.path.join(REPO, "scripts")] + [
-        os.path.join(REPO, f) for f in ("bench.py", "__graft_entry__.py")
+        os.path.join(REPO, f) for f in ("chip_smoke.py", "__graft_entry__.py")
     ]
     findings = analysis.check_paths(
         paths, passes=("configflow",) + SPMD_PASSES

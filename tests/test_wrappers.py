@@ -170,7 +170,7 @@ def test_ale_knobs_still_learn():
     """VERDICT 'Done = knobs on + still learns' — CI-sized proxy: IMPALA
     on CartPole with frame_skip=2 + sticky 0.25 still beats the random
     baseline clearly. (Pong/atari_impala learning with knobs is a
-    bench-scale run — hours, recorded in BENCH_HISTORY — not a unit
+    bench-scale run of hours, not a unit
     test; this pins that the wrappers don't break gradient flow or
     episode accounting.)"""
     from asyncrl_tpu import make_agent
