@@ -211,7 +211,8 @@ class _ProcessRecord:
         self._compiles: deque[tuple] = deque(maxlen=cap)  # guarded-by: _lock
         self._dropped = 0  # guarded-by: _lock
         self._pool_sites = {"kernel": 0, "fallback": 0}  # guarded-by: _lock
-        self._kda_sites = {"step": 0, "step_kernel": 0, "chunk": 0}  # guarded-by: _lock
+        self._kda_sites = dict.fromkeys(  # guarded-by: _lock
+            ("step", "step_kernel", "chunk", "pair", "pair_kernel"), 0)
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -283,7 +284,8 @@ def phase(name: str):
 def process_record() -> dict[str, Any]:
     """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
-    n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n}}``: copies,
+    n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
+    "pair_kernel": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -301,10 +303,12 @@ def count_pool_site(path: str) -> None:
 
 def count_kda_site(form: str) -> None:
     """One KDA site of a program took the one-token recurrence as its Pallas
-    kernel (``"step_kernel"``) or in plain ``jax.numpy`` (``"step"``), or
-    the chunked fragment form (``"chunk"``): called by ``ops/kda.py``, once
-    per site and trace (where the platform chose, once per site and program
-    lowered), nothing on a steady call."""
+    kernel (``"step_kernel"``) or in plain ``jax.numpy`` (``"step"``), the
+    chunked fragment form (``"chunk"``), or inside that form the pairs of a
+    sub-chunk as their Pallas kernels, forward or backward
+    (``"pair_kernel"``), or as the plain lines (``"pair"``): called by
+    ``ops/kda.py``, once per site and trace (where the platform chose, once
+    per site and program lowered), nothing on a steady call."""
     _RECORD.count_kda_site(form)
 
 

@@ -37,6 +37,19 @@ ever formed is <= 0: pairs inside a 16-token sub-chunk take the exact
 difference ``G_t - G_i`` per channel, pairs across sub-chunks factor it
 through the sub-chunks' edges, so no decay rate overflows a float32.
 
+The pairs inside a sub-chunk are the one term with no matrix product in it:
+the exponent is a pair's own per channel, so the sum over ``d`` has a ``[16,
+16, dk]`` operand a sub-chunk, which XLA writes to HBM and reads again from
+a chunk's forward to its backward (268 MB a block of envs). Where they can,
+those lines (``_plain_pairs``) are a pair of Pallas kernels that hold a
+sub-chunk's ``[16, dk]`` tiles in VMEM and write only the ``[16, 16]``
+results, under a ``custom_vjp`` whose backward is the second kernel: ``E``
+formed again on the chip, the residuals the inputs. Selected as the
+one-token kernel is (``_pairs_fit`` when traced, the platform when
+lowered), and counted: ``kda_sites`` holds ``"pair_kernel"`` and ``"pair"``
+(the plain lines), once per site and program lowered; ``"chunk"`` counts
+the calls of ``kda_chunk``.
+
 Decays, cumulative sums, the state and every accumulation are float32;
 ``dtype`` is what the operands of the matrix products are cast to.
 """
@@ -44,6 +57,7 @@ Decays, cumulative sums, the state and every accumulation are float32;
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -160,7 +174,7 @@ def _kernel_step(S, q, k, v, g, beta, fresh, interpret=False):
 
 
 # Which form a site whose shape fits ended on is known where it is lowered.
-_site_p = site_primitive("kda_step_site", introspect.count_kda_site)
+_site_p = site_primitive("kda_site", introspect.count_kda_site)
 
 
 @jax.custom_vjp
@@ -232,6 +246,258 @@ def _unit_lower_inverse(A):
     return inv
 
 
+# Pairs inside a sub-chunk of ``c`` tokens: ``D[s, j, t, i] = sum_d tk[s, jc+t,
+# d] k[jc+i, d] exp(G[jc+t, d] - G[jc+i, d])`` for ``i <= t``, 0 above the
+# diagonal; ``tk`` [B, H, S, C, dk] stacks the targets, ``k``, ``G`` [B, H, C,
+# dk]. The exponent is a pair's own per channel, so the operand of the sum
+# over ``d`` is [c, c, dk] a sub-chunk.
+
+
+def _on_or_below(c):
+    """[c(t), c(i)]: source ``i`` is target ``t`` or before it."""
+    return jnp.tril(jnp.ones((c, c), bool))
+
+
+def _plain_pairs(tk, k, G, c):
+    """The plain form, and the kernels' reference: the exact difference,
+    masked before the exp, for every pair at once, one reduce over dk. XLA
+    keeps a [c, c, dk] operand from a chunk's forward to its backward (268
+    MB a block of envs)."""
+    sub = lambda x: x.reshape(*x.shape[:-2], -1, c, x.shape[-1])
+    Gs, ks, tks = sub(G), sub(k), sub(tk)
+    later = _on_or_below(c)[:, :, None]  # [t, i, 1]
+    diff = jnp.where(later, Gs[..., :, None, :] - Gs[..., None, :, :], 0.0)
+    src = ks[..., None, :, :] * jnp.where(later, jnp.exp(diff), 0.0)
+    # [..., S, n, c(t), c(i)]
+    return jnp.sum(tks[..., None, :] * src[..., None, :, :, :, :], axis=-1)
+
+
+def _source(i, p, t_of, G_p, g_row, k_row):
+    """Source ``i`` of a sub-chunk against the targets ``t`` of its tile
+    ``p`` (rows 8p..8p+7 on the sublanes; ``p >= i // 8``, the tiles above
+    hold no target after ``i``): ``E[t, d] = exp(G[t, d] - G[i, d])`` and
+    ``k[i, d] E[t, d]``. Only ``i``'s own tile has targets before it: the
+    mask comes before the exp (they read 1, and the caller drops them)."""
+    d = G_p - g_row
+    if p == i // _SUBLANE:
+        d = jnp.where(t_of >= i % _SUBLANE, d, 0.0)
+    E = jnp.exp(d)
+    return E, k_row * E
+
+
+def _tiles(ref, h, base, c):
+    """Head ``h``'s rows ``base..base+c`` of a (1, H, C, dk) ref, a tile each."""
+    return [ref[0, h, pl.ds(base + p, _SUBLANE)] for p in range(0, c, _SUBLANE)]
+
+
+def _pairs_fwd_kernel(g_ref, k_ref, *refs, c):
+    """One env: g_ref, k_ref and the S target refs (1, H, C, dk) -> out_ref
+    (1, H, c, n S c): row ``t``, lane ``(j, s, i)``, so a head's result is
+    one dense tile (a last dimension of 16 is padded to 128 lanes in HBM).
+    A sub-chunk's ``[8, dk]`` tiles stay in registers while its sources go
+    by, ``E`` formed one source and tile at a time; the sum over ``d`` is a
+    lane reduction whose column is selected into the result's lane. The
+    loops over heads and sub-chunks are loops; the ``c`` sources are
+    unrolled (a rolled loop waits out each source's chain of operations,
+    six times slower). Pairs above the diagonal are the caller's to mask."""
+    *tk_refs, out_ref = refs
+    H, C, dk = g_ref.shape[1:]
+    S, tiles = len(tk_refs), range(c // _SUBLANE)
+    t_of = lax.broadcasted_iota(jnp.int32, (_SUBLANE, dk), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (_SUBLANE, out_ref.shape[3]), 1)
+
+    def head(h, _):
+        def sub_chunk(j, acc):
+            base = pl.multiple_of(j * c, c)
+            G, acc = _tiles(g_ref, h, base, c), list(acc)
+            tks = [_tiles(r, h, base, c) for r in tk_refs]
+            for i in range(c):
+                row = pl.ds(base + i, 1)
+                for p in tiles[i // _SUBLANE:]:
+                    _, src = _source(
+                        i, p, t_of, G[p], g_ref[0, h, row], k_ref[0, h, row])
+                    for s in range(S):
+                        col = jnp.sum(tks[s][p] * src, axis=-1, keepdims=True)
+                        acc[p] = jnp.where(
+                            lane == (j * S + s) * c + i, col, acc[p])
+            return tuple(acc)
+
+        acc = lax.fori_loop(
+            0, C // c, sub_chunk, (jnp.zeros(lane.shape, F32),) * len(tiles))
+        out_ref[0, h] = jnp.concatenate(acc, axis=0)
+
+    lax.fori_loop(0, H, head, None)
+
+
+def _pairs_bwd_kernel(g_ref, k_ref, *refs, c):
+    """The forward's tiles and ``dd_ref`` (1, H, c, n S c), the cotangent in
+    the forward's layout and already masked -> dg_ref, dk_ref and the S dtk
+    refs (1, H, C, dk). ``E`` is formed again, one source and tile at a
+    time, and never stored. With ``a_i[t, d] = sum_s dD[s, t, i] tk[s, t, d]``:
+
+        dtk[s, t] = sum_i dD[s, t, i] k[i] E_i[t]    dk[i] = sum_t E_i[t] a_i[t]
+        dG[t] = sum_i k[i] E_i[t] a_i[t] - k[t] dk[t]
+
+    (dG's second term is the sum over the targets of source ``t``)."""
+    S = (len(refs) - 3) // 2
+    tk_refs, dd_ref = refs[:S], refs[S]
+    dg_ref, dk_ref, *dtk_refs = refs[S + 1:]
+    H, C, dk = g_ref.shape[1:]
+    tiles = range(c // _SUBLANE)
+    t_of = lax.broadcasted_iota(jnp.int32, (_SUBLANE, dk), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (_SUBLANE, dd_ref.shape[3]), 1)
+    zero = jnp.zeros((_SUBLANE, dk), F32)
+
+    def head(h, _):
+        dD = [dd_ref[0, h, pl.ds(p * _SUBLANE, _SUBLANE)] for p in tiles]
+
+        def sub_chunk(j, _):
+            base = pl.multiple_of(j * c, c)
+            G = _tiles(g_ref, h, base, c)
+            tks = [_tiles(r, h, base, c) for r in tk_refs]
+            dG, dK = [zero] * len(tiles), [zero] * len(tiles)
+            dtks = [[zero] * len(tiles) for _ in range(S)]
+            for i in range(c):
+                row = pl.ds(base + i, 1)
+                k_row, own = k_ref[0, h, row], i // _SUBLANE
+                Ea_sum = zero
+                for p in tiles[own:]:
+                    E, src = _source(i, p, t_of, G[p], g_ref[0, h, row], k_row)
+                    a = zero
+                    for s in range(S):
+                        col = jnp.sum(
+                            jnp.where(lane == (j * S + s) * c + i, dD[p], 0.0),
+                            axis=-1, keepdims=True)
+                        a = a + col * tks[s][p]
+                        dtks[s][p] = dtks[s][p] + col * src
+                    Ea = E * a
+                    dG[p] = dG[p] + k_row * Ea
+                    Ea_sum = Ea_sum + Ea
+                dK[own] = jnp.where(
+                    t_of == i % _SUBLANE,
+                    jnp.sum(Ea_sum, axis=0, keepdims=True), dK[own])
+            k = _tiles(k_ref, h, base, c)
+            for p in tiles:
+                rows = pl.ds(base + p * _SUBLANE, _SUBLANE)
+                dg_ref[0, h, rows] = dG[p] - k[p] * dK[p]
+                dk_ref[0, h, rows] = dK[p]
+                for ref, dtk in zip(dtk_refs, dtks):
+                    ref[0, h, rows] = dtk[p]
+
+        lax.fori_loop(0, C // c, sub_chunk, None)
+
+    lax.fori_loop(0, H, head, None)
+
+
+def _pairs_vmem(H, S, C, dk, c) -> int:
+    """Double-buffered VMEM of the backward's grid step (the larger call)."""
+    return 2 * 4 * H * (2 * (2 + S) * C * dk + c * max(S * C, _LANE))
+
+
+def _pairs_fit(shape: tuple[int, ...], dtype, c: int) -> bool:
+    """What the pair kernels ask of ``tk``'s static shape: float32, ``[B,
+    H, S, C, dk]`` with whole (8, 128) tiles a sub-chunk, one env's blocks
+    inside the VMEM budget."""
+    if len(shape) != 5 or jnp.dtype(dtype) != F32:
+        return False
+    B, H, S, C, dk = shape
+    return (min(shape) > 0 and dk % _LANE == 0 and c % _SUBLANE == 0
+            and C % c == 0
+            and _pairs_vmem(H, S, C, dk, c) <= _VMEM_BLOCK_BUDGET)
+
+
+def _pairs_call(kernel, name, c, S, inputs, out_shapes, work, interpret):
+    """``pallas_call`` over the envs, one a grid step (the forward reads 4
+    MB at the published widths); ``work`` is the vector unit's operations
+    per pair and channel. Outputs declare the inputs' varying mesh axes."""
+    B, H, C, dk = inputs[0].shape
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+
+    def spec(shape):
+        return pl.BlockSpec((1, *shape[1:]), lambda b: (b, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        functools.partial(kernel, c=c),
+        name=name,
+        grid=(B,),
+        in_specs=[spec(x.shape) for x in inputs],
+        out_specs=[spec(shape) for shape in out_shapes],
+        out_shape=[jax.ShapeDtypeStruct(shape, F32, vma=vma)
+                   for shape in out_shapes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_pairs_vmem(H, S, C, dk, c) + _VMEM_HEADROOM,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=work * B * H * C * c * dk, transcendentals=B * H * C * c * dk,
+            bytes_accessed=4 * sum(math.prod(x.shape) for x in inputs)
+            + 4 * sum(map(math.prod, out_shapes))),
+        interpret=interpret,
+    )(*inputs)
+
+
+def _packed(D):
+    """``[B, H, S, n, t, i]`` <-> ``[B, H, t, n, S, i]``, its own inverse."""
+    return jnp.swapaxes(D, 2, 4)
+
+
+# jit: a layer's sites (the primal pass, two rematerialisations, the backward)
+# and the layers share one trace and one lowering of each unrolled kernel
+@functools.partial(jax.jit, static_argnames=("c", "interpret"))
+def _kernel_pairs(tk, k, G, c, interpret=False):
+    B, H, S, C, _ = tk.shape
+    (out,) = _pairs_call(
+        _pairs_fwd_kernel, "kda_pairs_fwd", c, S,
+        [G, k, *(tk[:, :, s] for s in range(S))], [(B, H, c, S * C)],
+        3 + 2 * S, interpret)
+    D = _packed(out.reshape(B, H, c, C // c, S, c))
+    return jnp.where(_on_or_below(c), D, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("c", "interpret"))
+def _kernel_pairs_bwd(tk, k, G, dD, c, interpret=False):
+    B, H, S, C, _ = tk.shape
+    dD = jnp.where(_on_or_below(c), dD, 0.0)
+    dG, dk, *dtk = _pairs_call(
+        _pairs_bwd_kernel, "kda_pairs_bwd", c, S,
+        [G, k, *(tk[:, :, s] for s in range(S)),
+         _packed(dD).reshape(B, H, c, S * C)], [G.shape] * (2 + S),
+        7 + 6 * S, interpret)
+    return jnp.stack(dtk, axis=2), dk, dG
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pairs(tk, k, G, c):
+    """``_plain_pairs`` at widths the kernels take: on a TPU a kernel that
+    holds a sub-chunk's tiles in VMEM and writes only the ``[S, c, c]``
+    result, with a backward of its own whose residuals are the inputs."""
+    return _pairs_fwd(tk, k, G, c)[0]
+
+
+def _pairs_fwd(tk, k, G, c):
+    D = lax.platform_dependent(
+        tk, k, G,
+        tpu=lambda tk, *xs: _kernel_pairs(
+            _site_p.bind(tk, path="pair_kernel"), *xs, c),
+        default=lambda tk, *xs: _plain_pairs(
+            _site_p.bind(tk, path="pair"), *xs, c))
+    return D, (tk, k, G)
+
+
+def _pairs_bwd(c, residuals, dD):
+    return lax.platform_dependent(
+        *residuals, dD,
+        tpu=lambda tk, *xs: _kernel_pairs_bwd(
+            _site_p.bind(tk, path="pair_kernel"), *xs, c),
+        default=lambda tk, k, G, dD: jax.vjp(
+            functools.partial(_plain_pairs, c=c),
+            _site_p.bind(tk, path="pair"), k, G)[1](dD))
+
+
+_pairs.defvjp(_pairs_fwd, _pairs_bwd)
+
+
 def _pairwise(tk, k, G, g_first, dtype):
     """``P[s, t, i] = sum_d tk[s, t, d] k[i, d] exp(G[t, d] - G[i, d])`` for
     ``i <= t``, 0 above the diagonal. ``tk`` [..., S, C, dk] stacks the
@@ -245,14 +511,12 @@ def _pairwise(tk, k, G, g_first, dtype):
     start = Gs[..., 0, :] - sub(g_first)[..., 0, :]  # [..., n, dk]
     end = Gs[..., -1, :]
 
-    # inside a sub-chunk: the exact difference, masked before the exp, for
-    # every pair at once: one reduce over dk, whose [c, c, dk] operand XLA
-    # keeps from a chunk's forward to its backward (268 MB a block of envs)
-    later = jnp.tril(jnp.ones((c, c), bool))[:, :, None]  # [t, i, 1]
-    diff = jnp.where(later, Gs[..., :, None, :] - Gs[..., None, :, :], 0.0)
-    src = ks[..., None, :, :] * jnp.where(later, jnp.exp(diff), 0.0)
-    # [..., S, n, c(t), c(i)]
-    diag = jnp.sum(tks[..., None, :] * src[..., None, :, :, :, :], axis=-1)
+    # inside a sub-chunk: [..., S, n, c(t), c(i)]
+    if _pairs_fit(tk.shape, tk.dtype, c):
+        diag = _pairs(tk, k, G, c)
+    else:
+        introspect.count_kda_site("pair")
+        diag = _plain_pairs(tk, k, G, c)
     if n == 1:
         return diag[..., 0, :, :]
 

@@ -3,6 +3,7 @@ ops/moe.py, envs/token_task.py) against its plain reference
 (benchmarks/reference/kimi_linear.py), on seeded random weights at the tiny
 preset's sizes, in float32."""
 
+import contextlib
 import dataclasses
 import functools
 from unittest import mock
@@ -267,9 +268,24 @@ def test_the_shares_and_the_shared_expert_once_sum_to_the_uncut_layer(E, k, N, s
 
 
 # (e) the chunked scan against the recurrence
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_kda_matches_the_recurrence(chunk):
-    T, B, H, dk = 50, 2, 2, 16
+def pair_kernels_on_a_cpu():
+    """What a TPU lowers where the pair kernels fit, on a CPU: the platform's
+    choice taken for it, the kernels in the Pallas interpreter."""
+    stack = contextlib.ExitStack()
+    stack.enter_context(mock.patch.object(
+        kda.lax, "platform_dependent", lambda *a, tpu, default: tpu(*a)))
+    for name in ("_kernel_pairs", "_kernel_pairs_bwd"):
+        stack.enter_context(mock.patch.object(
+            kda, name, functools.partial(getattr(kda, name), interpret=True)))
+    return stack
+
+
+@pytest.mark.parametrize("chunk, dk, pair_kernels", [
+    (16, 16, False), (64, 16, False),
+    (64, 128, True),  # the published width, the sub-chunk pairs by the kernels
+])
+def test_chunked_kda_matches_the_recurrence(chunk, dk, pair_kernels):
+    T, B, H = 50, 2, 2
     keys = jax.random.split(jax.random.PRNGKey(9), 7)
     norm = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = norm(jax.random.normal(keys[0], (T, B, H, dk))) * dk ** -0.5
@@ -293,18 +309,108 @@ def test_chunked_kda_matches_the_recurrence(chunk):
     def chunked(S0, q, k, v, g, beta):
         return kda.kda_chunk(S0, q, k, v, g, beta, done, chunk=chunk)
 
+    mix = jax.random.normal(keys[6], (T, B, H, dk))
+    scalar = lambda f: lambda *a: jnp.sum(f(*a)[1] * mix) + jnp.sum(f(*a)[0])
     S_r, o_r = recurrence(S0, q, k, v, g, beta)
-    S_c, o_c = chunked(S0, q, k, v, g, beta)
+    grads_r = jax.grad(scalar(recurrence), argnums=range(6))(S0, q, k, v, g, beta)
+    before = introspect.process_record()["kda_sites"]
+    with pair_kernels_on_a_cpu() if pair_kernels else contextlib.nullcontext():
+        S_c, o_c = chunked(S0, q, k, v, g, beta)
+        grads_c = jax.grad(scalar(chunked), argnums=range(6))(S0, q, k, v, g, beta)
+    # narrow widths count the plain pairs where they are traced; where the
+    # kernels fit, a site is counted when a program holding it is lowered
+    assert (kda_sites_since(before)["pair"] == 0) == pair_kernels
     np.testing.assert_allclose(o_c, o_r, atol=2e-5)
     np.testing.assert_allclose(S_c, S_r, atol=2e-5)
     assert bool(jnp.all(S_c[1] == 0)) and bool(jnp.any(S_c[0] != 0))
-
-    mix = jax.random.normal(keys[6], o_r.shape)
-    scalar = lambda f: lambda *a: jnp.sum(f(*a)[1] * mix) + jnp.sum(f(*a)[0])
-    grads_r = jax.grad(scalar(recurrence), argnums=range(6))(S0, q, k, v, g, beta)
-    grads_c = jax.grad(scalar(chunked), argnums=range(6))(S0, q, k, v, g, beta)
     for a, b in zip(grads_c, grads_r):
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))) + 1e-6)
+
+
+def pair_operands(B, H, S, C, dk, seed=13):
+    """Targets, keys and cumulative decays as ``_chunk`` hands them to the
+    pairs: the steepest decays forget a state in a token, so over a
+    sub-chunk ``G`` falls by hundreds and an exponent formed above the
+    diagonal (``G_i - G_t``, ``i > t``) overflows a float32."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    norm = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    tk = norm(jax.random.normal(keys[0], (B, H, S, C, dk)))
+    k = norm(jax.random.normal(keys[1], (B, H, C, dk)))
+    g = -jnp.exp(jax.random.uniform(keys[2], (B, H, C, dk), minval=-7.0, maxval=5.0))
+    return tk, k, jnp.cumsum(g, axis=2)
+
+
+@pytest.mark.parametrize("n, H", [(1, 3), (4, 2)])
+def test_the_pair_kernel_is_the_plain_lines_to_float32_rounding(n, H):
+    c = kda.SUB
+    tk, k, G = pair_operands(2, H, 2, n * c, 128)
+    assert float(jnp.max(G[:, :, 0] - G[:, :, c - 1])) > 128  # e^128 overflows
+    mine = kda._kernel_pairs(tk, k, G, c, interpret=True)
+    ref = kda._plain_pairs(tk, k, G, c)
+    assert mine.shape == ref.shape == (2, H, 2, n, c, c) and mine.dtype == jnp.float32
+    assert bool(jnp.all(jnp.isfinite(mine)))
+    assert float(jnp.max(jnp.abs(mine - ref))) <= 1e-6 * float(jnp.max(jnp.abs(ref)))
+    # nothing above the diagonal, something on and below it
+    above = ~np.tril(np.ones((c, c), bool))
+    assert not np.any(np.asarray(mine)[..., above]) and np.all(np.asarray(mine)[..., ~above])
+
+
+@pytest.mark.parametrize("n, H", [(1, 3), (4, 2)])
+def test_the_pair_kernels_backward_is_the_plain_lines_vjp(n, H):
+    """For all four inputs (two targets, the keys, the decays), from a
+    cotangent that is not masked: a pair above the diagonal takes none."""
+    c = kda.SUB
+    tk, k, G = pair_operands(2, H, 2, n * c, 128)
+    dD = jax.random.normal(jax.random.PRNGKey(5), (2, H, 2, n, c, c))
+    mine = kda._kernel_pairs_bwd(tk, k, G, dD, c, interpret=True)
+    ref = jax.vjp(functools.partial(kda._plain_pairs, c=c), tk, k, G)[1](dD)
+    for a, b in zip((mine[0][:, :, 0], mine[0][:, :, 1], *mine[1:]),
+                    (ref[0][:, :, 0], ref[0][:, :, 1], *ref[1:])):
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * float(jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("shape, dtype, c, fits", [
+    ((16, 32, 2, 64, 128), jnp.float32, 16, True),  # kimi_linear_rl's, a block of envs
+    ((2, 3, 2, 16, 128), jnp.float32, 16, True),  # a fragment of one sub-chunk
+    ((1, 1, 1, 8, 256), jnp.float32, 8, True),
+    ((2, 2, 2, 32, 16), jnp.float32, 16, False),  # kimi_linear_tiny's width
+    ((16, 32, 2, 64, 128), jnp.bfloat16, 16, False),  # the decays are float32
+    ((32, 2, 64, 128), jnp.float32, 16, False),  # [B, H, S, C, dk] only
+    ((2, 2, 2, 4, 128), jnp.float32, 4, False),  # a sub-chunk under a tile
+    ((16, 512, 2, 64, 128), jnp.float32, 16, False),  # one env's blocks over VMEM
+    ((0, 32, 2, 64, 128), jnp.float32, 16, False),
+])
+def test_the_shapes_the_pair_kernels_take(shape, dtype, c, fits):
+    assert kda._pairs_fit(shape, dtype, c) == fits
+
+
+@pytest.mark.parametrize("dk", [16, 128])
+def test_off_the_tpu_and_at_small_widths_the_pairs_are_the_plain_lines(dk):
+    """By shape when traced (dk = 16), by platform when lowered (dk = 128,
+    here a CPU): counted as ``"pair"``, no Mosaic call in the program, and
+    the values and gradients of the lines as they stood."""
+    T, B, H = 32, 2, 2
+    keys = jax.random.split(jax.random.PRNGKey(4), 6)
+    q, k, v = (jax.random.normal(key, (T, B, H, dk)) * dk ** -0.5 for key in keys[:3])
+    g = -jnp.exp(jax.random.uniform(keys[3], (T, B, H, dk), minval=-7.0, maxval=1.6))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (T, B, H)))
+    done = jnp.zeros((T, B), bool).at[9, 1].set(True)
+    S0 = jnp.zeros((B, H, dk, dk))
+
+    def loss(q, k, g):
+        S, o = kda.kda_chunk(S0, q, k, v, g, beta, done, chunk=32)
+        return jnp.sum(o ** 2) + jnp.sum(S)
+
+    before = introspect.process_record()["kda_sites"]
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(q, k, g)
+    since = kda_sites_since(before)
+    assert since["pair"] > 0 and since["pair_kernel"] == 0 and since["chunk"] == 1
+    assert "tpu_custom_call" not in lowered.as_text()
+    with mock.patch.object(kda, "_pairs_fit", lambda *a: False):  # the lines alone
+        ref = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, g)
+    for a, b in zip(jax.tree.leaves(lowered.compile()(q, k, g)), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
 
 
 # (e') the one-token recurrence as a kernel, and the reset taken on the read
@@ -378,7 +484,8 @@ def test_off_the_tpu_and_at_small_widths_the_step_is_the_plain_form(d, different
     before = introspect.process_record()["kda_sites"]
     mine = jax.jit(wrap(kda.kda_step))(*operands)
     assert kda_sites_since(before) == {
-        "step": 2 if differentiated else 1, "step_kernel": 0, "chunk": 0}
+        "step": 2 if differentiated else 1, "step_kernel": 0, "chunk": 0,
+        "pair": 0, "pair_kernel": 0}
     ref = jax.jit(wrap(kda._plain_step))(*operands)
     for a, b in zip(mine, ref):
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)

@@ -222,7 +222,7 @@ def test_the_one_token_kda_step_compiles_to_one_in_place_kernel_under_its_scope(
     text = jax.jit(model.apply, donate_argnums=2).lower(
         variables, tokens, core).compile().as_text()
     assert {k: v - before[k] for k, v in sites().items()} == {
-        "step": 0, "step_kernel": 1, "chunk": 0}
+        "step": 0, "step_kernel": 1, "chunk": 0, "pair": 0, "pair_kernel": 0}
     calls = re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*', text)
     assert len(calls) == 1
@@ -235,3 +235,47 @@ def test_the_one_token_kda_step_compiles_to_one_in_place_kernel_under_its_scope(
     passes = re.findall(rf"= {re.escape(state)}\S* (\S+?)\(", text)
     assert "get-tuple-element" in passes  # the kernel's own result
     assert set(passes) <= {"parameter", "get-tuple-element", "bitcast"}, passes
+
+
+def test_the_chunked_kda_compiles_its_sub_chunk_pairs_to_kernels_under_its_scope(
+        one_chip):
+    """``kimi_linear_rl``'s KDA widths, one layer's chunked scan over a block
+    of envs, differentiated as the learner does: the pairs inside a
+    sub-chunk lower as the Mosaic kernels, forward and backward, under
+    ``/kda_chunk/`` (``kda_chunk_device_ms`` and ``kda_device_ms`` read that
+    path), and no ``[.., 16, 16, 128]`` operand is left in the program."""
+    from asyncrl_tpu.ops import kda
+
+    T, B, H, d = 256, 16, 32, 128
+    shapes = dict(S0=(B, H, d, d), q=(T, B, H, d), k=(T, B, H, d),
+                  v=(T, B, H, d), g=(T, B, H, d), beta=(T, B, H))
+    args = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for n, s in shapes.items()}
+    done = jax.ShapeDtypeStruct((T, B), jnp.bool_, sharding=one_chip)
+
+    def loss(args, done):
+        with jax.named_scope("kda"):  # as the mixer does
+            S, o = kda.kda_chunk(*(args[n] for n in shapes), done,
+                                 dtype=jnp.bfloat16)
+        return jnp.sum(o) + jnp.sum(S)
+
+    def sites():
+        return introspect.process_record()["kda_sites"]
+
+    before = sites()
+    text = jax.jit(jax.value_and_grad(jax.checkpoint(loss))).lower(
+        args, done).compile().as_text()
+    since = {k: v - before[k] for k, v in sites().items()}
+    # a site a lowering: the primal pass, the outer checkpoint's and the scan
+    # body's own rematerialised forward, the backward
+    assert since == {"step": 0, "step_kernel": 0, "chunk": 1, "pair": 0,
+                     "pair_kernel": 4}
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]+)"', text)
+    assert len(calls) == 4
+    for call in calls:
+        assert "/kda_chunk/" in call, call
+    assert sum(c.endswith("kda_pairs_fwd/pallas_call") for c in calls) == 3
+    assert sum(c.endswith("kda_pairs_bwd/pallas_call") and "transpose(" in c
+               for c in calls) == 1
+    assert not re.findall(r"f32\[[\d,]*16,16,128\]", text)
