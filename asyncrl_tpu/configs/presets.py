@@ -406,6 +406,24 @@ kimi_linear_tiny = kimi_linear_rl.replace(
     total_env_steps=100_000, learning_rate=1e-3,
 )
 
+# LFM2-8B-A1B as a token-level policy on the same path: gated short-conv and
+# grouped-query attention (RoPE, q/k norm) mixers, 4-of-32 routed experts of
+# which this chip holds 8 (models/lfm2_moe.py SHAPES: one chip's share of
+# layers 1-5, each layer shared by 4 chips). 128 envs x 256 tokens = 32,768
+# tokens an update; episodes of 128-2,048 tokens, so the K/V cache, the
+# rotary positions and the conv tail outlive a fragment. 541 M parameters at
+# 16 bytes (RMSProp, donated) -- see benchmarks/configs/lfm2_moe_rl.json.
+lfm2_moe_rl = kimi_linear_rl.replace(
+    seq_model="lfm2_moe_5l",
+    token_task=(16384, 128, 2048, 16, 64),
+    num_envs=128,
+)
+lfm2_moe_tiny = lfm2_moe_rl.replace(
+    seq_model="lfm2_moe_tiny", token_task=(64, 2, 32, 1, 2),
+    num_envs=8, unroll_len=32,
+    total_env_steps=100_000, learning_rate=1e-3,
+)
+
 PRESETS: dict[str, Config] = {
     "cartpole_a3c": cartpole_a3c,
     "cartpole_a3c_cpu": cartpole_a3c_cpu,
@@ -436,6 +454,8 @@ PRESETS: dict[str, Config] = {
     "pendulum_native_ppo": pendulum_native_ppo,
     "kimi_linear_rl": kimi_linear_rl,
     "kimi_linear_tiny": kimi_linear_tiny,
+    "lfm2_moe_rl": lfm2_moe_rl,
+    "lfm2_moe_tiny": lfm2_moe_tiny,
 }
 
 

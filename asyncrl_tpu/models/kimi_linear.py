@@ -4,29 +4,16 @@ mixers, a leading dense feed-forward and routed-expert layers of which this
 chip holds a share, an output head over the held vocabulary slice and a
 value head (the RL addition).
 
-One function in two forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
+The policy's two forms (one token through the carry; a whole fragment from
+the fragment-initial carry), its trunk, heads and counters, and the carry's
+reset-on-read protocol are ``models/seq_common.py``'s, shared with the other
+sequence policy (``models/lfm2_moe.py``). This module holds the shape
+record, the two mixers and the weights. In the fragment form KDA runs
+chunkwise and MLA under an episode mask.
 
-- ``apply(params, tokens [B], core) -> (logits [B, V], value [B], core)``:
-  one token through the carry, the rollout's form. The CALLER resets the
-  carry where an episode ends (``models.networks.reset_core``) and settles
-  it before anything but the policy reads it (``settle_core``).
-- ``apply(params, tokens [T, B], done [T, B], core, actions [T, B],
-  method="fragment") -> (logp, entropy, values [T, B], core, aux)``: the
-  same function over a whole fragment from the fragment-initial carry, the
-  learner's form: projections over all T*B tokens at once, KDA chunkwise,
-  MLA under an episode mask, the head in token blocks (the [T*B, V] float32
-  logits are never whole), resets applied inside, every block rematerialised
-  in the backward pass. ``actions=None`` returns the logits instead (tests).
-
-The carry (``SeqCore``) is a tuple with one entry per layer: a KDA layer's
-``{"S" [B, H, dk, dv] float32, "conv" [B, 3, 3*H*dk], "fresh" [B] bool}``,
-an MLA layer's ``{"kv" [B, L, kv_lora + rope], "len" [B] int32}`` -- two
-kinds of state in one pytree, every leaf with the env axis first. A reset
-does not pass over ``S`` (134 MB a layer at the published widths): it sets
-``fresh``, and the next read of ``S``, in either form, takes zero there, as
-``len`` empties a latent cache whose rows stay. ``settle()`` spends the
-pending resets; a carry that leaves ``rollout.anakin.unroll`` or the
-fragment form is settled (``docs/ARCHITECTURE.md`` "Sequence policy").
+The carry's entries: a KDA layer's ``{"S" [B, H, dk, dv] float32, "conv"
+[B, 3, 3*H*dk], "fresh" [B] bool}``, an MLA layer's ``{"kv" [B, L, kv_lora +
+rope], "len" [B] int32}``.
 
 Precision: operands of the matrix products in ``compute_dtype``; KDA state,
 decays, cumulative sums, softmax, router scores, norms and the head's
@@ -41,11 +28,23 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from flax import struct
 
-from asyncrl_tpu.ops import kda, moe
-
-F32 = jnp.float32
+from asyncrl_tpu.models.seq_common import (  # noqa: F401  (SeqCore: the carry's type, by this name too)
+    F32,
+    SeqCore,
+    SeqPolicyBase,
+    _cache_after,
+    _dot,
+    _env_block,
+    _episode_mask,
+    _rms_norm,
+    _softmax,
+    _short_conv,
+    _to_blocks,
+    _zero_where,
+    seeded,
+)
+from asyncrl_tpu.ops import kda
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,84 +104,11 @@ SHAPES: dict[str, SeqShape] = {
 }
 
 
-@struct.dataclass
-class SeqCore:
-    """The carry: ``layers[i]`` is layer i's state."""
-
-    layers: tuple
-
-    def reset(self, done):
-        """The carry the next token starts from: zero where ``done`` [B].
-        A latent cache is emptied by its length and a KDA state by
-        ``fresh``, both on their next read: the rows and the state stay."""
-        with jax.named_scope("core_reset"):
-            return SeqCore(tuple(
-                {**layer, "len": _zero_where(done, layer["len"])}
-                if "kv" in layer else
-                {**layer, "conv": _zero_where(done, layer["conv"]),
-                 "fresh": layer["fresh"] | done}
-                for layer in self.layers
-            ))
-
-    def settle(self):
-        """The same carry with no reset pending: ``S`` zero where ``fresh``,
-        ``fresh`` all false. For whoever reads ``"S"`` and is neither form
-        of the mixer (the learner's ``S0`` is safe either way)."""
-        with jax.named_scope("core_reset"):
-            return SeqCore(tuple(
-                layer if "kv" in layer else
-                {**layer, "S": _zero_where(layer["fresh"], layer["S"]),
-                 "fresh": jnp.zeros_like(layer["fresh"])}
-                for layer in self.layers
-            ))
-
-
-def _zero_where(done, x):
-    return jnp.where(
-        done.reshape(-1, *([1] * (x.ndim - 1))), jnp.zeros_like(x), x)
-
-
 # ------------------------------------------------------------------ pieces
-
-
-def _dot(x, kernel, dtype):
-    return jnp.matmul(
-        x.astype(dtype), kernel.astype(dtype), preferred_element_type=F32
-    )
-
-
-def _rms_norm(x, scale, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
 def _l2_norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
-def _swiglu(p, x, dtype):
-    h = jax.nn.silu(_dot(x, p["gate"], dtype)) * _dot(x, p["up"], dtype)
-    return _dot(h, p["down"], dtype)
-
-
-def _short_conv(weights, tail, x, done):
-    """Depthwise causal conv over time that never reads across an episode
-    boundary. ``x`` [T, B, N] (or [B, N]: one token, ``done`` None);
-    ``tail`` [B, W-1, N] the inputs before it. Returns (y, new tail)."""
-    if x.ndim == 2:
-        window = jnp.concatenate([tail, x[:, None]], axis=1)
-        return jnp.einsum("bwn,wn->bn", window, weights), window[:, 1:]
-    W, T = weights.shape[0], x.shape[0]
-    ext = jnp.concatenate([jnp.moveaxis(tail, 1, 0), x], axis=0)  # [W-1+T, B, N]
-    alive = jnp.concatenate(
-        [jnp.ones((W - 1, x.shape[1]), F32), 1.0 - done.astype(F32)], axis=0
-    )
-    y, valid = weights[W - 1] * x, jnp.ones_like(alive[:T])
-    for s in range(1, W):  # the input s tokens back, if no boundary since
-        valid = valid * alive[W - 1 - s: W - 1 - s + T]
-        y = y + weights[W - 1 - s] * ext[W - 1 - s: W - 1 - s + T] * valid[..., None]
-    keep = jnp.cumprod(alive[T:][::-1], axis=0)[::-1]  # no boundary up to the end
-    return y, jnp.moveaxis(ext[T:] * keep[..., None], 0, 1)
 
 
 def _kda_mixer(p, x, state, done, shape: SeqShape, dtype):
@@ -229,13 +155,6 @@ def _mla_project(p, x, shape: SeqShape, dtype):
     return q, latent.astype(dtype)
 
 
-def _softmax(scores, mask):
-    scores = jnp.where(mask, scores, -jnp.inf)
-    scores = scores - jax.lax.stop_gradient(jnp.max(scores, axis=-1, keepdims=True))
-    e = jnp.where(mask, jnp.exp(scores), 0.0)
-    return e / jnp.sum(e, axis=-1, keepdims=True)
-
-
 def _mla_step(p, x, state, shape: SeqShape, dtype):
     """One token: write its latent row at ``len``, attend over the rows of
     the current episode with the up-projection absorbed into the query and
@@ -271,27 +190,6 @@ def _mla_step(p, x, state, shape: SeqShape, dtype):
         )
 
 
-def _env_block(batch: int, per_env: int, limit: int = 1 << 26) -> int:
-    """Largest divisor of ``batch`` whose block stays under ``limit``
-    elements of ``per_env`` each."""
-    best = 1
-    for b in range(1, batch + 1):
-        if batch % b == 0 and b * per_env <= limit:
-            best = b
-    return best
-
-
-def _to_blocks(a, axis: int, n: int):
-    """Split the env axis into ``n`` blocks, blocks leading."""
-    shape = a.shape[:axis] + (n, a.shape[axis] // n) + a.shape[axis + 1:]
-    return jnp.moveaxis(a.reshape(shape), axis, 0)
-
-
-def _from_blocks(a, axis: int):
-    a = jnp.moveaxis(a, 0, axis)
-    return a.reshape(a.shape[:axis] + (-1,) + a.shape[axis + 2:])
-
-
 def _mla_fragment(p, x, state, done, shape: SeqShape, dtype):
     """A fragment: keys and values materialised for the cached rows of the
     episode in progress and the fragment's own, causal softmax within the
@@ -304,15 +202,7 @@ def _mla_fragment(p, x, state, done, shape: SeqShape, dtype):
         rows = jnp.concatenate(
             [state["kv"], jnp.moveaxis(latent, 0, 1)], axis=1
         )  # [B, L + T, lora + rope]
-        ends = jnp.cumsum(done.astype(jnp.int32), axis=0)
-        seg = (ends - done.astype(jnp.int32)).T  # [B, T] boundaries before t
-        t = jnp.arange(T)
-        mask = jnp.concatenate([
-            (jnp.arange(L)[None, None, :] < state["len"][:, None, None])
-            & (seg == 0)[:, :, None],
-            (t[None, :, None] >= t[None, None, :])
-            & (seg[:, :, None] == seg[:, None, :]),
-        ], axis=-1)  # [B, T, L + T]
+        mask, ends = _episode_mask(done, state["len"], L)  # [B, T, L + T]
 
         def attend(args):
             q, rows, mask = args  # [b, T, H, dn + rope], [b, L+T, .], [b, T, L+T]
@@ -343,48 +233,17 @@ def _mla_fragment(p, x, state, done, shape: SeqShape, dtype):
         ).reshape(B, T, -1)
         out = _dot(jnp.moveaxis(out, 0, 1), p["o"], dtype)
 
-        # the cache the next fragment starts from: the rows of the episode
-        # in progress, from position 0 (rows past ``len`` are never read)
-        any_done = ends[-1] > 0
-        first = jnp.where(  # row of ``rows`` that lands at position 0
-            any_done, L + T - 1 - jnp.argmax(done[::-1], axis=0) + 1, 0
-        )
-        length = jnp.where(any_done, L + T - first, state["len"] + T)
-        pos = jnp.arange(L)[None, :]
-        src = jnp.where(
-            any_done[:, None] | (pos < state["len"][:, None]),
-            first[:, None] + pos,
-            L + pos - state["len"][:, None],
-        )
-        cache = jnp.take_along_axis(
-            rows, jnp.clip(src, 0, L + T - 1)[..., None], axis=1
-        )
-        return out, {"kv": cache, "len": length.astype(jnp.int32)}
-
-
-def _ffn(p, kind, x, shape: SeqShape, dtype):
-    """The layer's feed-forward on rows ``x`` [N, D]: (y, held-expert loads
-    or None)."""
-    if kind == "dense":
-        return _swiglu(p, x, dtype), None
-    with jax.named_scope("moe"):
-        ids, weights = moe.route(
-            x, p["router"], p["router_bias"], shape.top_k, shape.routed_scale
-        )
-        y, load = moe.held_experts(
-            x, ids, weights, shape.held_experts, shape.num_experts,
-            p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"], dtype,
-        )
-        return y + _swiglu(p["shared"], x, dtype), load
+        src, length = _cache_after(done, ends, state["len"], L)
+        cache = jnp.take_along_axis(rows, src[..., None], axis=1)
+        return out, {"kv": cache, "len": length}
 
 
 # ------------------------------------------------------------------- model
 
 
 @dataclasses.dataclass(frozen=True)
-class SeqPolicy:
-    """See the module docstring. Not a flax module: ``init`` / ``apply``
-    over a plain nested dict, which is all the learner asks of a model."""
+class SeqPolicy(SeqPolicyBase):
+    """See the module docstring and ``seq_common.SeqPolicyBase``."""
 
     shape: SeqShape
     compute_dtype: Any = F32
@@ -418,11 +277,7 @@ class SeqPolicy:
         the inverse softplus of a log-uniform step in [1e-3, 1e-1] (the
         family's convention); the router's correction bias N(0, 0.02)."""
         s = self.shape
-        keys = iter(jax.random.split(key, 64 * (len(s.layers) + 1)))
-
-        def w(*dims, fan_in=None):
-            std = (fan_in or dims[-2]) ** -0.5
-            return std * jax.random.normal(next(keys), dims, F32)
+        w, keys = seeded(key, 64 * (len(s.layers) + 1))
 
         def swiglu(width, *lead):
             return {"gate": w(*lead, s.hidden, width), "up": w(*lead, s.hidden, width),
@@ -475,98 +330,12 @@ class SeqPolicy:
         params["value"] = {"kernel": w(D, 1), "bias": jnp.zeros((1,), F32)}
         return {"params": params}
 
-    def apply(self, variables, *args, method: str | None = None):
-        return getattr(self, method or "step")(variables["params"], *args)
-
-    def _layer(self, p, kind, h, state, done):
+    def _mixer(self, p, mixer, x, state, done):
         s, dtype = self.shape, self.compute_dtype
-        mixer, ffn = kind.split("+")
-        x = _rms_norm(h, p["norm_mixer"], s.eps)
         if mixer == "kda":
-            y, state = _kda_mixer(p["kda"], x, state, done, s, dtype)
+            y, state = _kda_mixer(p, x, state, done, s, dtype)
         elif done is None:
-            y, state = _mla_step(p["mla"], x, state, s, dtype)
+            y, state = _mla_step(p, x, state, s, dtype)
         else:
-            y, state = _mla_fragment(p["mla"], x, state, done, s, dtype)
-        h = h + y
-        x = _rms_norm(h, p["norm_ffn"], s.eps)
-        y, load = _ffn(p["ffn"], ffn, x.reshape(-1, s.hidden), s, dtype)
-        return h + y.reshape(h.shape), state, load
-
-    def _trunk(self, params, tokens, core, done):
-        """Embedding and layers -> (final normed hidden, carry, loads)."""
-        s = self.shape
-        h = jnp.take(params["embed"], tokens, axis=0)
-        states, loads = [], []
-        for i, kind in enumerate(s.layers):
-            p, state = params[f"layer_{i}"], core.layers[i]
-            if done is None:
-                h, state, load = self._layer(p, kind, h, state, None)
-            else:
-                # in blocks of whole envs, each rematerialised in the
-                # backward pass: what is kept of a layer is its input
-                n = tokens.shape[1] // _env_block(
-                    tokens.shape[1], tokens.shape[0], s.block_tokens
-                )
-                h, state, load = jax.lax.map(
-                    jax.checkpoint(
-                        lambda a, p=p, kind=kind: self._layer(p, kind, *a)
-                    ),
-                    (_to_blocks(h, 1, n),
-                     jax.tree.map(lambda c: _to_blocks(c, 0, n), state),
-                     _to_blocks(done, 1, n)),
-                )
-                h = _from_blocks(h, 1)
-                state = jax.tree.map(lambda c: _from_blocks(c, 0), state)
-                load = None if load is None else jnp.sum(load, axis=0)
-            states.append(state)
-            if load is not None:
-                loads.append(load)
-        h = _rms_norm(h, params["final_norm"], s.eps)
-        return h, SeqCore(tuple(states)), loads
-
-    def _value(self, params, h):
-        v = _dot(h, params["value"]["kernel"], self.compute_dtype)
-        return v[..., 0] + params["value"]["bias"][0]
-
-    def step(self, params, tokens, core):
-        h, core, _ = self._trunk(params, tokens, core, None)
-        with jax.named_scope("lm_head"):
-            logits = _dot(h, params["head"], self.compute_dtype)
-        return logits, self._value(params, h), core
-
-    def fragment(self, params, tokens, done, core, actions=None):
-        T, B = tokens.shape
-        h, core, loads = self._trunk(params, tokens, core, done)
-        values = self._value(params, h)
-        core = core.reset(done[-1]).settle()
-        loads = jnp.stack(loads).astype(F32)  # [expert layers, held]
-        aux = {
-            "moe_load_max": jnp.max(loads),
-            "moe_load_mean": jnp.mean(loads),
-            "moe_local_frac": jnp.sum(loads) / (
-                loads.shape[0] * T * B * self.shape.top_k
-            ),
-            "episode_resets": jnp.sum(done.astype(F32)),
-        }
-        if actions is None:
-            with jax.named_scope("lm_head"):
-                return _dot(h, params["head"], self.compute_dtype), values, core, aux
-        n = T * B
-        b = _env_block(n, 1, 2048)
-
-        def head(args):
-            # the scope inside the mapped body: its backward ops keep it
-            with jax.named_scope("lm_head"):
-                h, a = args
-                logits = _dot(h, params["head"], self.compute_dtype)
-                logp = jax.nn.log_softmax(logits, axis=-1)
-                taken = jnp.take_along_axis(logp, a[:, None], axis=-1)[:, 0]
-                return taken, -jnp.sum(jnp.exp(logp) * logp, axis=-1)
-
-        logp, entropy = jax.lax.map(
-            jax.checkpoint(head),
-            (h.reshape(n // b, b, -1),
-             actions.astype(jnp.int32).reshape(n // b, b)),
-        )
-        return logp.reshape(T, B), entropy.reshape(T, B), values, core, aux
+            y, state = _mla_fragment(p, x, state, done, s, dtype)
+        return y, state, {}

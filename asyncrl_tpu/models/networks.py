@@ -305,7 +305,7 @@ class RecurrentQNetwork(nn.Module):
 def reset_core(core, done):
     """Zero the recurrent carry where ``done`` (episode boundary); ``done``
     is [B] bool/float. An LSTM's ``(c, h)`` leaves are [B, H]; a carry
-    that holds more than one kind of state (``kimi_linear.SeqCore``) says
+    that holds more than one kind of state (``seq_common.SeqCore``) says
     itself what a reset is."""
     if hasattr(core, "reset"):
         return core.reset(done.astype(bool))
@@ -315,7 +315,7 @@ def reset_core(core, done):
 
 def settle_core(core):
     """The carry with no reset pending. ``reset_core`` may leave a reset to
-    the policy's next read of the carry (``kimi_linear.SeqCore`` does, for
+    the policy's next read of the carry (``seq_common.SeqCore`` does, for
     its KDA states); whoever hands a carry to anything but the policy (a
     rollout's exit, a recorded ``init_core``) settles it first. The
     identity for a carry that resets eagerly (an LSTM's ``(c, h)``)."""
@@ -334,9 +334,19 @@ def build_model(config, env_spec):
         jnp.bfloat16 if config.precision == "bf16_matmul" else jnp.float32
     )
     if config.seq_model:
-        from asyncrl_tpu.models import kimi_linear
+        from asyncrl_tpu.models import kimi_linear, lfm2_moe
 
-        shape = kimi_linear.SHAPES[config.seq_model]
+        # each sequence policy keeps the shape records it builds
+        for shapes, policy in ((kimi_linear.SHAPES, kimi_linear.SeqPolicy),
+                               (lfm2_moe.SHAPES, lfm2_moe.Lfm2Policy)):
+            if config.seq_model in shapes:
+                shape = shapes[config.seq_model]
+                break
+        else:
+            raise ValueError(
+                f"unknown seq_model {config.seq_model!r}; have "
+                f"{sorted([*kimi_linear.SHAPES, *lfm2_moe.SHAPES])}"
+            )
         if config.algo == "qlearn" or env_spec.num_actions != shape.vocab:
             raise ValueError(
                 f"seq_model={config.seq_model!r} is a policy over its "
@@ -350,7 +360,7 @@ def build_model(config, env_spec):
                 f"positions of an episode; the env's episodes have up to "
                 f"{env_spec.max_episode_steps or 'an unstated number of'} steps"
             )
-        return kimi_linear.SeqPolicy(shape, compute_dtype)
+        return policy(shape, compute_dtype)
     if config.algo == "qlearn":
         if env_spec.continuous:
             raise ValueError(

@@ -1,0 +1,362 @@
+"""What the token-level sequence policies share (``models/kimi_linear.py``,
+``models/lfm2_moe.py``): the carry and its reset-on-read protocol, the trunk
+(embedding, layers in blocks of whole envs, each rematerialised), the
+feed-forward of a layer (dense, or the routed experts this chip holds), the
+blocked output head, the value head and the fragment form's counters, and
+the pieces a mixer is made of (norms, the boundary-aware short conv, the
+episode mask and the cache a fragment leaves).
+
+A policy is ``SeqPolicyBase`` with a shape record of its own and three
+methods: ``initial_core``, ``init`` and ``_mixer``. One function in two
+forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
+
+- ``apply(params, tokens [B], core) -> (logits [B, V], value [B], core)``:
+  one token through the carry, the rollout's form. The CALLER resets the
+  carry where an episode ends (``models.networks.reset_core``) and settles
+  it before anything but the policy reads it (``settle_core``).
+- ``apply(params, tokens [T, B], done [T, B], core, actions [T, B],
+  method="fragment") -> (logp, entropy, values [T, B], core, aux)``: the
+  same function over a whole fragment from the fragment-initial carry, the
+  learner's form: every layer over blocks of whole envs, the head in token
+  blocks (the [T*B, V] float32 logits are never whole), resets applied
+  inside, every block rematerialised in the backward pass. ``actions=None``
+  returns the logits instead (tests).
+
+The carry (``SeqCore``) is a tuple with one entry per layer, every leaf with
+the env axis first, and four kinds of state live in it side by side:
+
+- a KDA layer's ``{"S" [B, H, dk, dv] float32, "conv" [B, W-1, 3 H dk],
+  "fresh" [B] bool}``;
+- a latent-attention layer's ``{"kv" [B, L, lora + rope], "len" [B]}``;
+- a gated short-conv layer's ``{"conv" [B, W-1, D] float32}``;
+- a grouped-query attention layer's ``{"k", "v" [B, L, Hkv * dh], "len"
+  [B]}`` (the keys rotated at their positions in the episode).
+
+A reset decides by what a layer's state holds: a conv tail is zeroed, a
+cache is emptied by its ``len`` (the rows stay), and a KDA state is not
+passed over at all (134 MB a layer at the published widths): the reset sets
+``fresh``, and the next read of ``S``, in either form, takes zero there.
+``settle()`` spends the pending resets; a carry that leaves
+``rollout.anakin.unroll`` or the fragment form is settled.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import struct
+
+from asyncrl_tpu.ops import moe
+
+F32 = jnp.float32
+
+
+@struct.dataclass
+class SeqCore:
+    """The carry: ``layers[i]`` is layer i's state."""
+
+    layers: tuple
+
+    def reset(self, done):
+        """The carry the next token starts from: zero where ``done`` [B].
+        A conv tail is zeroed; a cache is emptied by its length and a KDA
+        state by ``fresh``, both on their next read: the rows and the state
+        stay."""
+        with jax.named_scope("core_reset"):
+            return SeqCore(tuple(_reset(layer, done) for layer in self.layers))
+
+    def settle(self):
+        """The same carry with no reset pending: ``S`` zero where ``fresh``,
+        ``fresh`` all false. For whoever reads ``"S"`` and is neither form
+        of the mixer (the learner's ``S0`` is safe either way)."""
+        with jax.named_scope("core_reset"):
+            return SeqCore(tuple(
+                {**layer, "S": _zero_where(layer["fresh"], layer["S"]),
+                 "fresh": jnp.zeros_like(layer["fresh"])}
+                if "fresh" in layer else layer
+                for layer in self.layers
+            ))
+
+
+def _reset(layer: dict, done) -> dict:
+    out = dict(layer)
+    for name in ("len", "conv"):
+        if name in layer:
+            out[name] = _zero_where(done, layer[name])
+    if "fresh" in layer:
+        out["fresh"] = layer["fresh"] | done
+    return out
+
+
+def _zero_where(done, x):
+    return jnp.where(
+        done.reshape(-1, *([1] * (x.ndim - 1))), jnp.zeros_like(x), x)
+
+
+# ------------------------------------------------------------------ pieces
+
+
+def _dot(x, kernel, dtype):
+    return jnp.matmul(
+        x.astype(dtype), kernel.astype(dtype), preferred_element_type=F32
+    )
+
+
+def _rms_norm(x, scale, eps):
+    # The barrier makes the sum of squares a pass of its own. Fused into
+    # the epilogue of the product that made ``x`` it is summed in the order
+    # of that product's output tiles, which XLA chooses by what else the
+    # program holds in VMEM: the rollout inside the step and the same
+    # rollout alone then differ in a float32's last bits, and sample other
+    # tokens (PERF.md, PR 30).
+    x = jax.lax.optimization_barrier(x.astype(F32))
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def _swiglu(p, x, dtype):
+    h = jax.nn.silu(_dot(x, p["gate"], dtype)) * _dot(x, p["up"], dtype)
+    return _dot(h, p["down"], dtype)
+
+
+def _short_conv(weights, tail, x, done):
+    """Depthwise causal conv over time that never reads across an episode
+    boundary. ``x`` [T, B, N] (or [B, N]: one token, ``done`` None);
+    ``tail`` [B, W-1, N] the inputs before it. Returns (y, new tail)."""
+    if x.ndim == 2:
+        window = jnp.concatenate([tail, x[:, None]], axis=1)
+        return jnp.einsum("bwn,wn->bn", window, weights), window[:, 1:]
+    W, T = weights.shape[0], x.shape[0]
+    ext = jnp.concatenate([jnp.moveaxis(tail, 1, 0), x], axis=0)  # [W-1+T, B, N]
+    alive = jnp.concatenate(
+        [jnp.ones((W - 1, x.shape[1]), F32), 1.0 - done.astype(F32)], axis=0
+    )
+    y, valid = weights[W - 1] * x, jnp.ones_like(alive[:T])
+    for s in range(1, W):  # the input s tokens back, if no boundary since
+        valid = valid * alive[W - 1 - s: W - 1 - s + T]
+        y = y + weights[W - 1 - s] * ext[W - 1 - s: W - 1 - s + T] * valid[..., None]
+    keep = jnp.cumprod(alive[T:][::-1], axis=0)[::-1]  # no boundary up to the end
+    return y, jnp.moveaxis(ext[T:] * keep[..., None], 0, 1)
+
+
+def _softmax(scores, mask):
+    scores = jnp.where(mask, scores, -jnp.inf)
+    scores = scores - jax.lax.stop_gradient(jnp.max(scores, axis=-1, keepdims=True))
+    e = jnp.where(mask, jnp.exp(scores), 0.0)
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+def _env_block(batch: int, per_env: int, limit: int = 1 << 26) -> int:
+    """Largest divisor of ``batch`` whose block stays under ``limit``
+    elements of ``per_env`` each."""
+    best = 1
+    for b in range(1, batch + 1):
+        if batch % b == 0 and b * per_env <= limit:
+            best = b
+    return best
+
+
+def _to_blocks(a, axis: int, n: int):
+    """Split the env axis into ``n`` blocks, blocks leading."""
+    shape = a.shape[:axis] + (n, a.shape[axis] // n) + a.shape[axis + 1:]
+    return jnp.moveaxis(a.reshape(shape), axis, 0)
+
+
+def _from_blocks(a, axis: int):
+    a = jnp.moveaxis(a, 0, axis)
+    return a.reshape(a.shape[:axis] + (-1,) + a.shape[axis + 2:])
+
+
+def _episode_mask(done, length, L: int):
+    """Which rows a fragment's token may attend, of the ``L`` cached rows
+    of the episode in progress followed by the fragment's own ``T``: those
+    of its own episode up to itself. ``done`` [T, B], ``length`` [B] the
+    rows the cache holds. Returns (mask [B, T, L + T], the running count of
+    boundaries [T, B])."""
+    T = done.shape[0]
+    ends = jnp.cumsum(done.astype(jnp.int32), axis=0)
+    seg = (ends - done.astype(jnp.int32)).T  # [B, T] boundaries before t
+    t = jnp.arange(T)
+    mask = jnp.concatenate([
+        (jnp.arange(L)[None, None, :] < length[:, None, None])
+        & (seg == 0)[:, :, None],
+        (t[None, :, None] >= t[None, None, :])
+        & (seg[:, :, None] == seg[:, None, :]),
+    ], axis=-1)
+    return mask, ends
+
+
+def _cache_after(done, ends, length, L: int):
+    """The cache the next fragment starts from holds the rows of the
+    episode in progress, from position 0 (rows past ``len`` are never
+    read). Returns (for each of its ``L`` rows the row of ``[cache,
+    fragment]`` it is taken from [B, L], its length [B])."""
+    T = done.shape[0]
+    any_done = ends[-1] > 0
+    first = jnp.where(  # row of ``rows`` that lands at position 0
+        any_done, L + T - 1 - jnp.argmax(done[::-1], axis=0) + 1, 0
+    )
+    new_length = jnp.where(any_done, L + T - first, length + T)
+    pos = jnp.arange(L)[None, :]
+    src = jnp.where(
+        any_done[:, None] | (pos < length[:, None]),
+        first[:, None] + pos,
+        L + pos - length[:, None],
+    )
+    return jnp.clip(src, 0, L + T - 1), new_length.astype(jnp.int32)
+
+
+def seeded(key, n: int):
+    """``w(*dims, fan_in=None)``: the next of ``n`` seeded N(0, 1/fan_in)
+    matrices (fan-in: the second-to-last dim), and the iterator of keys it
+    draws from."""
+    keys = iter(jax.random.split(key, n))
+
+    def w(*dims, fan_in=None):
+        std = (fan_in or dims[-2]) ** -0.5
+        return std * jax.random.normal(next(keys), dims, F32)
+
+    return w, keys
+
+
+# ------------------------------------------------------------------- model
+
+
+@dataclasses.dataclass(frozen=True)
+class SeqPolicyBase:
+    """See the module docstring. Not a flax module: ``init`` / ``apply``
+    over a plain nested dict, which is all the learner asks of a model.
+    ``shape`` names its ``layers`` ("<mixer>+<dense|moe>"), ``hidden``,
+    ``eps``, ``block_tokens`` and the expert layer's sizes."""
+
+    shape: Any
+    compute_dtype: Any = F32
+
+    # added to the sum the router's chosen scores are renormalised by
+    ROUTE_EPS = 0.0
+
+    def apply(self, variables, *args, method: str | None = None):
+        return getattr(self, method or "step")(variables["params"], *args)
+
+    def _mixer(self, p, mixer, x, state, done):
+        """``x`` [B, D] with ``done`` None (one token) or [T, B, D] ->
+        (y, the layer's state, counters {name: float32 scalar})."""
+        raise NotImplementedError
+
+    def _ffn(self, p, kind, x):
+        """The layer's feed-forward on rows ``x`` [N, D]: (y, counters: the
+        held experts' loads and whether the block was computed densely)."""
+        s, dtype = self.shape, self.compute_dtype
+        if kind == "dense":
+            return _swiglu(p, x, dtype), {}
+        with jax.named_scope("moe"):
+            ids, weights = moe.route(
+                x, p["router"], p["router_bias"], s.top_k, s.routed_scale,
+                self.ROUTE_EPS,
+            )
+            y, load, dense = moe.held_experts(
+                x, ids, weights, s.held_experts, s.num_experts,
+                p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"],
+                dtype,
+            )
+            if "shared" in p:
+                y = y + _swiglu(p["shared"], x, dtype)
+            return y, {"load": load, "dense": dense.astype(F32)}
+
+    def _layer(self, p, kind, h, state, done):
+        s = self.shape
+        mixer, ffn = kind.split("+")
+        x = _rms_norm(h, p["norm_mixer"], s.eps)
+        y, state, seen = self._mixer(p[mixer], mixer, x, state, done)
+        h = h + y
+        x = _rms_norm(h, p["norm_ffn"], s.eps)
+        y, counted = self._ffn(p["ffn"], ffn, x.reshape(-1, s.hidden))
+        return h + y.reshape(h.shape), state, {**seen, **counted}
+
+    def _trunk(self, params, tokens, core, done):
+        """Embedding and layers -> (final normed hidden, carry, each
+        layer's counters summed over its blocks)."""
+        s = self.shape
+        h = jnp.take(params["embed"], tokens, axis=0)
+        states, counters = [], []
+        for i, kind in enumerate(s.layers):
+            p, state = params[f"layer_{i}"], core.layers[i]
+            if done is None:
+                h, state, counted = self._layer(p, kind, h, state, None)
+            else:
+                # in blocks of whole envs, each rematerialised in the
+                # backward pass: what is kept of a layer is its input
+                n = tokens.shape[1] // _env_block(
+                    tokens.shape[1], tokens.shape[0], s.block_tokens
+                )
+                h, state, counted = jax.lax.map(
+                    jax.checkpoint(
+                        lambda a, p=p, kind=kind: self._layer(p, kind, *a)
+                    ),
+                    (_to_blocks(h, 1, n),
+                     jax.tree.map(lambda c: _to_blocks(c, 0, n), state),
+                     _to_blocks(done, 1, n)),
+                )
+                h = _from_blocks(h, 1)
+                state = jax.tree.map(lambda c: _from_blocks(c, 0), state)
+                counted = {k: jnp.sum(v, axis=0) for k, v in counted.items()}
+            states.append(state)
+            counters.append(counted)
+        h = _rms_norm(h, params["final_norm"], s.eps)
+        return h, SeqCore(tuple(states)), counters
+
+    def _value(self, params, h):
+        v = _dot(h, params["value"]["kernel"], self.compute_dtype)
+        return v[..., 0] + params["value"]["bias"][0]
+
+    def step(self, params, tokens, core):
+        h, core, _ = self._trunk(params, tokens, core, None)
+        with jax.named_scope("lm_head"):
+            logits = _dot(h, params["head"], self.compute_dtype)
+        return logits, self._value(params, h), core
+
+    def fragment(self, params, tokens, done, core, actions=None):
+        T, B = tokens.shape
+        h, core, counters = self._trunk(params, tokens, core, done)
+        values = self._value(params, h)
+        core = core.reset(done[-1]).settle()
+        # [expert layers, held]
+        loads = jnp.stack([c["load"] for c in counters if "load" in c]).astype(F32)
+        aux = {
+            "moe_load_max": jnp.max(loads),
+            "moe_load_mean": jnp.mean(loads),
+            "moe_local_frac": jnp.sum(loads) / (
+                loads.shape[0] * T * B * self.shape.top_k
+            ),
+            "episode_resets": jnp.sum(done.astype(F32)),
+            # what the expert layers had to compute, and how many of the
+            # update's blocks took the dense side to do it
+            "moe_local_assignments": jnp.sum(loads),
+            "moe_dense_blocks": sum(c["dense"] for c in counters if "dense" in c),
+        }
+        attended = [c["rows_attended"] for c in counters if "rows_attended" in c]
+        if attended:  # mean rows a query attended, over the attention layers
+            aux["gqa_rows_attended"] = sum(attended) / (len(attended) * T * B)
+        if actions is None:
+            with jax.named_scope("lm_head"):
+                return _dot(h, params["head"], self.compute_dtype), values, core, aux
+        n = T * B
+        b = _env_block(n, 1, 2048)
+
+        def head(args):
+            # the scope inside the mapped body: its backward ops keep it
+            with jax.named_scope("lm_head"):
+                h, a = args
+                logits = _dot(h, params["head"], self.compute_dtype)
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                taken = jnp.take_along_axis(logp, a[:, None], axis=-1)[:, 0]
+                return taken, -jnp.sum(jnp.exp(logp) * logp, axis=-1)
+
+        logp, entropy = jax.lax.map(
+            jax.checkpoint(head),
+            (h.reshape(n // b, b, -1),
+             actions.astype(jnp.int32).reshape(n // b, b)),
+        )
+        return logp.reshape(T, B), entropy.reshape(T, B), values, core, aux

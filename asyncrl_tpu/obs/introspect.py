@@ -213,6 +213,8 @@ class _ProcessRecord:
         self._pool_sites = {"kernel": 0, "fallback": 0}  # guarded-by: _lock
         self._kda_sites = dict.fromkeys(  # guarded-by: _lock
             ("step", "step_kernel", "chunk", "pair", "pair_kernel"), 0)
+        self._moe_sites = dict.fromkeys(  # guarded-by: _lock
+            ("grouped", "gathered", "dense"), 0)
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -252,6 +254,10 @@ class _ProcessRecord:
         with self._lock:
             self._kda_sites[form] += 1
 
+    def count_moe_site(self, path: str) -> None:
+        with self._lock:
+            self._moe_sites[path] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -260,6 +266,7 @@ class _ProcessRecord:
                 "dropped": self._dropped,
                 "pool_sites": dict(self._pool_sites),
                 "kda_sites": dict(self._kda_sites),
+                "moe_sites": dict(self._moe_sites),
             }
 
 
@@ -285,7 +292,8 @@ def process_record() -> dict[str, Any]:
     """``{"phases": [(name, t0, t1)], "compiles": [(event, fun_name, t_end,
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
-    "pair_kernel": n}}``: copies,
+    "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
+    n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -310,6 +318,17 @@ def count_kda_site(form: str) -> None:
     ``ops/kda.py``, once per site and trace (where the platform chose, once
     per site and program lowered), nothing on a steady call."""
     _RECORD.count_kda_site(form)
+
+
+def count_moe_site(path: str) -> None:
+    """One held-expert site of a program being lowered was built with the
+    one buffer over all held experts (``"grouped"``: dense routing, a
+    fragment's tokens), a buffer an expert (``"gathered"``: sparse routing),
+    or every held expert on every token (``"dense"``: a decode step's few
+    tokens; the first two keep that side behind a ``lax.cond``, which the
+    update's ``moe_dense_blocks`` counts): called by ``ops/moe.py``, once
+    per site and program lowered, nothing on a steady call."""
+    _RECORD.count_moe_site(path)
 
 
 def _sig(obj: Any) -> Any:

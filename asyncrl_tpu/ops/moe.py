@@ -5,30 +5,62 @@ token, as the whole model does; this chip computes the part of the result
 that its own experts give and adds nothing for the rest (on one chip the
 layer runs without its exchange). No token is dropped and there is no
 capacity factor. A static shape that can never overflow is every held
-expert on every token (a decode step's few tokens are computed so); over a
-fragment's tokens the experts' rows are gathered into buffers of eight
-times the mean load (a router is not balanced, least of all a fresh one:
-the fullest held expert of the benchmark's cell is sent four times the
-mean), and a block that sends a held expert more than that is computed
-densely instead (``lax.cond`` on the observed load): the same result at
-four times the cost.
+expert on every token (``dense``: a decode step's few tokens are computed
+so, the weights bound it). Over a fragment's tokens the work has to follow
+the assignments routed here, and the routing's density, known when the call
+is traced, says how:
+
+- sparse routing (8 of 256: a held expert's mean load is N/32): each
+  expert's rows are gathered into a buffer of its own of eight times the
+  mean load (``gathered``; a router is not balanced, least of all a fresh
+  one: the fullest held expert of that cell is sent four times the mean),
+  and a block that sends a held expert more than that is computed densely
+  instead (``lax.cond`` on the observed load): the same result at four
+  times the cost;
+- dense routing (4 of 32: the mean load is N/8, so eight times it is every
+  token and those buffers are the dense side): ONE buffer over all held
+  experts (``grouped``), the block's local assignments sorted by expert into
+  it, each expert's rows padded to whole tiles, and a loop over the tiles in
+  use with a product per tile with the weights of the tile's expert: the
+  work follows the assignments routed here, not the buffer's size. Its
+  static size bounds the block's TOTAL local assignments (twice the expected
+  ``N k held / E``, never more than the worst case ``N min(k, held)``), so
+  an expert's imbalance costs nothing, and only a collective preference for
+  the held experts overflows it: that block is computed densely
+  (``lax.cond``), the same result.
+
+``process_record()["moe_sites"]`` counts, per site and program lowered,
+which side a call was built with.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from asyncrl_tpu.obs import introspect
+from asyncrl_tpu.ops.site import site_primitive
+
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+# Rows of a tile of the grouped side. A tile's products read its expert's
+# weights once: at 512 rows they are bound by the MXU, not by those bytes
+# (2 x 512 flop a weight byte at bfloat16 against the v5e's 240), and the
+# padding to whole tiles is half a tile an expert.
+TILE = 512
+
+_site_p = site_primitive("moe_site", introspect.count_moe_site)
 
 
-def route(x, router_kernel, router_bias, top_k: int, scale: float):
+def route(x, router_kernel, router_bias, top_k: int, scale: float,
+          norm_eps: float = 0.0):
     """``x`` [N, D] -> (expert ids [N, k], weights [N, k]), in float32:
     sigmoid scores, the top k of score + correction bias (a buffer: no
-    gradient reaches it), weights renormalised over the chosen scores."""
+    gradient reaches it), weights renormalised over the chosen scores
+    (``norm_eps`` added to their sum where the model's own code does)."""
     with jax.named_scope("moe_router"):
         scores = jax.nn.sigmoid(jnp.matmul(
             x.astype(F32), router_kernel.astype(F32), precision=HIGHEST
@@ -37,7 +69,10 @@ def route(x, router_kernel, router_bias, top_k: int, scale: float):
             scores + jax.lax.stop_gradient(router_bias.astype(F32)), top_k
         )
         chosen = jnp.take_along_axis(scores, ids, axis=-1)
-        weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        if norm_eps:
+            total = total + norm_eps
+        weights = scale * chosen / total
         return ids, weights
 
 
@@ -54,8 +89,10 @@ def _expert_act(x, gate, up, dtype):
     return jax.nn.silu(mm(gate)) * mm(up)
 
 
-def _down(spec, act, down, dtype):
-    return jnp.einsum(spec, act.astype(dtype), down, preferred_element_type=F32)
+def _down(act, down, dtype):
+    """A product an expert: [E, n, F] x [E, F, D] -> [E, n, D] float32."""
+    return jnp.einsum(
+        "enf,efd->end", act.astype(dtype), down, preferred_element_type=F32)
 
 
 def held_token_weights(ids, weights, held):
@@ -65,20 +102,204 @@ def held_token_weights(ids, weights, held):
     return jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
 
 
-def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype):
-    """sum over held experts of ``w E(x)``: [N, D] float32, and the number
-    of tokens each held expert was sent [E_held]."""
+# ------------------------------------------------------------ grouped side
+
+
+def _mm(a, b, dtype):
+    return jnp.matmul(a.astype(dtype), b.astype(dtype), preferred_element_type=F32)
+
+
+def _index(weights, e):
+    return tuple(
+        jax.lax.dynamic_index_in_dim(w, e, keepdims=False) for w in weights
+    )
+
+
+def _varying_as(a, like):
+    """``a`` varying over the mesh axes ``like`` varies over (inside a
+    ``shard_map``; the identity outside one). JAX casts a replicated operand
+    of a product with a sharded one by itself; a scan's carry and a
+    ``custom_vjp``'s operands have to be cast by hand."""
+    axes = tuple(jax.typeof(like).vma - jax.typeof(a).vma)
+    return jax.lax.pcast(a, axes, to="varying") if axes else a
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def tile_experts(xt, tile_expert, used, gate, up, down, dtype):
+    """A SwiGLU per tile with the weights of the tile's expert: ``xt``
+    [tiles, rows, D], ``tile_expert`` [tiles] int32, ``used`` the leading
+    tiles that hold a row, weights [E, D, F] / [E, F, D] in ``dtype`` ->
+    [tiles, rows, D] float32, zero past ``used``. A loop over the used
+    tiles alone (the work follows the assignments, not the buffer's size)
+    that indexes the weights: no per-tile copy of them is formed, in this
+    pass or in the backward one (below: three float32 accumulators
+    [E, D, F] in the carry, one expert's slice of each updated a tile)."""
+    def one(i, y):
+        x, (g, u, d) = xt[i], _index((gate, up, down), tile_expert[i])
+        out = _mm(jax.nn.silu(_mm(x, g, dtype)) * _mm(x, u, dtype), d, dtype)
+        return jax.lax.dynamic_update_index_in_dim(y, out, i, 0)
+
+    return jax.lax.fori_loop(
+        0, used, one, _varying_as(jnp.zeros(xt.shape, F32), xt))
+
+
+def _tile_experts_fwd(xt, tile_expert, used, gate, up, down, dtype):
+    return tile_experts(xt, tile_expert, used, gate, up, down, dtype), (
+        xt, tile_expert, used, gate, up, down)
+
+
+def _tile_experts_bwd(dtype, residuals, dy):
+    xt, tile_expert, used, gate, up, down = residuals
+
+    def one(i, carry):
+        acc, dxt = carry
+        x, e = xt[i], tile_expert[i]
+        g, u, d = _index((gate, up, down), e)
+        a, b = _mm(x, g, dtype), _mm(x, u, dtype)
+        sig = jax.nn.sigmoid(a)
+        s = a * sig
+        dh = _mm(dy[i], d.T, dtype)
+        da = dh * b * (sig + s * (1.0 - sig))
+        db = dh * s
+        dx = _mm(da, g.T, dtype) + _mm(db, u.T, dtype)
+        steps = (_mm(x.T, da, dtype), _mm(x.T, db, dtype), _mm((s * b).T, dy[i], dtype))
+        acc = tuple(
+            jax.lax.dynamic_update_index_in_dim(
+                total, jax.lax.dynamic_index_in_dim(total, e, keepdims=False) + step,
+                e, 0)
+            for total, step in zip(acc, steps)
+        )
+        return acc, jax.lax.dynamic_update_index_in_dim(
+            dxt, dx.astype(dxt.dtype), i, 0)
+
+    zeros = lambda shape, dtype: _varying_as(jnp.zeros(shape, dtype), dy)
+    acc, dxt = jax.lax.fori_loop(0, used, one, (
+        tuple(zeros(w.shape, F32) for w in (gate, up, down)),
+        zeros(xt.shape, xt.dtype),
+    ))
+    return (dxt, None, None, *(
+        total.astype(w.dtype) for total, w in zip(acc, (gate, up, down))))
+
+
+tile_experts.defvjp(_tile_experts_fwd, _tile_experts_bwd)
+
+
+def _picked(rows, index):
+    """``rows[index]``, zeros where ``index`` points past the last row."""
+    return jnp.take(rows, index, axis=0, mode="fill", fill_value=0)
+
+
+# Tokens into the buffer's rows and back. Every assignment has a row of its
+# own (``dest`` [N, k]: the row of token n's j-th assignment, past the last
+# row if that expert is not held) and every row at most one token
+# (``source`` [rows]: its token, N if it holds none), so the transpose of
+# either gather is the other index's gather. Left to autodiff it is a
+# scatter-add of whole rows, which the TPU runs a row at a time.
+
+
+@jax.custom_vjp
+def _to_rows(x, source, dest):
+    """``x`` [N, D] -> the buffer [rows, D]."""
+    return _picked(x, source)
+
+
+def _to_rows_bwd(residuals, g):
+    dest = residuals
+    return sum(_picked(g, dest[:, j]) for j in range(dest.shape[1])), None, None
+
+
+_to_rows.defvjp(lambda x, source, dest: (_picked(x, source), dest), _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(y, weights, source, dest):
+    """The buffer's results [rows, D] -> sum over a token's assignments of
+    ``weights`` [N, k] times its row: [N, D]."""
+    return sum(_picked(y, dest[:, j]) * weights[:, j, None]
+               for j in range(dest.shape[1]))
+
+
+def _from_rows_bwd(residuals, g):
+    y, weights, source, dest = residuals
+    by_row = jnp.zeros((y.shape[0],), weights.dtype).at[dest.reshape(-1)].set(
+        weights.reshape(-1), mode="drop")
+    dweights = jnp.stack(
+        [jnp.sum(_picked(y, dest[:, j]) * g, axis=-1) for j in range(dest.shape[1])],
+        axis=1)
+    return _picked(g, source) * by_row[:, None], dweights, None, None
+
+
+_from_rows.defvjp(
+    lambda y, weights, source, dest: (
+        _from_rows(y, weights, source, dest), (y, weights, source, dest)),
+    _from_rows_bwd)
+
+
+def grouped_rows(n_tokens: int, top_k: int, n_held: int, num_experts: int,
+                 tile: int) -> int:
+    """Rows of the grouped side's buffer: twice the local assignments a
+    balanced router sends a block (never more than the worst case), in
+    whole tiles, and a tile an expert for the padding to whole tiles."""
+    expected = n_tokens * top_k * n_held / num_experts
+    worst = n_tokens * min(top_k, n_held)
+    return (math.ceil(min(2 * expected, worst) / tile) + n_held) * tile
+
+
+def _grouped(x, ids, weights, held, starts, ends, rows, tile, gate, up, down,
+             dtype):
+    """sum over held experts of ``w E(x)`` through one buffer of ``rows``
+    rows, expert ``e``'s assignments in rows ``starts[e]`` on and its
+    padding to whole tiles before ``ends[e]``."""
     n_tokens, width = x.shape
+    n_held = len(held)
+    # where each assignment goes: its expert's first row + its rank among
+    # the expert's assignments (token order); ``rows`` (dropped on the way
+    # in, zeros on the way back) if the expert is not held
+    hit = (ids[:, :, None] == jnp.asarray(held, ids.dtype)[None, None, :])
+    hit = hit.reshape(-1, n_held)
+    rank = jnp.cumsum(hit.astype(jnp.int32), axis=0) - 1
+    dest = jnp.sum(jnp.where(hit, rank + starts[None, :], 0), axis=1)
+    dest = jnp.where(jnp.any(hit, axis=1), dest, rows).reshape(ids.shape)
+    token = jnp.broadcast_to(jnp.arange(n_tokens)[:, None], ids.shape)
+    source = jnp.full((rows,), n_tokens, jnp.int32).at[dest.reshape(-1)].set(
+        token.reshape(-1), mode="drop")
+    xg = _to_rows(x, source, dest)  # [rows, D]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(rows // tile) * tile, side="right"),
+        n_held - 1,
+    ).astype(jnp.int32)
+    y = tile_experts(
+        xg.reshape(-1, tile, width), tile_expert, ends[-1] // tile,
+        *(_varying_as(w, xg) for w in (gate, up, down)), dtype
+    ).reshape(rows, width)
+    return _from_rows(y, weights, source, dest)
+
+
+def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
+                 tile: int | None = None):
+    """sum over held experts of ``w E(x)``: [N, D] float32, the number of
+    tokens each held expert was sent [E_held], and whether the block was
+    computed densely. ``tile``: rows of a tile of the grouped side where
+    a test chooses them (``TILE`` is the chip's)."""
+    n_tokens, width = x.shape
+    tile = tile or TILE
     tw = held_token_weights(ids, weights, held)  # [N, E]
     load = jnp.sum(tw > 0, axis=0)
     # rows of an expert's gathered buffer: eight times its mean load, in 128s
     capacity = -(-8 * n_tokens * ids.shape[1] // (num_experts * 128)) * 128
+    rows = grouped_rows(n_tokens, ids.shape[1], len(held), num_experts, tile)
     # cast once, outside the branches below
     gate, up, down = (w.astype(dtype) for w in (gate, up, down))
 
     def dense_block(x, tw):
+        # a product an expert, then the experts' parts added one after the
+        # other. As ONE product with two contracted axes ("enf,efd->nd")
+        # XLA tiles the sum by what else the program holds in VMEM, so a
+        # rollout inside the step and the same rollout alone summed in
+        # another order and sampled other tokens (PERF.md, PR 30)
         act = _expert_act(x, gate, up, dtype) * tw.T[..., None]
-        return _down("enf,efd->nd", act, down, dtype)
+        parts = _down(act, down, dtype)
+        return functools.reduce(jnp.add, list(parts))
 
     def dense(_):
         # every held expert on every token, 2,048 tokens at a time: at a
@@ -99,19 +320,35 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype):
         rows = jnp.where(
             jnp.arange(capacity)[None, :] < load[:, None], order, n_tokens
         )
-        xg = jnp.take(x, rows, axis=0, mode="fill", fill_value=0)  # [E, C, D]
+        xg = _picked(x, rows)  # [E, C, D]
         wg = jnp.take_along_axis(
             jnp.pad(tw.T, ((0, 0), (0, 1))), rows, axis=1
         )
         act = _expert_act(xg, gate, up, dtype) * wg[..., None]
-        y = _down("enf,efd->end", act, down, dtype)
+        y = _down(act, down, dtype)
         return jnp.zeros((n_tokens, width), F32).at[rows.reshape(-1)].add(
             y.reshape(-1, width), mode="drop"
         )
 
     with jax.named_scope("moe_experts"):
-        if capacity >= n_tokens:  # a decode step's few tokens
+        if capacity < n_tokens:  # sparse routing: a buffer an expert
+            x = _site_p.bind(x, path="gathered")
+            fits = jnp.max(load) <= capacity
+            out = jax.lax.cond(fits, gathered, dense, None)
+        elif 3 * rows <= 2 * len(held) * n_tokens:
+            # dense routing, a fragment's tokens: the buffer is a third
+            # smaller than the dense side or more
+            x = _site_p.bind(x, path="grouped")
+            padded = -(-load // tile) * tile  # an expert's rows, in whole tiles
+            ends = jnp.cumsum(padded)
+            fits = ends[-1] <= rows
+            out = jax.lax.cond(
+                fits,
+                lambda _: _grouped(x, ids, weights, held, ends - padded, ends,
+                                   rows, tile, gate, up, down, dtype),
+                dense, None)
+        else:  # a decode step's few tokens
+            x = _site_p.bind(x, path="dense")
+            fits = jnp.zeros((), bool)
             out = dense(None)
-        else:
-            out = jax.lax.cond(jnp.max(load) <= capacity, gathered, dense, None)
-    return out, load
+    return out, load, ~fits

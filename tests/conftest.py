@@ -179,6 +179,10 @@ QUICK: dict[str, object] = {
     # The Kimi-Linear sequence policy against its plain reference at the
     # tiny preset (ISSUE 26): forms, carry, shares, chunked scan, training.
     "test_kimi_linear.py": "all",
+    # The LFM2-MoE sequence policy against its plain reference at the tiny
+    # preset (ISSUE 30): forms, carry, positions, the four shares, the
+    # grouped expert side against the dense one, Kimi's values as they were.
+    "test_lfm2_moe.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for

@@ -215,7 +215,9 @@ def test_step_form_through_reset_core_matches_the_fragment_form(policy):
 # (d) the share ties to the model
 @pytest.mark.parametrize("E, k, N, skew, path", [
     (8, 2, 64, 0.0, "dense"),  # a decode step's tokens: the buffer would hold them all
-    (8, 2, 4096, 0.0, "dense"),  # the same, in blocks of 2,048 tokens
+    # a fragment's tokens under dense routing (2 of 8): a buffer an expert
+    # would hold them all, so one buffer over the held experts (ISSUE 30)
+    (8, 2, 4096, 0.0, "grouped"),
     (64, 2, 4096, 0.0, "gathered"),  # a fragment's tokens, a router in balance
     (64, 2, 4096, 10.0, "overflow"),  # every token sent to expert 0: no token dropped
 ])
@@ -238,28 +240,32 @@ def test_the_shares_and_the_shared_expert_once_sum_to_the_uncut_layer(E, k, N, s
     ids, weights = moe.route(x, full["router"], full["router_bias"], k, 2.446)
     total = reference._swiglu(full["shared"], x, False)  # the shared expert, once
     halves = (tuple(range(E // 2)), tuple(range(E // 2, E)))
-    loads = []
+    loads, densely = [], []
     for held in halves:
         rows = {n: full["experts"][n][jnp.asarray(held)] for n in ("gate", "up", "down")}
-        part, load = jax.jit(moe.held_experts, static_argnums=(3, 4, 8))(
+        part, load, dense = jax.jit(moe.held_experts, static_argnums=(3, 4, 8))(
             x, ids, weights, held, E, rows["gate"], rows["up"], rows["down"],
             jnp.float32)
         total = total + part
         loads.append(load)
+        densely.append(bool(dense))
     np.testing.assert_allclose(total, uncut, atol=2e-4)
     assert int(jnp.sum(jnp.concatenate(loads))) == N * k  # no token dropped
     # the case takes the path it is named for: the buffer holds eight times
     # the mean load, in rows of 128
     buffer = -(-8 * N * k // (E * 128)) * 128
     fullest = int(jnp.max(jnp.concatenate(loads)))
-    assert {"dense": buffer >= N, "gathered": fullest <= buffer < N,
-            "overflow": buffer < fullest}[path]
+    assert {"dense": buffer >= N, "grouped": buffer >= N,
+            "gathered": fullest <= buffer < N, "overflow": buffer < fullest}[path]
+    # ... and says so (the share with the favoured expert overflows alone)
+    assert {"dense": all(densely), "overflow": densely == [True, False]}.get(
+        path, not any(densely))
     # and the reference's own share of it agrees with the program's
     first = halves[0]
     share = reference.expert_layer(
         {**full, "experts": {n: v[:len(first)] for n, v in full["experts"].items()}},
         x, {**dims, "held_experts": first})
-    part, _ = moe.held_experts(
+    part, _, _ = moe.held_experts(
         x, ids, weights, first, E, *(full["experts"][n][:len(first)]
                                      for n in ("gate", "up", "down")),
         jnp.float32)
