@@ -49,6 +49,7 @@ from asyncrl_tpu.models.seq_common import (
     _to_blocks,
     seeded,
 )
+from asyncrl_tpu.ops.gqa import gqa_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,33 +141,18 @@ def _gqa_project(p, x, pos, shape: Lfm2Shape, dtype):
 
 def _gqa_step(p, x, state, shape: Lfm2Shape, dtype):
     """One token: write its key and value rows at ``len``, attend over the
-    rows of the current episode; query head j reads key-value head j // 4.
-    Both products run over the cache's whole rows, batched over envs only
-    (the cache is read as it lies, once each): a query is laid into its
-    key-value head's lanes of a row of zeros, and of the weighted values a
-    head keeps its own key-value head's lanes. Eight times the flops of a
-    product a head group, on a step the cache's bytes bound."""
-    H, G, dh = shape.heads, shape.kv_heads, shape.head_dim
+    rows of the current episode (``ops/gqa.py``: the cache is read up to
+    ``len`` where its kernel runs, whole under a mask elsewhere); query head
+    j reads key-value head j // 4. The cache's arrays go to it as the write
+    leaves them: rows of 512, env-major, nothing copied."""
     with jax.named_scope("gqa"):
         B = x.shape[0]
         q, k, v = _gqa_project(p, x, state["len"], shape, dtype)
         at = (jnp.arange(B), state["len"])
         keys, values = state["k"].at[at].set(k), state["v"].at[at].set(v)
-        own = (jnp.arange(H)[:, None] // (H // G) == jnp.arange(G)[None, :])
-        own = own.astype(F32)[None, :, :, None]  # [1, H, G, 1]
-        scores = jnp.einsum(
-            "bhc,bpc->bhp", (q[:, :, None, :] * own).reshape(B, H, G * dh).astype(dtype),
-            keys, preferred_element_type=F32,
-        ) / math.sqrt(dh)
-        mask = jnp.arange(keys.shape[1])[None, :] <= state["len"][:, None]
-        probs = _softmax(scores, mask[:, None, :])
-        out = jnp.einsum(
-            "bhp,bpc->bhc", probs.astype(dtype), values,
-            preferred_element_type=F32,
-        )
-        out = jnp.sum(out.reshape(B, H, G, dh) * own, axis=2)
+        out = gqa_step(q, keys, values, state["len"])
         return (
-            _dot(out.reshape(B, H * dh), p["o"], dtype),
+            _dot(out.reshape(B, shape.heads * shape.head_dim), p["o"], dtype),
             {"k": keys, "v": values, "len": state["len"] + 1},
         )
 

@@ -215,6 +215,7 @@ class _ProcessRecord:
             ("step", "step_kernel", "chunk", "pair", "pair_kernel"), 0)
         self._moe_sites = dict.fromkeys(  # guarded-by: _lock
             ("grouped", "gathered", "dense"), 0)
+        self._gqa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -258,6 +259,10 @@ class _ProcessRecord:
         with self._lock:
             self._moe_sites[path] += 1
 
+    def count_gqa_site(self, form: str) -> None:
+        with self._lock:
+            self._gqa_sites[form] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -267,6 +272,7 @@ class _ProcessRecord:
                 "pool_sites": dict(self._pool_sites),
                 "kda_sites": dict(self._kda_sites),
                 "moe_sites": dict(self._moe_sites),
+                "gqa_sites": dict(self._gqa_sites),
             }
 
 
@@ -293,7 +299,7 @@ def process_record() -> dict[str, Any]:
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
     "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
-    n}}``: copies,
+    n}, "gqa_sites": {"step": n, "step_kernel": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -329,6 +335,15 @@ def count_moe_site(path: str) -> None:
     update's ``moe_dense_blocks`` counts): called by ``ops/moe.py``, once
     per site and program lowered, nothing on a steady call."""
     _RECORD.count_moe_site(path)
+
+
+def count_gqa_site(form: str) -> None:
+    """One one-token grouped-query attention site of a program took the
+    Pallas kernel that reads the cache up to ``len`` (``"step_kernel"``) or
+    the plain lines over its whole capacity (``"step"``): called by
+    ``ops/gqa.py``, once per site and trace (where the platform chose, once
+    per site and program lowered), nothing on a steady call."""
+    _RECORD.count_gqa_site(form)
 
 
 def _sig(obj: Any) -> Any:

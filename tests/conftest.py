@@ -183,6 +183,10 @@ QUICK: dict[str, object] = {
     # preset (ISSUE 30): forms, carry, positions, the four shares, the
     # grouped expert side against the dense one, Kimi's values as they were.
     "test_lfm2_moe.py": "all",
+    # The one-token attention's kernel (ops/gqa.py, ISSUE 31) in the Pallas
+    # interpreter against the plain lines: every edge of a chunk, rows
+    # beyond len, the VJP, the choice of form and its counter.
+    "test_gqa.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for

@@ -538,6 +538,12 @@ def test_the_step_names_the_scopes_a_profile_reads():
         # the backward pass keeps the scopes
         assert some("/loss_and_grad/", "transpose(", scope), scope
     assert some("/rollout/", "/core_reset/")
+    # the one-token attention's own scope inside ``gqa``: ``gqa_device_ms``
+    # still holds it, and a reader can select it by name (the learner's
+    # bootstrap token is differentiated, and reads ``jvp(gqa)/gqa_step``)
+    assert some("/rollout/", "/actor_forward/", "/gqa/gqa_step/")
+    assert some("/loss_and_grad/", "gqa)/gqa_step/")
+    assert not some("/loss_and_grad/", "transpose(", "gqa_step")
 
 
 # (j) the rule that keeps the update's rollout its replay's to the last bit

@@ -279,3 +279,62 @@ def test_the_chunked_kda_compiles_its_sub_chunk_pairs_to_kernels_under_its_scope
     assert sum(c.endswith("kda_pairs_bwd/pallas_call") and "transpose(" in c
                for c in calls) == 1
     assert not re.findall(r"f32\[[\d,]*16,16,128\]", text)
+
+
+def test_the_one_token_attention_compiles_to_one_kernel_that_leaves_the_cache_in_place(
+        one_chip):
+    """``lfm2_moe_rl``'s attention widths and cache, one layer, a scan of
+    one-token steps as the rollout runs them: ``ops/gqa.py``'s Mosaic kernel
+    under ``/gqa/gqa_step/`` (``gqa_device_ms`` reads that path, and a
+    reader can select the kernel by its name), and nothing in the loop's
+    body copies a ``[128, 2048, 512]`` array or lays it out again: the
+    kernel's operands are the arrays the row's write leaves (PERF.md, PR 30:
+    a copy of the cache a token cost a third of the rollout)."""
+    from asyncrl_tpu.models import lfm2_moe
+
+    shape = lfm2_moe.Lfm2Shape(
+        hidden=256, vocab=512, layers=("gqa+moe",),
+        heads=32, kv_heads=8, head_dim=64, rope_theta=1e6,
+        dense_ffn=256, expert_ffn=32, num_experts=8, held_experts=(0, 1),
+        top_k=2, routed_scale=1.0, max_positions=2048,
+    )
+    model = lfm2_moe.Lfm2Policy(shape, compute_dtype=jnp.bfloat16)
+    B, T = 128, 4
+    variables, core = jax.eval_shape(
+        lambda: (model.init(jax.random.key(0)), model.initial_core(B)))
+    variables, tokens, core = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables, jax.ShapeDtypeStruct((T, B), jnp.int32), core))
+
+    def rollout(variables, tokens, core):
+        def step(core, token):
+            logits, value, core = model.apply(variables, token, core)
+            return core, (jnp.argmax(logits, axis=-1), value)
+        return jax.lax.scan(step, core, tokens)
+
+    def gqa_sites():
+        return introspect.process_record()["gqa_sites"]
+
+    before = gqa_sites()
+    text = jax.jit(rollout, donate_argnums=2).lower(
+        variables, tokens, core).compile().as_text()
+    assert {k: v - before[k] for k, v in gqa_sites().items()} == {
+        "step": 0, "step_kernel": 1}
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 1
+    (call,) = calls
+    assert re.search(r'op_name="[^"]*/gqa/gqa_step/[^"]*pallas_call', call), call
+    assert re.search(r"%gqa_step\S* = \S+ custom-call\(", text)  # the kernel's name
+    # what passes over the cache: the row's write, in place, and the kernel
+    cache = rf"bf16\[{B},2048,512\]"
+    passes = re.findall(rf"= {cache}\S* (\S+?)\(", text)
+    assert "fusion" in passes or "scatter" in passes  # the write
+    assert set(passes) <= {"parameter", "get-tuple-element", "bitcast",
+                           "fusion", "scatter"}, passes
+    # and the fusions that give a cache back are the scatters of one row
+    for body in re.findall(
+            rf"\n(%fused_computation\S*) \([^\n]*\) -> {cache} \{{(.*?)\n\}}", text,
+            flags=re.S):
+        assert "scatter(" in body[1], body[0]
+    # rows beyond len stay in HBM: no product over the capacity is left
+    assert not re.findall(rf"f32\[{B},32,2048\]", text)
