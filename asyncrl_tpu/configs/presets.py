@@ -424,6 +424,28 @@ lfm2_moe_tiny = lfm2_moe_rl.replace(
     total_env_steps=100_000, learning_rate=1e-3,
 )
 
+# Keye-VL-2.0-30B-A3B's language model as a token-level policy on the same
+# path: grouped-query attention under a learned sparse-attention indexer
+# (the top 2,048 rows of a cache of 8,192: ops/dsa.py), 8-of-128
+# softmax-routed experts of which this chip holds 16 (models/keye_moe.py
+# SHAPES: one chip's share of layers 0-3, each layer shared by 8 chips).
+# 16 envs x 512 tokens = 8,192 tokens an update; episodes of 2,048-8,192
+# tokens, so most queries have more rows behind them than they may attend.
+# 465 M parameters at 16 bytes (RMSProp, donated) -- see
+# benchmarks/configs/keye_moe_rl.json.
+keye_moe_rl = kimi_linear_rl.replace(
+    seq_model="keye_moe_4l",
+    token_task=(18992, 2048, 8192, 32, 128),
+    num_envs=16,
+    unroll_len=512,
+)
+# Episodes of 12-32 tokens under a top-k of 8: the selection prunes.
+keye_moe_tiny = keye_moe_rl.replace(
+    seq_model="keye_moe_tiny", token_task=(64, 12, 32, 1, 2),
+    num_envs=8, unroll_len=16,
+    total_env_steps=100_000, learning_rate=1e-3,
+)
+
 PRESETS: dict[str, Config] = {
     "cartpole_a3c": cartpole_a3c,
     "cartpole_a3c_cpu": cartpole_a3c_cpu,
@@ -456,6 +478,8 @@ PRESETS: dict[str, Config] = {
     "kimi_linear_tiny": kimi_linear_tiny,
     "lfm2_moe_rl": lfm2_moe_rl,
     "lfm2_moe_tiny": lfm2_moe_tiny,
+    "keye_moe_rl": keye_moe_rl,
+    "keye_moe_tiny": keye_moe_tiny,
 }
 
 

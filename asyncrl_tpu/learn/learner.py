@@ -31,6 +31,7 @@ from asyncrl_tpu.ops.normalize import (
     update_stats,
 )
 from asyncrl_tpu.models.networks import is_recurrent, reset_core
+from asyncrl_tpu.models.seq_common import MODEL_LOSS
 from asyncrl_tpu.obs import introspect, trace
 from asyncrl_tpu.obs import spans as span_names
 from asyncrl_tpu.ops.losses import (
@@ -434,7 +435,14 @@ def _algo_loss(
 
     def with_aux(loss_and_metrics):
         loss, metrics = loss_and_metrics
-        return loss, {**metrics, **aux}
+        # a loss term of the model's own (``models/seq_common.py
+        # MODEL_LOSS``): added to the algorithm's, coefficient 1, and kept
+        # out of the metrics
+        extra = dict(aux)
+        own = extra.pop(MODEL_LOSS, None)
+        if own is not None:
+            loss = loss + own
+        return loss, {**metrics, **extra}
 
     if config.algo == "qlearn":
         # ``logits`` ARE the online Q-values here (QNetwork head). The

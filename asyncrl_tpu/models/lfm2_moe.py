@@ -43,6 +43,7 @@ from asyncrl_tpu.models.seq_common import (
     _dot,
     _env_block,
     _episode_mask,
+    _gqa_project,
     _rms_norm,
     _short_conv,
     _softmax,
@@ -112,31 +113,6 @@ def _conv_mixer(p, x, state, done, dtype):
         b, c, xt = jnp.split(_dot(x, p["in"], dtype), 3, axis=-1)
         v, tail = _short_conv(p["conv"], state["conv"], b * xt, done)
         return _dot(c * v, p["out"], dtype), {"conv": tail}
-
-
-def _rotate(x, pos, theta: float):
-    """Rotary embedding on all of the last dim, rotate-half pairing
-    (``x1 = x[..., :d/2]``, ``x2 = x[..., d/2:]``): ``x`` [..., H, d] at
-    positions ``pos`` [...], float32."""
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
-    angle = pos.astype(F32)[..., None, None] * freqs
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _gqa_project(p, x, pos, shape: Lfm2Shape, dtype):
-    """Queries [..., H, dh] and the key and value rows [..., Hkv * dh] the
-    cache holds: projected, q and k normed over each head, then rotated at
-    ``pos`` [...]."""
-    H, G, dh = shape.heads, shape.kv_heads, shape.head_dim
-    q = _dot(x, p["q"], dtype).reshape(*x.shape[:-1], H, dh)
-    k = _dot(x, p["k"], dtype).reshape(*x.shape[:-1], G, dh)
-    q = _rotate(_rms_norm(q, p["q_norm"], shape.eps), pos, shape.rope_theta)
-    k = _rotate(_rms_norm(k, p["k_norm"], shape.eps), pos, shape.rope_theta)
-    return (q, k.reshape(*x.shape[:-1], G * dh).astype(dtype),
-            _dot(x, p["v"], dtype).astype(dtype))
 
 
 def _gqa_step(p, x, state, shape: Lfm2Shape, dtype):

@@ -334,18 +334,20 @@ def build_model(config, env_spec):
         jnp.bfloat16 if config.precision == "bf16_matmul" else jnp.float32
     )
     if config.seq_model:
-        from asyncrl_tpu.models import kimi_linear, lfm2_moe
+        from asyncrl_tpu.models import keye_moe, kimi_linear, lfm2_moe
 
         # each sequence policy keeps the shape records it builds
-        for shapes, policy in ((kimi_linear.SHAPES, kimi_linear.SeqPolicy),
-                               (lfm2_moe.SHAPES, lfm2_moe.Lfm2Policy)):
+        policies = ((kimi_linear.SHAPES, kimi_linear.SeqPolicy),
+                    (lfm2_moe.SHAPES, lfm2_moe.Lfm2Policy),
+                    (keye_moe.SHAPES, keye_moe.KeyePolicy))
+        for shapes, policy in policies:
             if config.seq_model in shapes:
                 shape = shapes[config.seq_model]
                 break
         else:
             raise ValueError(
                 f"unknown seq_model {config.seq_model!r}; have "
-                f"{sorted([*kimi_linear.SHAPES, *lfm2_moe.SHAPES])}"
+                f"{sorted(name for shapes, _ in policies for name in shapes)}"
             )
         if config.algo == "qlearn" or env_spec.num_actions != shape.vocab:
             raise ValueError(

@@ -1,10 +1,12 @@
 """What the token-level sequence policies share (``models/kimi_linear.py``,
-``models/lfm2_moe.py``): the carry and its reset-on-read protocol, the trunk
-(embedding, layers in blocks of whole envs, each rematerialised), the
-feed-forward of a layer (dense, or the routed experts this chip holds), the
-blocked output head, the value head and the fragment form's counters, and
-the pieces a mixer is made of (norms, the boundary-aware short conv, the
-episode mask and the cache a fragment leaves).
+``models/lfm2_moe.py``, ``models/keye_moe.py``): the carry and its
+reset-on-read protocol, the trunk (embedding, layers in blocks of whole envs,
+each rematerialised), the feed-forward of a layer (dense, or the routed
+experts this chip holds, scored by sigmoid or by softmax), the blocked
+output head, the value head, the fragment form's counters and the model's
+own loss term, and the pieces a mixer is made of (norms, the boundary-aware
+short conv, the rotation and the grouped-query projection, the episode mask
+and the cache a fragment leaves).
 
 A policy is ``SeqPolicyBase`` with a shape record of its own and three
 methods: ``initial_core``, ``init`` and ``_mixer``. One function in two
@@ -23,14 +25,23 @@ forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
   returns the logits instead (tests).
 
 The carry (``SeqCore``) is a tuple with one entry per layer, every leaf with
-the env axis first, and four kinds of state live in it side by side:
+the env axis first, and five kinds of state live in it side by side:
 
 - a KDA layer's ``{"S" [B, H, dk, dv] float32, "conv" [B, W-1, 3 H dk],
   "fresh" [B] bool}``;
 - a latent-attention layer's ``{"kv" [B, L, lora + rope], "len" [B]}``;
 - a gated short-conv layer's ``{"conv" [B, W-1, D] float32}``;
 - a grouped-query attention layer's ``{"k", "v" [B, L, Hkv * dh], "len"
-  [B]}`` (the keys rotated at their positions in the episode).
+  [B]}`` (the keys rotated at their positions in the episode);
+- a sparse-attention layer's ``{"k", "v" [B, L, Hkv * dh], "ki" [B, L, dI],
+  "len" [B]}``: the same cache and a third kind of row beside it, the
+  indexer's key of each position (``ops/dsa.py``). Every array of rows a
+  cache holds is emptied by the one ``len`` and re-gathered by the one
+  ``_cache_after``.
+
+A model's own loss term (the sparse-attention indexer's KL term, which is
+all that trains the indexer) leaves the fragment form in ``aux`` under
+``MODEL_LOSS``; ``learn/learner.py`` adds it to the algorithm's loss.
 
 A reset decides by what a layer's state holds: a conv tail is zeroed, a
 cache is emptied by its ``len`` (the rows stay), and a KDA state is not
@@ -52,6 +63,9 @@ from flax import struct
 from asyncrl_tpu.ops import moe
 
 F32 = jnp.float32
+# ``aux``'s key for a loss term of the model's own: the learner adds it to
+# the algorithm's loss and leaves it out of the metrics.
+MODEL_LOSS = "model_loss"
 
 
 @struct.dataclass
@@ -139,6 +153,32 @@ def _short_conv(weights, tail, x, done):
         y = y + weights[W - 1 - s] * ext[W - 1 - s: W - 1 - s + T] * valid[..., None]
     keep = jnp.cumprod(alive[T:][::-1], axis=0)[::-1]  # no boundary up to the end
     return y, jnp.moveaxis(ext[T:] * keep[..., None], 0, 1)
+
+
+def _rotate(x, pos, theta: float):
+    """Rotary embedding on all of the last dim, rotate-half pairing
+    (``x1 = x[..., :d/2]``, ``x2 = x[..., d/2:]``): ``x`` [..., H, d] at
+    positions ``pos`` [...], float32."""
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
+    angle = pos.astype(F32)[..., None, None] * freqs
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _gqa_project(p, x, pos, shape, dtype):
+    """Queries [..., H, dh] and the key and value rows [..., Hkv * dh] the
+    cache holds: projected, q and k normed over each head, then rotated at
+    ``pos`` [...]. ``shape``: ``heads``, ``kv_heads``, ``head_dim``, ``eps``
+    and ``rope_theta``."""
+    H, G, dh = shape.heads, shape.kv_heads, shape.head_dim
+    q = _dot(x, p["q"], dtype).reshape(*x.shape[:-1], H, dh)
+    k = _dot(x, p["k"], dtype).reshape(*x.shape[:-1], G, dh)
+    q = _rotate(_rms_norm(q, p["q_norm"], shape.eps), pos, shape.rope_theta)
+    k = _rotate(_rms_norm(k, p["k_norm"], shape.eps), pos, shape.rope_theta)
+    return (q, k.reshape(*x.shape[:-1], G * dh).astype(dtype),
+            _dot(x, p["v"], dtype).astype(dtype))
 
 
 def _softmax(scores, mask):
@@ -236,6 +276,8 @@ class SeqPolicyBase:
 
     # added to the sum the router's chosen scores are renormalised by
     ROUTE_EPS = 0.0
+    # how the router scores the experts (``ops/moe.py route``)
+    ROUTE_SCORE = "sigmoid"
 
     def apply(self, variables, *args, method: str | None = None):
         return getattr(self, method or "step")(variables["params"], *args)
@@ -253,8 +295,8 @@ class SeqPolicyBase:
             return _swiglu(p, x, dtype), {}
         with jax.named_scope("moe"):
             ids, weights = moe.route(
-                x, p["router"], p["router_bias"], s.top_k, s.routed_scale,
-                self.ROUTE_EPS,
+                x, p["router"], p.get("router_bias"), s.top_k, s.routed_scale,
+                self.ROUTE_EPS, self.ROUTE_SCORE,
             )
             y, load, dense = moe.held_experts(
                 x, ids, weights, s.held_experts, s.num_experts,
@@ -339,6 +381,16 @@ class SeqPolicyBase:
         attended = [c["rows_attended"] for c in counters if "rows_attended" in c]
         if attended:  # mean rows a query attended, over the attention layers
             aux["gqa_rows_attended"] = sum(attended) / (len(attended) * T * B)
+        sparse = [c for c in counters if "indexer_kl" in c]
+        if sparse:
+            # the selection's counters, means over queries and sparse layers
+            for name in ("dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"):
+                aux[name] = jax.lax.stop_gradient(
+                    sum(c[name] for c in sparse) / (len(sparse) * T * B))
+            # the indexers' loss: each layer's mean KL over the fragment's
+            # queries, the layers' added (all that trains an indexer)
+            aux[MODEL_LOSS] = sum(c["indexer_kl"] for c in sparse) / (T * B)
+            aux["indexer_kl"] = jax.lax.stop_gradient(aux[MODEL_LOSS])
         if actions is None:
             with jax.named_scope("lm_head"):
                 return _dot(h, params["head"], self.compute_dtype), values, core, aux

@@ -1,0 +1,242 @@
+"""Attention that chooses its rows: a learned indexer scores every row of a
+query's episode, the ``top_k`` rows of largest score are selected, and
+grouped-query attention runs over the selected rows only (the
+DeepSeek-Sparse-Attention mechanism). This module knows rows, ``len``, heads
+and ``top_k``, and nothing of the model that calls it.
+
+For a query ``t`` and a row ``s`` of its episode up to itself:
+
+- the index score ``I[t, s] = scale * sum_j w[t, j] * relu(qi[t, j] .
+  ki[s])`` over the indexer's ``J`` heads (``index_scores``; ``scale`` is
+  the caller's, ``dI ** -0.5 * J ** -0.5`` in the published indexer);
+- ``S_t``: the ``top_k`` rows of largest ``I[t, s]``, every row where the
+  episode holds no more (``select``: exact, ties to the earlier row, as
+  ``lax.top_k`` breaks them);
+- the heads' softmax over ``S_t`` alone, query head ``j`` reading key-value
+  head ``j // (H / G)`` of rows that hold a position's ``G`` key-value heads
+  side by side (the cache's layout, ``ops/gqa.py``);
+- the indexer's loss ``KL(P_t || softmax_{s in S_t} I[t, s])``, ``P_t`` the
+  heads' probabilities summed over the heads and normalised over ``S_t``,
+  under ``stop_gradient``: the selection passes no gradient, so this term is
+  all that trains the indexer, and it reaches nothing else.
+
+Two forms, one function:
+
+- ``dsa_step``: one token over the cache. The indexer scores the cache's
+  whole capacity (its key rows are 128 B a position), ``select`` gives the
+  chosen rows as a mask, and both products run over the cache's WHOLE
+  capacity under that mask, as ``ops/gqa.py``'s plain lines do under
+  ``len``: the key and value rows that were not selected are read too. That
+  is the faster of the two on the chip (PERF.md, PR 32; 16 envs, 8,192 rows
+  of 512 lanes, bfloat16, top 2,048): the cache streams at 700 GB/s (0.385
+  ms a call), while ``lax.top_k`` + a gather of the selected rows + the
+  products over 2,048 rows take 1.84 ms, 1.71 of them the gather (80 GB/s: a
+  row at a time), and ``gqa_step``'s kernel, which stops at ``len``, 1.06 ms
+  at 16 envs this long. A kernel that read the chosen rows only would move a
+  third of the bytes; none is written here. Where the cache's capacity is no
+  more than ``top_k`` every row is selected and ``gqa_step`` serves.
+- ``dsa_fragment``: a fragment's queries over ``[cache, fragment]`` rows, in
+  blocks of one env and ``query_block`` queries, each rematerialised in the
+  backward pass: every row's scores are computed and the softmax runs under
+  the selection's mask (a gather of 2,048 rows a QUERY would move more than
+  the products it saves). Returns the summed KL term and the selection's
+  counters beside the heads' outputs.
+
+``select`` finds the ``k``-th largest score by bisection on the scores' bits
+(32 passes of compare-and-count, then the ties by their index): a mask, no
+sort. On the chip it takes 0.036 ms for a decode step's [16, 8192] scores
+where ``lax.top_k`` takes 0.089, and 0.12 ms for a block's [128, 8704] where
+``lax.top_k`` takes 0.77 (PERF.md, PR 32).
+
+Precision: the products' operands in the rows' dtype, float32 accumulation;
+``relu``, the heads' weights, the scale, the selection, both softmaxes and
+the KL term in float32.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from asyncrl_tpu.ops import gqa
+
+F32 = jnp.float32
+
+
+def index_scores(qi, w, ki, scale: float):
+    """``qi`` [..., Q, J, dI] the indexer's queries, ``w`` [..., Q, J] their
+    heads' weights (float32), ``ki`` [..., P, dI] the rows' indexer keys ->
+    ``I`` [..., Q, P] float32."""
+    products = jnp.einsum(
+        "...qjd,...pd->...jqp", qi.astype(ki.dtype), ki,
+        preferred_element_type=F32,
+    )
+    # a pass of its own, as ``models/seq_common.py _rms_norm``'s sum: fused
+    # into the product's epilogue the sum over heads is tiled by what else
+    # the program holds, and a last bit of a score moves a row in or out
+    products = lax.optimization_barrier(jax.nn.relu(products))
+    weights = jnp.moveaxis(w.astype(F32), -1, -2)[..., None]  # [..., J, Q, 1]
+    return scale * jnp.sum(products * weights, axis=-3)
+
+
+def _ordered(x):
+    """float32 -> uint32 in the floats' order (-0 as +0)."""
+    x = jnp.where(x == 0, jnp.zeros_like(x), x)
+    bits = lax.bitcast_convert_type(x.astype(F32), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def select(scores, valid, k: int):
+    """The ``k`` largest of ``scores`` [..., P] among ``valid`` [..., P]
+    (all of them where there are no more than ``k``), ties to the lower
+    index: a mask [..., P]. Exact: the ``k``-th largest is found bit by bit
+    (the largest threshold that ``k`` rows still reach), then the ties at it
+    are admitted in index order."""
+    P = scores.shape[-1]
+    u = jnp.where(valid, _ordered(scores), jnp.uint32(0))
+    count = lambda hit: jnp.sum(hit, axis=-1, dtype=jnp.int32)
+    kk = jnp.minimum(count(valid), k)
+    zero = kk * 0  # varies over the mesh axes the scores vary over
+
+    def value_bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(u >= cand[..., None]) >= kk, cand, tau)
+
+    tau = lax.fori_loop(0, 32, value_bit, zero.astype(jnp.uint32))
+    above, ties = u > tau[..., None], valid & (u == tau[..., None])
+    need = kk - count(above)
+    idx = jnp.arange(P, dtype=jnp.int32)
+    bits = P.bit_length()
+
+    def index_bit(i, m):
+        cand = m | (jnp.int32(1) << (bits - 1 - i))
+        return jnp.where(count(ties & (idx < cand[..., None])) <= need, cand, m)
+
+    m = lax.fori_loop(0, bits, index_bit, zero)
+    return above | (ties & (idx < m[..., None]))
+
+
+# ------------------------------------------------------------- one token
+
+
+def dsa_step(q, keys, values, qi, w, ki, length, top_k: int, scale: float):
+    """One token. ``q`` [B, H, dh] float32, normed and rotated; ``keys``,
+    ``values`` [B, L, G * dh] and ``ki`` [B, L, dI], this token's rows
+    written; ``qi`` [B, J, dI], ``w`` [B, J]; ``length`` [B] int32, the index
+    of this token's row. Returns the heads' weighted values [B, H, dh]
+    float32."""
+    L = keys.shape[1]
+    if L <= top_k:  # every row of any episode is selected
+        return gqa.gqa_step(q, keys, values, length)
+    with jax.named_scope("dsa_index"):
+        scores = index_scores(qi[:, None], w[:, None], ki, scale)[:, 0]  # [B, L]
+    with jax.named_scope("dsa_select"):
+        chosen = select(scores, jnp.arange(L)[None, :] <= length[:, None], top_k)
+    with jax.named_scope("dsa_attend"):
+        return _attend_rows(q, keys, values, chosen)
+
+
+def _attend_rows(q, keys, values, mask):
+    """``ops/gqa.py _plain_step`` under a mask of rows [B, L] instead of a
+    length: both products over the whole rows of the whole cache, batched
+    over envs only, a query laid into its key-value head's lanes."""
+    B, H, dh = q.shape
+    G, dtype = keys.shape[-1] // dh, keys.dtype
+    scores = jnp.einsum(
+        "bhc,bpc->bhp", gqa._laid(q, G, dtype), keys, preferred_element_type=F32,
+    ) / math.sqrt(dh)
+    probs = _masked_softmax(scores, mask[:, None, :])
+    out = jnp.einsum(
+        "bhp,bpc->bhc", probs.astype(dtype), values, preferred_element_type=F32,
+    )
+    return jnp.sum(
+        out.reshape(B, H, G, dh) * gqa._own(H, G)[None, :, :, None], axis=2)
+
+
+# -------------------------------------------------------------- fragment
+
+
+def _masked_softmax(scores, mask):
+    scores = jnp.where(mask, scores, -jnp.inf)
+    scores = scores - lax.stop_gradient(jnp.max(scores, axis=-1, keepdims=True))
+    e = jnp.where(mask, jnp.exp(scores), 0.0)
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+def _block(q, qi, w, mask, keys, values, ki, top_k, scale, groups,
+           with_chosen=False):
+    """One env's ``tq`` queries over its ``P`` rows: ``q`` [tq, H, dh],
+    ``qi`` [tq, J, dI], ``w`` [tq, J], ``mask`` [tq, P]; ``keys``, ``values``
+    [P, G * dh], ``ki`` [P, dI] -> (out [tq, H, dh], [kl, rows scored, rows
+    selected, queries pruned] summed over the queries, and with
+    ``with_chosen`` the selection [tq, P])."""
+    tq, H, dh = q.shape
+    G, dtype = groups, keys.dtype
+    with jax.named_scope("dsa_index"):
+        scores = index_scores(qi, w, ki, scale)  # [tq, P]
+    with jax.named_scope("dsa_select"):
+        chosen = select(lax.stop_gradient(scores), mask, top_k)
+    with jax.named_scope("dsa_attend"):
+        keys, values = (a.reshape(-1, G, dh) for a in (keys, values))
+        attn = jnp.einsum(
+            "tgjd,pgd->gjtp", q.reshape(tq, G, H // G, dh).astype(dtype), keys,
+            preferred_element_type=F32,
+        ) / math.sqrt(dh)
+        probs = _masked_softmax(attn, chosen[None, None])
+        out = jnp.einsum(
+            "gjtp,pgd->tgjd", probs.astype(dtype), values,
+            preferred_element_type=F32,
+        ).reshape(tq, H, dh)
+    with jax.named_scope("dsa_index"):
+        # KL(P || softmax over the chosen rows of I): the heads' softmaxes
+        # each sum to 1 over the chosen rows, so their mean is P
+        target = lax.stop_gradient(jnp.mean(probs, axis=(0, 1)))  # [tq, P]
+        index = jnp.where(chosen, scores, -jnp.inf)
+        top = lax.stop_gradient(jnp.max(index, axis=-1, keepdims=True))
+        log_pi = scores - top - jnp.log(jnp.sum(
+            jnp.where(chosen, jnp.exp(index - top), 0.0), axis=-1, keepdims=True))
+        live = chosen & (target > 0)
+        kl = jnp.sum(jnp.where(
+            live, target * (jnp.log(jnp.where(live, target, 1.0)) - log_pi), 0.0))
+    scored = jnp.sum(mask, axis=-1)
+    counted = jnp.stack([
+        kl, jnp.sum(scored).astype(F32), jnp.sum(chosen).astype(F32),
+        jnp.sum(scored > top_k).astype(F32),
+    ])
+    return (out, counted, chosen) if with_chosen else (out, counted)
+
+
+def dsa_fragment(q, qi, w, mask, keys, values, ki, top_k: int, scale: float,
+                 query_block: int, with_chosen: bool = False):
+    """A fragment. ``q`` [B, T, H, dh] float32, normed and rotated; ``qi``
+    [B, T, J, dI], ``w`` [B, T, J]; ``mask`` [B, T, P]: the rows of a
+    query's own episode up to itself; ``keys``, ``values`` [B, P, G * dh],
+    ``ki`` [B, P, dI]: the cached rows and the fragment's own. Returns (the
+    heads' weighted values [B, T, H, dh] float32, {"indexer_kl",
+    "dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"}: sums over
+    the B * T queries; with ``with_chosen`` also "chosen" [B, T, P], the
+    rows each query attended)."""
+    B, T, H, dh = q.shape
+    G = keys.shape[-1] // dh
+    tq = math.gcd(T, query_block)
+
+    def env(args):
+        q, qi, w, mask, keys, values, ki = args
+        blocks = lambda a: a.reshape(T // tq, tq, *a.shape[1:])
+        out, counted, *chosen = lax.map(
+            jax.checkpoint(lambda xs: _block(
+                *xs, keys, values, ki, top_k, scale, G, with_chosen)),
+            tuple(blocks(a) for a in (q, qi, w, mask)),
+        )
+        return (out.reshape(T, H, dh), jnp.sum(counted, axis=0),
+                *(c.reshape(T, -1) for c in chosen))
+
+    out, counted, *chosen = lax.map(env, (q, qi, w, mask, keys, values, ki))
+    names = ("indexer_kl", "dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share")
+    counted = dict(zip(names, jnp.sum(counted, axis=0)))
+    if with_chosen:
+        counted["chosen"] = chosen[0]
+    return out, counted

@@ -1,7 +1,8 @@
 """A routed-expert layer that is told which experts it holds.
 
-The router scores all ``num_experts`` and chooses ``top_k`` of them per
-token, as the whole model does; this chip computes the part of the result
+The router scores all ``num_experts`` (by sigmoid with a correction bias, or
+by a softmax over all of them: the model's choice, ``route``'s ``score``) and
+chooses ``top_k`` of them per token, as the whole model does; this chip computes the part of the result
 that its own experts give and adds nothing for the rest (on one chip the
 layer runs without its exchange). No token is dropped and there is no
 capacity factor. A static shape that can never overflow is every held
@@ -56,18 +57,26 @@ _site_p = site_primitive("moe_site", introspect.count_moe_site)
 
 
 def route(x, router_kernel, router_bias, top_k: int, scale: float,
-          norm_eps: float = 0.0):
-    """``x`` [N, D] -> (expert ids [N, k], weights [N, k]), in float32:
-    sigmoid scores, the top k of score + correction bias (a buffer: no
-    gradient reaches it), weights renormalised over the chosen scores
-    (``norm_eps`` added to their sum where the model's own code does)."""
+          norm_eps: float = 0.0, score: str = "sigmoid"):
+    """``x`` [N, D] -> (expert ids [N, k], weights [N, k]), in float32.
+    ``score="sigmoid"``: sigmoid scores, the top k of score + correction
+    bias (a buffer: no gradient reaches it; ``None``: no bias).
+    ``score="softmax"``: a softmax over all the experts, the top k of it.
+    Either way the weights are the chosen scores renormalised over their
+    sum (``norm_eps`` added to it where the model's own code does)."""
     with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.matmul(
+        logits = jnp.matmul(
             x.astype(F32), router_kernel.astype(F32), precision=HIGHEST
-        ))
-        _, ids = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(router_bias.astype(F32)), top_k
         )
+        if score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        elif score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            raise ValueError(f"unknown router score {score!r}")
+        biased = scores if router_bias is None else (
+            scores + jax.lax.stop_gradient(router_bias.astype(F32)))
+        _, ids = jax.lax.top_k(biased, top_k)
         chosen = jnp.take_along_axis(scores, ids, axis=-1)
         total = jnp.sum(chosen, axis=-1, keepdims=True)
         if norm_eps:

@@ -1,0 +1,199 @@
+"""Attention that chooses its rows (ops/dsa.py): the exact selection against
+``lax.top_k`` with ties, the one-token form and the fragment form against
+plain lines written here (a top-k by ``lax.top_k``, a softmax over the chosen
+rows a query at a time), the KL term, where its gradient goes and where it
+does not, and the form that serves a cache no longer than ``top_k``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from asyncrl_tpu.obs import introspect
+from asyncrl_tpu.ops import dsa
+
+H, G, DH, J, DI = 4, 2, 8, 3, 4
+SCALE = DI ** -0.5 * J ** -0.5
+
+
+def top_k_mask(scores, valid, k):
+    """The plain lines: ``lax.top_k`` (descending, ties to the lower index)
+    of the valid rows, as a mask. -0.0 and +0.0 are one score (``lax.top_k``
+    sorts by the total order, in which they are two)."""
+    masked = jnp.where(valid, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+    _, rows = lax.top_k(masked, min(k, scores.shape[-1]))
+    hit = jnp.zeros(scores.shape, bool)
+    hit = jnp.put_along_axis(hit, rows, True, axis=-1, inplace=False)
+    return hit & valid
+
+
+def scores_case(case, shape, key):
+    x = jax.random.normal(key, shape)
+    if case == "ties":  # a few distinct values: most rows tie with others
+        return jnp.round(x)
+    if case == "all_equal":
+        return jnp.zeros(shape)
+    if case == "signed_zeros":  # -0.0 and +0.0 are one score
+        return jnp.where(x > 0, 0.0, -0.0) * jnp.abs(x)
+    if case == "huge":  # the whole float32 range, infinities too
+        return jnp.where(x > 1, jnp.inf, jnp.where(x < -1, -jnp.inf, x * 1e38))
+    return x
+
+
+@pytest.mark.parametrize("k", [1, 5, 16, 40])
+@pytest.mark.parametrize("case", ["random", "ties", "all_equal", "signed_zeros", "huge"])
+def test_select_is_an_exact_top_k_with_ties_to_the_lower_index(case, k):
+    key = jax.random.PRNGKey(k)
+    scores = scores_case(case, (6, 33), key)
+    # rows 0..n of each query are valid: 1, 2, 5, 16, 17 and all 33
+    valid = jnp.arange(33)[None, :] < jnp.asarray([1, 2, 5, 16, 17, 33])[:, None]
+    chosen = jax.jit(dsa.select, static_argnums=2)(scores, valid, k)
+    np.testing.assert_array_equal(chosen, top_k_mask(scores, valid, k))
+    np.testing.assert_array_equal(
+        jnp.sum(chosen, axis=-1), jnp.minimum(jnp.sum(valid, axis=-1), k))
+
+
+def operands(B, L, key, dtype=jnp.float32):
+    ks = jax.random.split(key, 6)
+    normal = lambda k, *shape: jax.random.normal(k, shape)
+    return dict(
+        keys=normal(ks[0], B, L, G * DH).astype(dtype),
+        values=normal(ks[1], B, L, G * DH).astype(dtype),
+        ki=normal(ks[2], B, L, DI).astype(dtype),
+    )
+
+
+def plain_attend(q, qi, w, keys, values, ki, valid, k):
+    """One env's queries [Q, ...] over its rows [P, ...]: the definition."""
+    products = jnp.einsum("qjd,pd->qjp", qi, ki.astype(jnp.float32))
+    index = SCALE * jnp.einsum("qj,qjp->qp", w, jax.nn.relu(products))
+    chosen = top_k_mask(index, valid, k)
+    keys, values = (a.astype(jnp.float32).reshape(-1, G, DH) for a in (keys, values))
+    keys, values = (jnp.repeat(a, H // G, axis=1) for a in (keys, values))
+    attn = jnp.einsum("qhd,phd->hqp", q, keys) / np.sqrt(DH)
+    probs = jax.nn.softmax(jnp.where(chosen[None], attn, -jnp.inf), axis=-1)
+    out = jnp.einsum("hqp,phd->qhd", probs, values)
+    target = jnp.mean(probs, axis=0)
+    log_pi = jax.nn.log_softmax(jnp.where(chosen, index, -jnp.inf), axis=-1)
+    kl = jnp.sum(jnp.where(
+        chosen & (target > 0),
+        target * (jnp.log(jnp.where(target > 0, target, 1.0)) - log_pi), 0.0))
+    return out, kl, chosen
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_one_token_form_attends_the_top_k_rows_up_to_len(dtype):
+    B, L, k = 5, 24, 6
+    rows = operands(B, L, jax.random.PRNGKey(0), dtype)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (B, H, DH))
+    qi = jax.random.normal(ks[1], (B, J, DI))
+    w = jax.random.normal(ks[2], (B, J))
+    length = jnp.asarray([0, 3, 5, 6, 23])  # fewer rows than k, k, more
+    out = jax.jit(dsa.dsa_step, static_argnums=(7, 8))(
+        q, rows["keys"], rows["values"], qi, w, rows["ki"], length, k, SCALE)
+    for b in range(B):
+        valid = (jnp.arange(L) <= length[b])[None]
+        ref, _, chosen = plain_attend(
+            q[b][None], qi[b][None].astype(dtype).astype(jnp.float32), w[b][None],
+            rows["keys"][b], rows["values"][b], rows["ki"][b], valid, k)
+        assert int(jnp.sum(chosen)) == min(int(length[b]) + 1, k)
+        np.testing.assert_allclose(
+            out[b], ref[0], atol=2e-5 if dtype == jnp.float32 else 3e-2)
+
+
+def test_a_cache_no_longer_than_top_k_is_served_by_gqa_step():
+    B, L = 3, 8
+    rows = operands(B, L, jax.random.PRNGKey(2))
+    q = jax.random.normal(jax.random.PRNGKey(3), (B, H, DH))
+    qi, w = jnp.zeros((B, J, DI)), jnp.zeros((B, J))
+    length = jnp.asarray([0, 4, 7])
+    before = introspect.process_record()["gqa_sites"]
+    out = dsa.dsa_step(q, rows["keys"], rows["values"], qi, w, rows["ki"], length,
+                       L, SCALE)
+    after = introspect.process_record()["gqa_sites"]
+    assert after["step"] == before["step"] + 1
+    for b in range(B):
+        ref, _, _ = plain_attend(
+            q[b][None], qi[b][None], w[b][None], rows["keys"][b], rows["values"][b],
+            rows["ki"][b], (jnp.arange(L) <= length[b])[None], L)
+        np.testing.assert_allclose(out[b], ref[0], atol=2e-5)
+
+
+def fragment_case():
+    B, T, P, k = 2, 8, 20, 5
+    rows = operands(B, P, jax.random.PRNGKey(4))
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, T, H, DH))
+    qi = jax.random.normal(ks[1], (B, T, J, DI))
+    w = jax.random.normal(ks[2], (B, T, J))
+    # env 0: 12 cached rows then its queries, causal; env 1: a boundary after
+    # its third query, so its later queries see the fragment's rows only
+    t = jnp.arange(T)
+    cached = jnp.stack([jnp.arange(12) < 12, jnp.arange(12) < 7])  # [B, 12]
+    episode = jnp.stack([jnp.zeros(T, int), (t >= 3).astype(int)])
+    own = (t[None, :, None] >= t[None, None, :]) & (
+        episode[:, :, None] == episode[:, None, :])
+    mask = jnp.concatenate(
+        [cached[:, None, :] & (episode == 0)[:, :, None], own], axis=-1)
+    return (q, qi, w, mask, rows["keys"], rows["values"], rows["ki"]), k
+
+
+def test_the_fragment_form_is_the_definition_a_query_at_a_time():
+    args, k = fragment_case()
+    out, counted = jax.jit(
+        lambda *a: dsa.dsa_fragment(*a, k, SCALE, 4, True))(*args)
+    q, qi, w, mask, keys, values, ki = args
+    total = 0.0
+    for b in range(q.shape[0]):
+        ref, kl, chosen = plain_attend(
+            q[b], qi[b], w[b], keys[b], values[b], ki[b], mask[b], k)
+        np.testing.assert_allclose(out[b], ref, atol=2e-5)
+        np.testing.assert_array_equal(counted["chosen"][b], chosen)
+        total += kl
+    assert float(counted["indexer_kl"]) == pytest.approx(float(total), rel=1e-5)
+    scored = jnp.sum(mask, axis=-1)
+    assert float(counted["dsa_rows_scored"]) == float(jnp.sum(scored))
+    assert float(counted["dsa_rows_selected"]) == float(
+        jnp.sum(jnp.minimum(scored, k)))
+    assert float(counted["dsa_pruned_share"]) == float(jnp.sum(scored > k))
+    assert 0 < float(counted["dsa_pruned_share"]) < scored.size
+
+
+def test_the_kl_term_trains_the_indexer_and_nothing_else():
+    """The heads' outputs pass no gradient to the indexer's operands (the
+    selection is a constant); the KL term passes none to the heads' (its
+    target is one)."""
+    args, k = fragment_case()
+
+    def both(*a):
+        out, counted = dsa.dsa_fragment(*a, k, SCALE, 4)
+        return jnp.sum(jnp.square(out)), counted["indexer_kl"]
+
+    heads = jax.grad(lambda *a: both(*a)[0], argnums=(0, 1, 2, 4, 5, 6))(*args)
+    index = jax.grad(lambda *a: both(*a)[1], argnums=(0, 1, 2, 4, 5, 6))(*args)
+    norm = lambda g: float(jnp.linalg.norm(g.astype(jnp.float32)))
+    q, qi, w, keys, values, ki = heads
+    assert min(norm(q), norm(keys), norm(values)) > 0
+    assert max(norm(qi), norm(w), norm(ki)) == 0
+    q, qi, w, keys, values, ki = index
+    assert max(norm(q), norm(keys), norm(values)) == 0
+    assert min(norm(qi), norm(w), norm(ki)) > 0
+
+    # and the gradient is the definition's
+    def plain_kl(qi, w, ki):
+        q, _, _, mask, keys, values, _ = args
+        return sum(plain_attend(q[b], qi[b], w[b], keys[b], values[b], ki[b],
+                                mask[b], k)[1] for b in range(q.shape[0]))
+
+    def program_kl(qi, w, ki):
+        q, _, _, mask, keys, values, _ = args
+        return dsa.dsa_fragment(q, qi, w, mask, keys, values, ki, k, SCALE, 4)[1][
+            "indexer_kl"]
+
+    _, qi, w, _, _, _, ki = args
+    mine = jax.grad(program_kl, argnums=(0, 1, 2))(qi, w, ki)
+    ref = jax.grad(plain_kl, argnums=(0, 1, 2))(qi, w, ki)
+    for a, b in zip(mine, ref):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
