@@ -216,6 +216,7 @@ class _ProcessRecord:
         self._moe_sites = dict.fromkeys(  # guarded-by: _lock
             ("grouped", "gathered", "dense"), 0)
         self._gqa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
+        self._dsa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -263,6 +264,10 @@ class _ProcessRecord:
         with self._lock:
             self._gqa_sites[form] += 1
 
+    def count_dsa_site(self, form: str) -> None:
+        with self._lock:
+            self._dsa_sites[form] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -273,6 +278,7 @@ class _ProcessRecord:
                 "kda_sites": dict(self._kda_sites),
                 "moe_sites": dict(self._moe_sites),
                 "gqa_sites": dict(self._gqa_sites),
+                "dsa_sites": dict(self._dsa_sites),
             }
 
 
@@ -299,7 +305,8 @@ def process_record() -> dict[str, Any]:
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
     "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
-    n}, "gqa_sites": {"step": n, "step_kernel": n}}``: copies,
+    n}, "gqa_sites": {"step": n, "step_kernel": n}, "dsa_sites": {"step": n,
+    "step_kernel": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -344,6 +351,16 @@ def count_gqa_site(form: str) -> None:
     ``ops/gqa.py``, once per site and trace (where the platform chose, once
     per site and program lowered), nothing on a steady call."""
     _RECORD.count_gqa_site(form)
+
+
+def count_dsa_site(form: str) -> None:
+    """One one-token sparse-attention site of a program (a cache deeper than
+    ``top_k``) attended its chosen rows by ``ops/gqa.py``'s kernel under the
+    selection's mask, reading the cache up to ``len`` (``"step_kernel"``),
+    or by the plain products over its whole capacity (``"step"``): called by
+    ``ops/dsa.py``, once per site and trace (where the platform chose, once
+    per site and program lowered), nothing on a steady call."""
+    _RECORD.count_dsa_site(form)
 
 
 def _sig(obj: Any) -> Any:

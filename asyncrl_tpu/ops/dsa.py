@@ -23,18 +23,28 @@ For a query ``t`` and a row ``s`` of its episode up to itself:
 Two forms, one function:
 
 - ``dsa_step``: one token over the cache. The indexer scores the cache's
-  whole capacity (its key rows are 128 B a position), ``select`` gives the
-  chosen rows as a mask, and both products run over the cache's WHOLE
-  capacity under that mask, as ``ops/gqa.py``'s plain lines do under
-  ``len``: the key and value rows that were not selected are read too. That
-  is the faster of the two on the chip (PERF.md, PR 32; 16 envs, 8,192 rows
-  of 512 lanes, bfloat16, top 2,048): the cache streams at 700 GB/s (0.385
-  ms a call), while ``lax.top_k`` + a gather of the selected rows + the
-  products over 2,048 rows take 1.84 ms, 1.71 of them the gather (80 GB/s: a
-  row at a time), and ``gqa_step``'s kernel, which stops at ``len``, 1.06 ms
-  at 16 envs this long. A kernel that read the chosen rows only would move a
-  third of the bytes; none is written here. Where the cache's capacity is no
-  more than ``top_k`` every row is selected and ``gqa_step`` serves.
+  whole capacity (its key rows are 128 B a position) and ``select`` gives
+  the chosen rows as a mask. Where it can (a TPU, rows of whole lane tiles:
+  ``gqa._kernel_fits``), the heads attend by ``ops/gqa.py``'s kernel under
+  that mask: the cache stays in HBM and an env's rows are copied up to
+  ``len`` only, so the rows beyond never leave HBM, and of the rows copied
+  those that were not chosen are masked out of the softmax. Elsewhere
+  ``_attend_rows``: both products over the cache's WHOLE capacity under the
+  mask, as ``ops/gqa.py``'s plain lines under ``len``. On the chip (PERF.md,
+  PR 33 and PR 32; 16 envs, 8,192 rows of 512 lanes, bfloat16, top 2,048,
+  the caches passed to each timed call as they lie; ms a call): the masked
+  products over everything 0.382 (the cache streams at ~700 GB/s), the
+  kernel 0.237 at lengths uniform in 100-8,191 and 0.130 at
+  the lengths the traffic has (mean ~2,500 rows); ``lax.top_k`` + a gather
+  of the selected rows + the products over 2,048 rows 1.84, 1.71 of them
+  the gather (80 GB/s: a row at a time). (PR 32 read the kernel at 1.06:
+  its timing loop handed the call a fresh copy of the cache every time.) A
+  kernel that copied the chosen rows only would move a third of the bytes
+  again, a row of 1 KB at a time; none is written here. ``process_record()
+  ["dsa_sites"]`` counts ``"step_kernel"`` and ``"step"`` (the masked
+  products), once per site and program lowered. The kernel's VJP is the
+  masked products'. Where the cache's capacity is no more than ``top_k``
+  every row is selected and ``gqa_step`` serves.
 - ``dsa_fragment``: a fragment's queries over ``[cache, fragment]`` rows, in
   blocks of one env and ``query_block`` queries, each rematerialised in the
   backward pass: every row's scores are computed and the softmax runs under
@@ -50,7 +60,9 @@ where ``lax.top_k`` takes 0.089, and 0.12 ms for a block's [128, 8704] where
 
 Precision: the products' operands in the rows' dtype, float32 accumulation;
 ``relu``, the heads' weights, the scale, the selection, both softmaxes and
-the KL term in float32.
+the KL term in float32; the probabilities cast to the rows' dtype for the
+second product (the kernel casts them before they are normalised, the plain
+lines after, as in ``ops/gqa.py``).
 """
 
 from __future__ import annotations
@@ -61,7 +73,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops import gqa
+from asyncrl_tpu.ops.site import site_primitive
 
 F32 = jnp.float32
 
@@ -136,7 +150,42 @@ def dsa_step(q, keys, values, qi, w, ki, length, top_k: int, scale: float):
     with jax.named_scope("dsa_select"):
         chosen = select(scores, jnp.arange(L)[None, :] <= length[:, None], top_k)
     with jax.named_scope("dsa_attend"):
-        return _attend_rows(q, keys, values, chosen)
+        if not gqa._kernel_fits(q.shape, keys.shape, keys.dtype, masked=True):
+            introspect.count_dsa_site("step")
+            return _attend_rows(q, keys, values, chosen)
+        return lax.platform_dependent(
+            q, keys, values, length, chosen,
+            tpu=lambda q, *xs: _kernel_attend(
+                _site_p.bind(q, path="step_kernel"), *xs),
+            default=lambda q, keys, values, _, chosen: _attend_rows(
+                _site_p.bind(q, path="step"), keys, values, chosen),
+        )
+
+
+# Which form a site whose shape fits ended on is known where it is lowered.
+_site_p = site_primitive("dsa_site", introspect.count_dsa_site)
+
+
+@jax.custom_vjp
+def _kernel_attend(q, keys, values, length, chosen):
+    """``ops/gqa.py``'s kernel under the mask of chosen rows, with the plain
+    lines' VJP (the learner differentiates the fragment form; only its
+    bootstrap token comes by here)."""
+    return gqa._kernel_step(q, keys, values, length, chosen)
+
+
+def _kernel_attend_fwd(q, keys, values, length, chosen):
+    out = gqa._kernel_step(q, keys, values, length, chosen)
+    return out, (q, keys, values, chosen)
+
+
+def _kernel_attend_bwd(xs, cotangent):
+    *operands, chosen = xs
+    _, vjp = jax.vjp(lambda *o: _attend_rows(*o, chosen), *operands)
+    return (*vjp(cotangent), None, None)
+
+
+_kernel_attend.defvjp(_kernel_attend_fwd, _kernel_attend_bwd)
 
 
 def _attend_rows(q, keys, values, mask):

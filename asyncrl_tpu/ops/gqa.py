@@ -24,6 +24,16 @@ at a time would pass 64 lanes of it eight times. Rows of a last chunk beyond
 ``length[b]`` are masked before the softmax and zeroed before the second
 product, so what they hold never reaches the output.
 
+The kernel takes one optional operand more, a mask of chosen rows [B, L]
+(``ops/dsa.py``'s selection; whole in VMEM, float32, a chunk's rows a
+sublane): a row then counts if it is at or before ``length[b]`` AND chosen.
+A chosen-out row up to ``length[b]`` holds finite data and its probability
+is exactly 0, so only the scores are masked; a chunk in which no row counts
+while the running maximum is still -inf leaves the running sums as they were
+(the maximum is guarded, not the chunk skipped). Whether the mask is there
+is read from the call: without it the kernel is, op for op, the kernel it
+was.
+
 Which path a call takes is read from what can be observed, as in
 ``ops/kda.py``: the static shape when the call is traced (``_kernel_fits``),
 the platform when the program is lowered (``lax.platform_dependent``), and
@@ -65,7 +75,10 @@ _VMEM_HEADROOM = 16 * 1024 * 1024
 # the call only as far as it believes the call lasts. In capacities of the
 # cache, with the rollout's ms an update in the cell (PERF.md, PR 31): none
 # given, 36 prefetches a token where the plain lines' step has 88; 1/4:
-# 496.7; 1/2: 488.6; 1, the call's bound: 479.5; 2: 472.0; 4: 472.0.
+# 496.7; 1/2: 488.6; 1, the call's bound: 479.5; 2: 472.0; 4: 472.0. Under
+# a mask in Keye's cell, whose calls read a third of a capacity before 0.6
+# GB of expert weights a step (PERF.md, PR 33): 1/4: 1,000.9; 1/2: 1,007.7;
+# 1: 1,018.3; 2: 988.3; 4: 1,062.7. One value serves both.
 _COST_CAPACITIES = 2
 
 
@@ -101,12 +114,14 @@ def _plain_step(q, keys, values, length):
     return jnp.sum(out.reshape(B, H, G, dh) * _own(H, G)[None, :, :, None], axis=2)
 
 
-def _step_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems):
+def _step_kernel(len_ref, q_ref, k_hbm, v_hbm, *refs):
     """All envs: len_ref [B] (SMEM), q_ref (B, H, W) the laid queries, k_hbm,
-    v_hbm (B, L, W) left in HBM -> o_ref (B, H, dh). k_buf, v_buf (slots,
-    chunk, W) take the copies; ``sems`` (2, slots). The loops over envs and
-    over an env's chunks are loops; the copies run ahead by a pointer of
-    their own, which crosses envs."""
+    v_hbm (B, L, W) left in HBM, and where the call passed one the mask of
+    chosen rows, chosen_ref (B, L / chunk, chunk) float32 -> o_ref (B, H,
+    dh). k_buf, v_buf (slots, chunk, W) take the copies; ``sems`` (2,
+    slots). The loops over envs and over an env's chunks are loops; the
+    copies run ahead by a pointer of their own, which crosses envs."""
+    *chosen_ref, o_ref, k_buf, v_buf, sems = refs
     B, H, W = q_ref.shape
     slots, chunk = k_buf.shape[:2]
     dh = o_ref.shape[-1]
@@ -145,6 +160,8 @@ def _step_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems):
         scores = lax.dot_general(
             q_ref[b], k_buf[slot], (((1,), (1,)), ((), ())),
             preferred_element_type=F32) / math.sqrt(dh)  # [H, chunk]
+        if chosen_ref:  # a row that was not chosen holds finite data: no zeroing
+            scores = jnp.where(chosen_ref[0][b, pl.ds(c, 1)] > 0, scores, -jnp.inf)
         v = v_buf[slot]
         if masked:  # an env's last chunk: rows beyond its length
             first = c * chunk
@@ -153,8 +170,13 @@ def _step_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems):
             row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
             v = jnp.where(first + row <= len_ref[b], v, jnp.zeros_like(v))
         m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        shrink = jnp.exp(m - m_new)
-        e = jnp.exp(scores - m_new)
+        top = m_new
+        if chosen_ref:
+            # a chunk with no chosen row, and none before it: the maximum is
+            # still -inf, and the chunk leaves m, l and acc as they were
+            top = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        shrink = jnp.exp(m - top)
+        e = jnp.exp(scores - top)
         l = shrink * l + jnp.sum(e, axis=1, keepdims=True)
         acc = shrink * acc + jnp.dot(
             e.astype(dtype), v, preferred_element_type=F32)
@@ -181,20 +203,23 @@ def _step_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems):
     lax.fori_loop(0, B, env, (*pointer, jnp.int32(0)))
 
 
-def _vmem(q_shape, rows_shape, dtype) -> int:
+def _vmem(q_shape, rows_shape, dtype, masked=False) -> int:
     """The call's VMEM: the laid queries and the result whole (a result's
-    ``dh`` lanes padded to a tile), the chunks in flight."""
-    (B, H, dh), W = q_shape, rows_shape[-1]
+    ``dh`` lanes padded to a tile), the chunks in flight, and with a mask of
+    chosen rows that too, whole (float32, a chunk's rows a sublane)."""
+    (B, H, dh), (_, L, W) = q_shape, rows_shape
     size = jnp.dtype(dtype).itemsize
     return (B * H * (W * size + -(-dh // _LANE) * _LANE * 4)
-            + 2 * _SLOTS * CHUNK * W * size)
+            + 2 * _SLOTS * CHUNK * W * size
+            + masked * B * -(-L // (8 * CHUNK)) * 8 * CHUNK * 4)
 
 
-def _kernel_fits(q_shape, rows_shape, dtype) -> bool:
+def _kernel_fits(q_shape, rows_shape, dtype, masked=False) -> bool:
     """What the kernel asks of the static shapes (the platform is asked when
     the program is lowered): rows of whole lane tiles in bfloat16 or float32,
-    a capacity of whole chunks, the heads in whole groups, the queries and
-    the result inside the VMEM budget."""
+    a capacity of whole chunks, the heads in whole groups, the queries, the
+    result and the mask of chosen rows, if there is one, inside the VMEM
+    budget."""
     if len(q_shape) != 3 or len(rows_shape) != 3:
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
@@ -203,26 +228,32 @@ def _kernel_fits(q_shape, rows_shape, dtype) -> bool:
     return (min(B, H, dh, L, W) > 0 and rows_shape[0] == B
             and W % _LANE == 0 and W % dh == 0 and H % (W // dh) == 0
             and L % CHUNK == 0
-            and _vmem(q_shape, rows_shape, dtype) <= _VMEM_BLOCK_BUDGET)
+            and _vmem(q_shape, rows_shape, dtype, masked) <= _VMEM_BLOCK_BUDGET)
 
 
-def _kernel_step(q, keys, values, length, interpret=False):
+def _kernel_step(q, keys, values, length, chosen=None, interpret=False):
     """One ``pallas_call`` and no grid: the laid queries (4 MB at the
     published widths) and the result whole in VMEM, the cache in HBM
     (``pl.ANY``: no operand of its size is copied or laid out again).
+    ``chosen`` [B, L], where given, is the mask of the rows that count (of
+    those up to ``length``): one more operand, whole in VMEM, and the
+    kernel's one more predicate; without it the call is what it was.
     Outputs declare the inputs' varying mesh axes, as in ``ops/kda.py``."""
     B, H, dh = q.shape
     L, W = keys.shape[1:]
     dtype = keys.dtype
+    mask = () if chosen is None else (
+        chosen.astype(F32).reshape(B, L // CHUNK, CHUNK),)
     vma = frozenset().union(
-        *(jax.typeof(x).vma for x in (q, keys, values, length)))
+        *(jax.typeof(x).vma for x in (q, keys, values, length, *mask)))
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     rows = _COST_CAPACITIES * L
     return pl.pallas_call(
         _step_kernel,
         name="gqa_step",
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), whole, hbm, hbm],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), whole, hbm, hbm,
+                  *(whole for _ in mask)],
         out_specs=whole,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), F32, vma=vma),
         scratch_shapes=[
@@ -231,13 +262,14 @@ def _kernel_step(q, keys, values, length, interpret=False):
             pltpu.SemaphoreType.DMA((2, _SLOTS)),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem(q.shape, keys.shape, dtype) + _VMEM_HEADROOM,
+            vmem_limit_bytes=(
+                _vmem(q.shape, keys.shape, dtype, bool(mask)) + _VMEM_HEADROOM),
         ),
         cost_estimate=pl.CostEstimate(
             flops=4 * B * H * W * rows, transcendentals=B * H * rows,
             bytes_accessed=2 * B * rows * W * jnp.dtype(dtype).itemsize),
         interpret=interpret,
-    )(length.astype(jnp.int32), _laid(q, W // dh, dtype), keys, values)
+    )(length.astype(jnp.int32), _laid(q, W // dh, dtype), keys, values, *mask)
 
 
 # Which form a site whose shape fits ended on is known where it is lowered.

@@ -2,7 +2,14 @@
 ``lax.top_k`` with ties, the one-token form and the fragment form against
 plain lines written here (a top-k by ``lax.top_k``, a softmax over the chosen
 rows a query at a time), the KL term, where its gradient goes and where it
-does not, and the form that serves a cache no longer than ``top_k``."""
+does not, the form that serves a cache no longer than ``top_k``, and the
+forms that attend the chosen rows of a deeper one: ``ops/gqa.py``'s kernel
+under the selection's mask (in the Pallas interpreter, the platform's choice
+forced) or the masked products, its VJP, and the counter of the choice."""
+
+import contextlib
+import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +18,7 @@ import pytest
 from jax import lax
 
 from asyncrl_tpu.obs import introspect
-from asyncrl_tpu.ops import dsa
+from asyncrl_tpu.ops import dsa, gqa
 
 H, G, DH, J, DI = 4, 2, 8, 3, 4
 SCALE = DI ** -0.5 * J ** -0.5
@@ -69,9 +76,11 @@ def plain_attend(q, qi, w, keys, values, ki, valid, k):
     products = jnp.einsum("qjd,pd->qjp", qi, ki.astype(jnp.float32))
     index = SCALE * jnp.einsum("qj,qjp->qp", w, jax.nn.relu(products))
     chosen = top_k_mask(index, valid, k)
-    keys, values = (a.astype(jnp.float32).reshape(-1, G, DH) for a in (keys, values))
-    keys, values = (jnp.repeat(a, H // G, axis=1) for a in (keys, values))
-    attn = jnp.einsum("qhd,phd->hqp", q, keys) / np.sqrt(DH)
+    heads, dh = q.shape[-2:]
+    keys, values = (a.astype(jnp.float32).reshape(a.shape[0], -1, dh)
+                    for a in (keys, values))
+    keys, values = (jnp.repeat(a, heads // a.shape[1], axis=1) for a in (keys, values))
+    attn = jnp.einsum("qhd,phd->hqp", q, keys) / np.sqrt(dh)
     probs = jax.nn.softmax(jnp.where(chosen[None], attn, -jnp.inf), axis=-1)
     out = jnp.einsum("hqp,phd->qhd", probs, values)
     target = jnp.mean(probs, axis=0)
@@ -119,6 +128,171 @@ def test_a_cache_no_longer_than_top_k_is_served_by_gqa_step():
             q[b][None], qi[b][None], w[b][None], rows["keys"][b], rows["values"][b],
             rows["ki"][b], (jnp.arange(L) <= length[b])[None], L)
         np.testing.assert_allclose(out[b], ref[0], atol=2e-5)
+
+
+# ---- a cache deeper than top_k at a shape ``ops/gqa.py``'s kernel takes
+
+KC = gqa.CHUNK
+KL, KH, KG, KDH, KTOP = 4 * KC, 8, 2, 64, KC + 44  # rows of 128 lanes
+
+
+def kernel_case(dtype=jnp.float32, lengths=(0, 17, KTOP - 2, KTOP - 1, KTOP, 2 * KC, KL - 1)):
+    """Lengths below, at and above ``top_k`` (``len`` + 1 rows are scored)."""
+    B = len(lengths)
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    normal = lambda k, *shape: jax.random.normal(k, shape)
+    return dict(
+        q=normal(ks[0], B, KH, KDH), keys=normal(ks[1], B, KL, KG * KDH).astype(dtype),
+        values=normal(ks[2], B, KL, KG * KDH).astype(dtype), qi=normal(ks[3], B, J, DI),
+        w=normal(ks[4], B, J), ki=normal(ks[5], B, KL, DI).astype(dtype),
+        length=jnp.asarray(lengths, jnp.int32))
+
+
+def step_of(case):
+    return dsa.dsa_step(*(case[n] for n in ("q", "keys", "values", "qi", "w", "ki", "length")),
+                        KTOP, SCALE)
+
+
+@contextlib.contextmanager
+def on_a_tpu():
+    """What a program lowered for a TPU takes of ``lax.platform_dependent``,
+    with the kernel in the Pallas interpreter."""
+    with mock.patch.object(lax, "platform_dependent",
+                           lambda *args, tpu, default: tpu(*args)), \
+            mock.patch.object(gqa, "_kernel_step",
+                              functools.partial(gqa._kernel_step, interpret=True)):
+        yield
+
+
+def dsa_sites_since(before):
+    now = introspect.process_record()["dsa_sites"]
+    return {k: now[k] - before[k] for k in now}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_one_token_form_by_the_kernel_is_the_definition(dtype):
+    """``dsa_step`` where the kernel serves: index and ``select`` over the
+    capacity, then the kernel under the selection's mask, against the
+    definition a query at a time; and the masked products give the same."""
+    case = kernel_case(dtype)
+    assert gqa._kernel_fits(case["q"].shape, case["keys"].shape, dtype, masked=True)
+    before = introspect.process_record()["dsa_sites"]
+    with on_a_tpu():
+        out = jax.jit(step_of)(case)
+    assert dsa_sites_since(before) == {"step_kernel": 1, "step": 0}
+    plain = jax.jit(lambda case: step_of(case))(case)  # on the CPU: the masked products
+    assert dsa_sites_since(before) == {"step_kernel": 1, "step": 1}
+    assert bool(jnp.all(jnp.isfinite(out)))
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(out, plain, atol=tol)
+    for b, n in enumerate(case["length"]):
+        ref, _, chosen = plain_attend(
+            case["q"][b][None], case["qi"][b][None].astype(dtype).astype(jnp.float32),
+            case["w"][b][None], case["keys"][b], case["values"][b], case["ki"][b],
+            (jnp.arange(KL) <= n)[None], KTOP)
+        assert int(jnp.sum(chosen)) == min(int(n) + 1, KTOP)
+        np.testing.assert_allclose(out[b], ref[0], atol=tol)
+
+
+def test_the_kernels_vjp_under_the_mask_is_the_masked_products():
+    """What a differentiated call on a TPU runs (the learner's bootstrap
+    token): the kernel forward, the plain lines' backward; neither the
+    selection nor ``len`` takes a cotangent, and no row that was not chosen
+    a gradient."""
+    case = kernel_case()
+    length = case["length"]
+    chosen = dsa.select(
+        dsa.index_scores(case["qi"][:, None], case["w"][:, None], case["ki"], SCALE)[:, 0],
+        jnp.arange(KL)[None, :] <= length[:, None], KTOP)
+    mix = jax.random.normal(jax.random.PRNGKey(7), case["q"].shape)
+    operands = (case["q"], case["keys"], case["values"])
+    with on_a_tpu():
+        value, mine = jax.value_and_grad(
+            lambda *o: jnp.sum(dsa._kernel_attend(*o, length, chosen) * mix),
+            argnums=range(3))(*operands)
+    ref_value, ref = jax.value_and_grad(
+        lambda *o: jnp.sum(dsa._attend_rows(*o, chosen) * mix), argnums=range(3))(*operands)
+    np.testing.assert_allclose(value, ref_value, rtol=1e-5)
+    for a, b in zip(mine, ref):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+    assert float(jnp.max(jnp.abs(jnp.where(chosen[..., None], 0.0, mine[1])))) == 0.0
+    assert float(jnp.max(jnp.abs(mine[1]))) > 0.0
+    # and through dsa_step, as the model calls it
+    loss = lambda q, keys, values: jnp.sum(step_of(
+        {**case, "q": q, "keys": keys, "values": values}) * mix)
+    with on_a_tpu():
+        through = jax.grad(loss, argnums=range(3))(*operands)
+    for a, b in zip(through, ref):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+
+
+@pytest.mark.parametrize("fits, differentiated", [
+    (False, False), (False, True), (True, False), (True, True)])
+def test_off_the_tpu_and_at_small_shapes_the_chosen_rows_are_attended_by_the_masked_products(
+        fits, differentiated):
+    """The other branch: by shape when the call is traced (rows of 16
+    lanes), by platform when it is lowered (a shape the kernel takes, here
+    on a CPU), differentiated or not: ``_attend_rows``' values and
+    gradients, counted as ``"step"`` once per site and program lowered."""
+    if fits:
+        case = kernel_case()
+    else:
+        B, L, k = 5, 24, 6
+        rows = operands(B, L, jax.random.PRNGKey(0))
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        case = dict(rows, q=jax.random.normal(ks[0], (B, H, DH)),
+                    qi=jax.random.normal(ks[1], (B, J, DI)),
+                    w=jax.random.normal(ks[2], (B, J)),
+                    length=jnp.asarray([0, 3, 5, 6, 23]))
+    k = KTOP if fits else 6
+    assert gqa._kernel_fits(
+        case["q"].shape, case["keys"].shape, jnp.float32, masked=True) == fits
+    L = case["keys"].shape[1]
+
+    def by(attend):
+        def f(q, keys, values):
+            chosen = dsa.select(
+                dsa.index_scores(case["qi"][:, None], case["w"][:, None], case["ki"],
+                                 SCALE)[:, 0],
+                jnp.arange(L)[None, :] <= case["length"][:, None], k)
+            return attend(q, keys, values, chosen)
+        return f
+
+    module = lambda q, keys, values: dsa.dsa_step(
+        q, keys, values, case["qi"], case["w"], case["ki"], case["length"], k, SCALE)
+    wrap = (lambda f: jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=range(3))) \
+        if differentiated else (lambda f: lambda *a: (f(*a),))
+    args = (case["q"], case["keys"], case["values"])
+    before = introspect.process_record()["dsa_sites"]
+    step = jax.jit(wrap(module))
+    mine = step(*args)
+    assert dsa_sites_since(before) == {"step": 1, "step_kernel": 0}
+    step(*args)  # a steady call counts nothing
+    assert dsa_sites_since(before) == {"step": 1, "step_kernel": 0}
+    for a, b in zip(mine, jax.jit(wrap(by(dsa._attend_rows)))(*args)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+
+
+def test_dsa_sites_count_every_site_of_a_program():
+    """Two sites of one shape in one program are two (the site's lowering is
+    not cached), a second program of the same function counts again, and a
+    program lowered for a TPU counts the kernel."""
+    case = kernel_case(lengths=(0, KTOP + 5))
+
+    def two_sites(q):
+        return step_of({**case, "q": step_of({**case, "q": q})})
+
+    before = introspect.process_record()["dsa_sites"]
+    jax.jit(two_sites)(case["q"])
+    assert dsa_sites_since(before) == {"step": 2, "step_kernel": 0}
+    jax.jit(lambda q: two_sites(q))(case["q"])
+    assert dsa_sites_since(before) == {"step": 4, "step_kernel": 0}
+    before_gqa = introspect.process_record()["gqa_sites"]
+    with on_a_tpu():
+        jax.jit(lambda q: two_sites(q) + 0)(case["q"])
+    assert dsa_sites_since(before) == {"step": 4, "step_kernel": 2}
+    # the sites are dsa's own: ``gqa_sites`` counts calls of ``gqa_step``
+    assert introspect.process_record()["gqa_sites"] == before_gqa
 
 
 def fragment_case():

@@ -1,6 +1,7 @@
 """The one-token grouped-query attention (``ops/gqa.py``): the kernel that
 reads the cache up to ``len``, in the Pallas interpreter, against the plain
-lines over the whole capacity; what reaches the output of a row beyond
+lines over the whole capacity, and under a mask of chosen rows against
+``ops/dsa.py``'s masked products; what reaches the output of a row beyond
 ``len``; the VJP; the choice between the two forms and its counter."""
 
 import functools
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 from asyncrl_tpu.obs import introspect
-from asyncrl_tpu.ops import gqa
+from asyncrl_tpu.ops import dsa, gqa
 
 C = gqa.CHUNK
 L = 4 * C  # a capacity of several chunks
@@ -61,19 +62,87 @@ def test_the_kernel_is_the_plain_lines_up_to_len(edge, dtype, groups):
     close(mine, gqa._plain_step(q, keys, values, length), dtype)
 
 
+def rows_up_to(length):
+    return jnp.arange(L)[None, :] <= jnp.asarray(length)[:, None]
+
+
+def some_rows(length, seed=7):
+    """A third of each env's rows up to ``len``, the current row among them."""
+    length = jnp.asarray(length)
+    drawn = jax.random.uniform(jax.random.PRNGKey(seed), (len(length), L)) < 1 / 3
+    return (drawn & rows_up_to(length)).at[jnp.arange(len(length)), length].set(True)
+
+
+def without(chosen, env, rows):
+    return chosen.at[env, rows].set(False)
+
+
+# name -> (lengths, the mask of chosen rows from them)
+CHOSEN = {
+    "a_first_chunk_with_no_chosen_row": (
+        (C, 2 * C + 5, L - 1),
+        lambda n: without(some_rows(n), slice(None), slice(0, C))),
+    "a_middle_chunk_with_none": (
+        (2 * C, 3 * C + 9, L - 1),
+        lambda n: without(some_rows(n), slice(None), slice(C, 2 * C))),
+    "a_last_chunk_whose_only_chosen_row_is_the_current_one": (
+        (C, 2 * C + 5, L - 1),
+        lambda n: (some_rows(n) & (jnp.arange(L)[None] < (jnp.asarray(n)[:, None] // C) * C)
+                   ).at[jnp.arange(len(n)), jnp.asarray(n)].set(True)),
+    "the_current_row_not_chosen": (
+        (1, C, 2 * C + 5, L - 1),
+        lambda n: some_rows(n).at[jnp.arange(len(n)), jnp.asarray(n)].set(False)
+        .at[:, 0].set(True)),
+    "every_row_chosen": (EDGES["mixed"], rows_up_to),
+    "len_on_a_chunks_last_row": ((C - 1, 3 * C - 1, L - 1), some_rows),
+    "len_on_a_chunks_first_row": ((C, 2 * C, 3 * C), some_rows),
+    "len_0": ((0, 0), some_rows),
+    "mixed": (EDGES["mixed"], some_rows),
+}
+
+
+@pytest.mark.parametrize("groups", [2, 8])  # rows of 128 and of 512 lanes
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", CHOSEN)
+def test_under_a_mask_the_kernel_is_the_masked_products_over_the_capacity(
+        case, dtype, groups):
+    """A row counts if it is at or before ``len`` and chosen. A chunk with no
+    chosen row (the first of a long episode can be one, while the running
+    maximum is still -inf) leaves the running sums as they were."""
+    lengths, choose = CHOSEN[case]
+    assert gqa._kernel_fits(
+        (len(lengths), H, DH), (len(lengths), L, groups * DH), dtype, masked=True)
+    q, keys, values, length = operands(lengths, groups, dtype)
+    chosen = choose(lengths)
+    assert bool(jnp.all(jnp.any(chosen, axis=-1)))
+    mine = gqa._kernel_step(q, keys, values, length, chosen, interpret=True)
+    assert mine.shape == (len(length), H, DH) and mine.dtype == jnp.float32
+    assert bool(jnp.all(jnp.isfinite(mine)))
+    close(mine, dsa._attend_rows(q, keys, values, chosen), dtype)
+    # rows chosen beyond len do not count: the mask is taken up to len
+    np.testing.assert_array_equal(mine, gqa._kernel_step(
+        q, keys, values, length, chosen | ~rows_up_to(length), interpret=True))
+    if case == "every_row_chosen":  # the kernel without a mask, to the bit
+        np.testing.assert_array_equal(
+            mine, gqa._kernel_step(q, keys, values, length, interpret=True))
+
+
+@pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("beyond", [jnp.nan, jnp.inf, 3e38])
-def test_a_row_beyond_len_never_reaches_the_output(beyond, dtype):
+def test_a_row_beyond_len_never_reaches_the_output(beyond, dtype, masked):
     """Neither read into the softmax nor multiplied by zero: NaN, infinity
     or the largest finite values beyond ``len`` (in a last chunk, which is
     copied, and in the chunks after it, which are not) leave the result
-    what zeros there leave it."""
+    what zeros there leave it, with a mask of chosen rows (which chooses
+    rows beyond ``len`` too) as without."""
     lengths = EDGES["mixed"]
     q, keys, values, length = operands(lengths, 2, dtype, beyond)
-    mine = gqa._kernel_step(q, keys, values, length, interpret=True)
+    chosen = (some_rows(lengths) | ~rows_up_to(lengths),) if masked else ()
+    mine = gqa._kernel_step(q, keys, values, length, *chosen, interpret=True)
     assert bool(jnp.all(jnp.isfinite(mine)))
     _, keys0, values0, _ = operands(lengths, 2, dtype, 0.0)
-    clean = gqa._kernel_step(q, keys0, values0, length, interpret=True)
+    clean = gqa._kernel_step(q, keys0, values0, length, *chosen, interpret=True)
     np.testing.assert_array_equal(mine, clean)
     # the plain lines multiply such a row by zero, and NaN is what they give
     if np.isnan(beyond):
@@ -118,6 +187,20 @@ def test_the_kernels_vjp_is_the_plain_lines():
 ])
 def test_the_shapes_the_kernel_takes(q_shape, rows_shape, dtype, fits):
     assert gqa._kernel_fits(q_shape, rows_shape, dtype) == fits
+
+
+@pytest.mark.parametrize("q_shape, rows_shape, fits, masked_fits", [
+    ((16, 32, 128), (16, 8192, 512), True, True),  # keye_moe_rl's: a mask of 512 KB
+    ((8, 4, 16), (8, 32, 32), False, False),  # keye_moe_tiny's
+    ((1024, 32, 64), (1024, 8192, 512), True, False),  # the mask takes it over VMEM
+])
+def test_the_mask_of_chosen_rows_counts_in_the_kernels_vmem(
+        q_shape, rows_shape, fits, masked_fits):
+    assert gqa._kernel_fits(q_shape, rows_shape, jnp.bfloat16) == fits
+    assert gqa._kernel_fits(q_shape, rows_shape, jnp.bfloat16, masked=True) == masked_fits
+    B, L_ = rows_shape[:2]
+    assert (gqa._vmem(q_shape, rows_shape, jnp.bfloat16, masked=True)
+            - gqa._vmem(q_shape, rows_shape, jnp.bfloat16)) >= B * L_ * 4
 
 
 def gqa_sites_since(before):

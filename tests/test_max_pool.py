@@ -281,6 +281,21 @@ def test_the_chunked_kda_compiles_its_sub_chunk_pairs_to_kernels_under_its_scope
     assert not re.findall(r"f32\[[\d,]*16,16,128\]", text)
 
 
+def assert_the_cache_is_left_in_place(text, cache):
+    """In a compiled decode loop nothing passes over an array of the
+    ``cache``'s shape (a regex) but the row's write, in place, and the
+    kernel, and the fusions that give one back are the scatters of one
+    row."""
+    passes = re.findall(rf"= {cache}\S* (\S+?)\(", text)
+    assert "fusion" in passes or "scatter" in passes  # the write
+    assert set(passes) <= {"parameter", "get-tuple-element", "bitcast",
+                           "fusion", "scatter"}, passes
+    for body in re.findall(
+            rf"\n(%fused_computation\S*) \([^\n]*\) -> {cache} \{{(.*?)\n\}}", text,
+            flags=re.S):
+        assert "scatter(" in body[1], body[0]
+
+
 def test_the_one_token_attention_compiles_to_one_kernel_that_leaves_the_cache_in_place(
         one_chip):
     """``lfm2_moe_rl``'s attention widths and cache, one layer, a scan of
@@ -325,16 +340,57 @@ def test_the_one_token_attention_compiles_to_one_kernel_that_leaves_the_cache_in
     (call,) = calls
     assert re.search(r'op_name="[^"]*/gqa/gqa_step/[^"]*pallas_call', call), call
     assert re.search(r"%gqa_step\S* = \S+ custom-call\(", text)  # the kernel's name
-    # what passes over the cache: the row's write, in place, and the kernel
-    cache = rf"bf16\[{B},2048,512\]"
-    passes = re.findall(rf"= {cache}\S* (\S+?)\(", text)
-    assert "fusion" in passes or "scatter" in passes  # the write
-    assert set(passes) <= {"parameter", "get-tuple-element", "bitcast",
-                           "fusion", "scatter"}, passes
-    # and the fusions that give a cache back are the scatters of one row
-    for body in re.findall(
-            rf"\n(%fused_computation\S*) \([^\n]*\) -> {cache} \{{(.*?)\n\}}", text,
-            flags=re.S):
-        assert "scatter(" in body[1], body[0]
+    assert_the_cache_is_left_in_place(text, rf"bf16\[{B},2048,512\]")
     # rows beyond len stay in HBM: no product over the capacity is left
     assert not re.findall(rf"f32\[{B},32,2048\]", text)
+
+
+def test_the_one_token_sparse_attention_compiles_to_the_kernel_under_the_selections_mask(
+        one_chip):
+    """``keye_moe_rl``'s attention and indexer widths and cache, one layer, a
+    scan of one-token steps as the rollout runs them: index and ``select``
+    over the capacity, then ``ops/gqa.py``'s Mosaic kernel under
+    ``/gqa/dsa_attend/`` (``dsa_attend_device_ms`` and ``keye_gqa_device_ms``
+    read that path), counted by ``dsa_sites``; no product over the cache's
+    8,192-row capacity is left, and nothing in the loop's body copies a
+    ``[16, 8192, 512]`` array or lays it out again."""
+    from asyncrl_tpu.models import keye_moe
+
+    shape = keye_moe.KeyeShape(
+        hidden=256, vocab=512, layers=("dsa+moe",),
+        heads=32, kv_heads=4, head_dim=128, rope_theta=1e7,
+        index_heads=16, index_dim=64, index_top_k=2048,
+        expert_ffn=32, num_experts=8, held_experts=(0, 1),
+        top_k=2, routed_scale=1.0, max_positions=8192,
+    )
+    model = keye_moe.KeyePolicy(shape, compute_dtype=jnp.bfloat16)
+    B, T = 16, 4
+    variables, core = jax.eval_shape(
+        lambda: (model.init(jax.random.key(0)), model.initial_core(B)))
+    variables, tokens, core = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables, jax.ShapeDtypeStruct((T, B), jnp.int32), core))
+
+    def rollout(variables, tokens, core):
+        def step(core, token):
+            logits, value, core = model.apply(variables, token, core)
+            return core, (jnp.argmax(logits, axis=-1), value)
+        return jax.lax.scan(step, core, tokens)
+
+    def sites(name):
+        return introspect.process_record()[name]
+
+    before = {name: sites(name) for name in ("dsa_sites", "gqa_sites")}
+    text = jax.jit(rollout, donate_argnums=2).lower(
+        variables, tokens, core).compile().as_text()
+    assert {k: v - before["dsa_sites"][k] for k, v in sites("dsa_sites").items()} == {
+        "step": 0, "step_kernel": 1}
+    assert sites("gqa_sites") == before["gqa_sites"]  # ``gqa_step``'s own calls
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 1
+    (call,) = calls
+    assert re.search(r'op_name="[^"]*/gqa/dsa_attend/[^"]*pallas_call', call), call
+    assert re.search(r"%gqa_step\S* = \S+ custom-call\(", text)  # the kernel's name
+    assert_the_cache_is_left_in_place(text, rf"bf16\[{B},8192,512\]")
+    # rows beyond len stay in HBM: the heads' scores over the capacity are gone
+    assert not re.findall(rf"f32\[{B},32,8192\]", text)
