@@ -5,8 +5,13 @@ fragment form) are one device program, ``Trainer.learner.update``.
 
 The timed window, the in-flight call, the sync discipline and the
 ``bench.*`` annotations are ``loops/anakin_seq.py``'s (the step runs donated,
-so the host waits on the loss of the call before). Two things are this
+so the host waits on the loss of the call before). Three things are this
 loop's own.
+
+THE DRAW. Where the mix states an ``episode_seed``, the env batch's states
+and key chains are that seed's (``loops/common.py with_episode_seed``) and
+not ``--seed``'s, which goes on seeding the parameters: the episode lengths,
+and with them the rows the rollout's attention reads, are the mix's.
 
 THE WARM-IN. Set-up advances every env by ``warm_in_fragments`` fragments of
 the program's own ``unroll`` before the first update, parameters untouched,
@@ -271,6 +276,15 @@ def run(*, cell, config_doc, traffic_doc, make_config, seed, seconds, trace,
         }
         held0 = jax.device_get(leaves_of(state.params))
 
+        # ---- the traffic's draw: a mix that states an ``episode_seed`` has
+        # its env batch start from that seed's states and key chains, so the
+        # cell meets the same episode lengths at the same steps whatever
+        # ``--seed`` the parameters have (the kernels' time follows them)
+        episode_seed = traffic_doc.get("episode_seed")
+        if episode_seed is not None:
+            agent.state = state = state.replace(actor=common.with_episode_seed(
+                state.actor, agent.env, n_dev, int(episode_seed)))
+
         # ---- set-up: the warm-in, on the actor state the update will start
         # from; every step's token and flag is kept for the reference
         roll = unroll_program(agent, cfg)
@@ -399,11 +413,19 @@ def run(*, cell, config_doc, traffic_doc, make_config, seed, seconds, trace,
               f"{got['moe_local_assignments']!r}, dense blocks "
               f"{got['moe_dense_blocks']!r}", file=sys.stderr)
 
-        def hold(what, value, limit, limit_f32=F32_TOL):
+        compared: dict[str, list] = {}  # short name -> [reading, limit]
+
+        def hold(name, what, value, limit, limit_f32=F32_TOL):
             limit = limit_f32 if f32 else limit
+            compared[name] = [value, limit]
             if not value <= limit:
                 reasons.append(f"{what}: {value!r} (limit {limit})")
 
+        compared.update({
+            "replay_gap": [replay_gap, 1e-6],
+            "boundaries_gap": [abs(resets - boundaries), 0],
+            "len_differs": [len_differs, 0],
+        })
         if not (replay_gap <= 1e-6 and resets == boundaries and not len_differs):
             reasons.append(
                 f"the first update did not train on the replayed fragment, "
@@ -416,52 +438,67 @@ def run(*, cell, config_doc, traffic_doc, make_config, seed, seconds, trace,
         else:
             for when, gaps in row_gaps.items():
                 for i, (gap, limit) in enumerate(zip(gaps, ROWS_TOL)):
-                    hold(f"layer {i}'s key, value and indexer-key rows {when} "
+                    hold(f"rows_{when}_l{i}",
+                         f"layer {i}'s key, value and indexer-key rows {when} "
                          f"the fragment, of their norm from the reference's",
                          gap, limit)
             for i, s in enumerate(selection):
-                hold(f"layer {i}'s selection: a row chosen by one side only, "
+                hold(f"select_gap_l{i}",
+                     f"layer {i}'s selection: a row chosen by one side only, "
                      f"from the reference's k-th score, in spreads", s["gap"],
                      SELECT_GAP_TOL[i], SELECT_GAP_TOL_F32)
-                hold(f"layer {i}'s selection: rows a query chose that the "
+                hold(f"select_extra_l{i}",
+                     f"layer {i}'s selection: rows a query chose that the "
                      f"reference did not", s["extra_max"], SELECT_EXTRA_TOL[i],
                      SELECT_EXTRA_TOL_F32)
-                hold(f"layer {i}'s selection: queries whose set is not the "
+                hold(f"select_size_l{i}",
+                     f"layer {i}'s selection: queries whose set is not the "
                      f"reference's size", s["size_differs"], 0, 0)
-            hold("behaviour_logp vs the reference's log-prob of the same "
+            hold("logp_mean",
+                 "behaviour_logp vs the reference's log-prob of the same "
                  "actions, mean gap in nats", logp_gap["mean"], LOGP_MEAN_TOL)
-            hold("behaviour_logp vs the reference's log-prob of the same "
+            hold("logp_rms",
+                 "behaviour_logp vs the reference's log-prob of the same "
                  "actions, rms gap in nats", logp_gap["rms"], LOGP_RMS_TOL)
-            hold("the learner's mean log-prob vs the reference's (the update's "
+            hold("kl",
+                 "the learner's mean log-prob vs the reference's (the update's "
                  "kl against the reference's), nats",
                  abs(got["kl"] - ref["kl"]), KL_TOL)
-            hold("the update's value loss vs the reference's, relative",
+            hold("value_loss",
+                 "the update's value loss vs the reference's, relative",
                  relative("value_loss"), VALUE_LOSS_TOL)
-            hold("the update's entropy vs the reference's, relative",
+            hold("entropy", "the update's entropy vs the reference's, relative",
                  relative("entropy"), ENTROPY_TOL)
-            hold("the update's indexer_kl vs the reference's, relative",
+            hold("indexer_kl",
+                 "the update's indexer_kl vs the reference's, relative",
                  relative("indexer_kl"), INDEXER_KL_TOL)
             for k in ("dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"):
                 if not other:  # counts of the traffic: exact but for float32 sums
-                    hold(f"the update's {k} vs the reference's, relative",
+                    hold(k, f"the update's {k} vs the reference's, relative",
                          relative(k), 1e-5, 1e-5)
             for k in GROUPS:
-                hold(f"the gradient of {k!r} as the optimizer's second moment "
+                hold(f"grad_{k}",
+                     f"the gradient of {k!r} as the optimizer's second moment "
                      f"keeps it vs the reference's, clipped, relative",
                      grad_gaps[k], GRAD_TOL[k], GRAD_TOL_F32)
-                hold(f"the update's step on {k!r} vs the reference's gradient "
+                hold(f"step_{k}",
+                     f"the update's step on {k!r} vs the reference's gradient "
                      f"stepped by the optimizer's rule, relative",
                      step_gaps[k], STEP_TOL, STEP_TOL_F32)
             if f32:
-                hold("the update's policy-gradient term vs the reference's, "
+                hold("pg_loss",
+                     "the update's policy-gradient term vs the reference's, "
                      "of max(1, |term|)", pg_gap, None)
-                hold("the update's loss vs the reference's, of max(1, |loss|)",
+                hold("loss",
+                     "the update's loss vs the reference's, of max(1, |loss|)",
                      loss_gap, None)
         # every leaf: a gradient reached it (the policy has no buffer), and
         # it moved where its step is one float32 can take
         still = {k for k in sums0 if np.array_equal(sums1[k], sums0[k])}
         unreached = sorted(k for k in sums0 if not taken[k][0] > 0)
         stuck = sorted(k for k in still if taken[k][1] > 0)
+        compared.update({"leaves_unreached": [len(unreached), 0],
+                         "leaves_stuck": [len(stuck), 0]})
         if unreached or stuck:
             reasons.append(
                 f"after the first update: no gradient reached {unreached}; "
@@ -516,10 +553,21 @@ def run(*, cell, config_doc, traffic_doc, make_config, seed, seconds, trace,
             reasons.append("a loss or gradient norm is not finite")
         if not all(h[2] for h in leaf_hashes(state.params).values()):
             reasons.append("params are not finite")
+        by_update = lambda key, scale=1: [
+            float(np.mean(m[key])) * scale for m in drained]
+        print(f"benchmarks: by update, from the warm-up call: episode "
+              f"boundaries {[round(x) for x in by_update('episode_resets', n_dev)]}"
+              f" (the mix's draw: episode_seed {episode_seed}), dsa_rows_scored "
+              f"{by_update('dsa_rows_scored')}, loss {by_update('loss')} (the "
+              f"parameters': --seed {seed})", file=sys.stderr)
         counted = counters.read(t_start, t_end)
         in_window = counted["compiles_in_window"]
         if in_window:
             reasons.append(f"{in_window} compilation(s) inside the window")
+        compared.update({
+            "updates_not_executed": [abs(calls * K - (executed - step0)), 0],
+            "compiles_in_window": [in_window, 0],
+        })
 
         fps = timed_calls * frames_per_call / elapsed
         timed = drained[1:] or drained
@@ -566,6 +614,7 @@ def run(*, cell, config_doc, traffic_doc, make_config, seed, seconds, trace,
         return {
             "correct": not reasons,
             "reasons": reasons,
+            "compared": compared,
             "attempted": timed_calls * K,
             "failed": 0,
             "end_to_end": {

@@ -47,6 +47,9 @@ class Counters:
                 device.cache_entries(self.cache_dir) - self.cache0,
             "compiles_in_window": self.compiles.between(t0, t1),
             "memory_peak_bytes": device.memory_peak_bytes(),
+            # every program asked of the backend since before ``make_agent``:
+            # the program's own record keeps its newest 16,384 events only
+            "backend_compile_stamps": list(self.compiles.stamps),
         }
 
 
@@ -89,6 +92,45 @@ def annotate(name: str, on: bool):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+def drawn_actor(env, num_envs: int, n_dev: int, episode_seed):
+    """The actor state ``learner.init_state(episode_seed)`` builds over
+    ``n_dev`` devices, without a policy's carry: the env batch's states,
+    their observations and the key chains every later episode is drawn from
+    (``envs/token_task.py`` draws a length when an episode starts, from the
+    env's own key, never from an action or a parameter). Traceable in the
+    seed, so ``benchmarks/episode_draw.py`` maps it over candidates."""
+    import jax
+
+    from asyncrl_tpu.learn.learner import derive_init_keys
+    from asyncrl_tpu.rollout.anakin import actor_init
+
+    _, akey = derive_init_keys(jax.random.PRNGKey(episode_seed))
+    per_device = jax.vmap(
+        lambda key: actor_init(env, num_envs // n_dev, key, model=None)
+    )(jax.random.split(akey, n_dev))
+    return jax.tree.map(
+        lambda a: a.reshape(num_envs, *a.shape[2:]), per_device)
+
+
+def with_episode_seed(actor, env, n_dev: int, episode_seed: int):
+    """``actor`` with its env states, observations and key chains replaced
+    by ``drawn_actor``'s, placed as the old leaves were. The policy's carry
+    (gigabytes of empty caches in Keye's cell) stays the one ``make_agent``
+    built. A mix that states an ``episode_seed`` meets the same episode
+    lengths at the same steps whatever ``--seed`` the parameters have."""
+    import jax
+
+    drawn = jax.jit(
+        lambda: drawn_actor(env, actor.obs.shape[0], n_dev, episode_seed))()
+    placed = lambda new, old: jax.tree.map(
+        lambda a, b: jax.device_put(a, b.sharding), new, old)
+    return actor.replace(
+        env_state=placed(drawn.env_state, actor.env_state),
+        obs=placed(drawn.obs, actor.obs),
+        keys=placed(drawn.keys, actor.keys),
+    )
 
 
 def param_delta(a, b) -> float:

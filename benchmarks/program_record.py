@@ -9,7 +9,9 @@ many agents (a test worker has), so a reader takes the LAST phase of its
 name that ended before the measured window opened. A program without the
 record (a parent commit), a phase that never ran, or a phase whose events
 the record's cap has already dropped, gives ``None``, and the harness
-leaves the metric out.
+leaves the metric out. The COUNT of a phase's programs does not depend on
+the record's events: the harness logs the backend's events itself, on the
+same clock, and ``programs_in_phase`` counts those inside the phase.
 
 A metric is ``layer_metrics/<name>.json`` for its ``params`` and a one-line
 ``layer_metrics/<name>.py`` that imports its reader from here as ``read``.
@@ -60,9 +62,17 @@ def phase_seconds(ev, phase: str):
 
 def programs_in_phase(ev, phase: str):
     """Programs asked of the backend inside a phase: each one a compile, or
-    the load from the persistent cache that stood in for it."""
-    found = _events_in(ev, phase, (BACKEND,))
-    return None if found is None else len(found[0])
+    the load from the persistent cache that stood in for it. The phase is
+    the record's; the events are counted from the harness's own log of them
+    (``loops/common.py Counters``, armed before ``make_agent`` and never
+    cut), since a long set-up pushes the phase's events out of the newest
+    16,384 the record keeps."""
+    found = _find(ev, phase)
+    stamps = ev.get("counters", {}).get("backend_compile_stamps")
+    if found is None or stamps is None:
+        return None
+    _, t0, t1 = found
+    return sum(1 for t in stamps if t0 <= t <= t1)
 
 
 def compile_seconds_in_phase(ev, phase: str, events: list[str]):

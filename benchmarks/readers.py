@@ -34,19 +34,21 @@ def scope_device_ms(ev, scope: str):
     return _per_update(ev, ps, 1e9) if ps else None
 
 
-def mosaic_device_us(ev):
-    """Device time per call of the Mosaic (compiled Pallas) custom call."""
+def mosaic_device_us(ev, kernel: str):
+    """Device time per call of one Mosaic (compiled Pallas) kernel on chip
+    0: the custom calls whose op carries the kernel's ``name=``. A step has
+    other kernels' calls by the thousand; they are not this one's."""
     trace = ev.get("trace")
-    calls = trace.devices[0].mosaic_calls() if trace else []
+    calls = trace.devices[0].mosaic_calls(kernel) if trace else []
     if not calls:
         return None
     return sum(e.duration_ps for e in calls) / len(calls) / 1e6
 
 
-def fused_vtrace_roofline(ev):
+def fused_vtrace_roofline(ev, kernel: str):
     """Least time the chip could take for the kernel's bytes (it is bound
     by bytes: ~10 flop per 32 bytes) over the time it took, in percent."""
-    per_call_us = mosaic_device_us(ev)
+    per_call_us = mosaic_device_us(ev, kernel)
     if per_call_us is None:
         return None
     g = ev["geometry"]

@@ -11,7 +11,9 @@ cell in ``BENCHMARK.json``; its configuration in ``configs/<name>.json``
 where it needs one). Nothing here branches on what identifies a cell.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics and a breakdown of the traced window.
+``--trace 1`` its per-layer metrics and a breakdown of the traced window;
+last in it, where the cell's loop gives them, the readings ``correct`` was
+decided by, each beside its limit (``compared``).
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import functools
 import importlib
 import importlib.util
 import json
+import math
 import os
 import sys
 import time
@@ -189,6 +192,18 @@ def main(argv: list[str] | None = None) -> int:
         }
     for reason in result.get("reasons", []):
         print(f"benchmarks: not correct: {reason}", file=sys.stderr)
+    # what a loop compared, each reading beside its limit: the last lines on
+    # stderr, and the line's last key (a loop that gives none adds none); a
+    # reading that is not finite goes as text, so the line stays JSON
+    compared = {
+        name: [x if math.isfinite(x) else repr(x) for x in pair]
+        for name, pair in result.get("compared", {}).items()
+    }
+    for name, (value, limit) in compared.items():
+        print(f"benchmarks: compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr)
+    if compared:
+        line["compared"] = compared
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
     return 0

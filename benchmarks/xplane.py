@@ -347,8 +347,14 @@ class DeviceTrace:
         scope path has ``scope`` as a component."""
         return sum(t for ev, t in self.op_self_times if in_scope(ev, scope))
 
-    def mosaic_calls(self) -> list[Event]:
-        return [ev for ev, _ in self.op_self_times if MOSAIC_TARGET in ev.name]
+    def mosaic_calls(self, kernel: str = "") -> list[Event]:
+        """The Mosaic custom calls; with ``kernel``, those of the Pallas
+        kernel of that ``name=`` alone: XLA names the call's op by it
+        (``%gqa_step.38``, ``%jvp_jit_fused_vtrace_pallas__.16``)."""
+        return [
+            ev for ev, _ in self.op_self_times
+            if MOSAIC_TARGET in ev.name and kernel in ev.name.split(" = ", 1)[0]
+        ]
 
     def collectives(self) -> tuple[int, int]:
         """(total, exposed) picoseconds of collective ops: their intervals

@@ -5,18 +5,22 @@ top-k or a sigmoid router, and when the bfloat16 reference stands in the
 program's place), its metric files, and its configuration and traffic files against
 the program and the catalog."""
 
+import ast
 import dataclasses
 import json
 import os
+import re
 import types
 
 import jax
+import numpy as np
 import pytest
 
 from asyncrl_tpu.envs import registry
 from asyncrl_tpu.models import keye_moe
-from benchmarks import device, keye_counts, keye_readers, program_record, readers, run
-from benchmarks.loops import anakin_keye
+from benchmarks import (
+    device, episode_draw, keye_counts, keye_readers, program_record, readers, run)
+from benchmarks.loops import anakin_keye, common
 
 CELL = "keye_moe_rl.anakin_16x512"
 SCOPES = {
@@ -164,10 +168,11 @@ def test_every_new_metric_resolves_to_a_reader_in_the_new_cell_only(spec):
     # others; those of the CNN and of the other sequence policies stay away
     assert {"rollout_device_ms", "loss_and_grad_device_ms", "hbm_peak_gb",
             "device_idle_share", "actor_forward_device_ms", "env_step_device_ms",
-            "fused_vtrace_roofline", "step_trace_lower_s", "make_agent_s"} <= in_cell
+            "fused_vtrace_roofline", "step_trace_lower_s", "make_agent_s",
+            "make_agent_programs"} <= in_cell
     assert not in_cell & {"render_device_ms", "model_flops_util", "seq_step_mfu",
                           "kda_device_ms", "lfm2_step_mfu", "gqa_device_ms",
-                          "moe_experts_roofline", "make_agent_programs"}
+                          "moe_experts_roofline"}
     # and no cell but this one reports the new metrics
     for cell in (w["name"] for w in spec.doc["workloads"]):
         if cell != CELL:
@@ -175,27 +180,27 @@ def test_every_new_metric_resolves_to_a_reader_in_the_new_cell_only(spec):
                 m["name"] for m in spec.metrics_of("per_layer", cell)}
 
 
-def test_make_agent_programs_lists_the_cells_it_had(spec):
-    """What ``test_benchmark_program_metrics.py``'s case held of the entry but
-    for its last line, and the list ISSUE 32 gave it."""
+def test_make_agent_programs_is_read_in_every_cell(spec):
+    """PR 35: the count comes from the harness's own log of the backend's
+    events, which no cap cuts, so the entry lists no cells again (ISSUE 32
+    gave it the four that read a number then) and Keye's cell reads it too."""
     entry = next(m for m in spec.doc["per_layer"] if m["name"] == "make_agent_programs")
     read, params = spec.reader("make_agent_programs")
     assert read is program_record.programs_in_phase
     assert entry["source"] == "program_counter"
     assert entry["layer"] == "Entry" and entry["moves"] == "setup_s"
     assert params["phase"].startswith("setup.")
-    assert entry["workloads"] == ACCEPTED_CELLS
+    assert "workloads" not in entry
     assert [w["name"] for w in spec.doc["workloads"]] == [*ACCEPTED_CELLS, CELL]
+    for cell in (*ACCEPTED_CELLS, CELL):
+        assert entry in spec.metrics_of("per_layer", cell)
 
 
 def test_a_rehearsal_under_an_accepted_cells_name_prints_the_five_program_metrics(
         tmp_path, monkeypatch, capsys):
-    """What ``test_benchmark_program_metrics.py``'s rehearsal held, under the
-    name of a cell ``make_agent_programs`` lists (its own throwaway name is
-    not on the list, so there the metric stays out)."""
-    import ast
-    import re
-
+    """What ``test_benchmark_program_metrics.py``'s rehearsal holds, under the
+    name of an accepted cell and under a throwaway name: since PR 35
+    ``make_agent_programs`` lists no cells, so both report the five."""
     program = ("make_agent_s", "init_state_s", "make_agent_programs",
                "step_trace_lower_s", "step_load_s")
     for kind in ("configs", "traffic"):
@@ -236,10 +241,14 @@ def test_a_rehearsal_under_an_accepted_cells_name_prints_the_five_program_metric
     assert got["make_agent_programs"] == int(got["make_agent_programs"])
     assert got["step_trace_lower_s"] > 0 and got["step_load_s"] > 0
     assert got["step_trace_lower_s"] + got["step_load_s"] <= phases["warm_call"]
-    # a cell the list does not name reports the other four
+    # a cell of any other name reports the five too, the count of programs
+    # from the log of THIS run's harness: a second agent of one process
+    # finds its programs built
     assert run.main([*args, "--workload", "tiny.job"]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert set(line["metrics"]) == set(program) - {"make_agent_programs"}
+    assert set(line["metrics"]) == set(program)
+    again = line["metrics"]["make_agent_programs"]["value"]
+    assert 0 <= again <= got["make_agent_programs"]
 
 
 def test_the_shares_read_a_trace_and_give_nothing_without_one():
@@ -278,7 +287,95 @@ def test_the_shares_read_a_trace_and_give_nothing_without_one():
     assert keye_readers.keye_rollout_hbm_roofline(ev) is None
 
 
+# ------------------------------------------- the mix's draw of episodes
+
+
+def test_the_committed_episode_seed_is_the_smallest_the_rule_admits(spec):
+    """ISSUE 35's rule: the mean rows behind a token over the 8 x 512 steps
+    that follow the warm-in, from cold, within 2% of the length law's
+    stationary mean; one vmapped simulation over the candidates up to the
+    committed one."""
+    mix = spec.load("traffic", "anakin_16x512")
+    doc = spec.load("configs", "keye_moe_rl")
+    cfg = run.program_config(doc, mix, 3)
+    env = registry.make(cfg.env_id, cfg)
+    assert "episode_seed" not in mix["overrides"]  # no field of the program's
+    assert cfg.num_envs == 16 and cfg.unroll_len == 512
+    last = 8 * cfg.unroll_len
+    seed, rows = episode_draw.smallest_seed(
+        env, cfg.num_envs, 1, doc["warm_in_fragments"] * cfg.unroll_len + last,
+        last, 0.02, candidates=mix["episode_seed"] + 1)
+    assert seed == mix["episode_seed"] == len(rows) - 1
+    assert episode_draw.stationary_rows(env.min_len, env.max_len) == 2560
+    assert abs(rows[seed] - 2560) <= 51.2
+    assert (np.abs(rows[:seed] - 2560) > 51.2).all()
+    # the draws it passed over are the spread the cell had across --seeds
+    assert rows[:seed].min() < 2300 and rows[:seed].max() > 2900
+
+
+def test_rows_behind_counts_positions_from_the_done_flags():
+    done = np.zeros((7, 2), bool)
+    done[2, 0] = done[4, 1] = done[5, 1] = True
+    # env 0: 0 1 2 | 0 1 2 3; env 1: 0 1 2 3 4 | 0 | 0
+    assert float(episode_draw.rows_behind(done, 7)) == pytest.approx((9 + 10) / 14)
+    assert float(episode_draw.rows_behind(done, 2)) == pytest.approx((2 + 3 + 0 + 0) / 4)
+
+
+def test_two_seeds_of_the_parameters_meet_one_draw_of_episodes():
+    """Two ``program_config``s that differ in ``--seed``: through the helper
+    their env batches are one, their parameters are not, the carries stay the
+    ones ``make_agent`` built; 2 x 512 steps of the program's ``unroll`` under
+    either policy end their episodes where the simulation does, on other
+    tokens."""
+    from asyncrl_tpu import make_agent
+
+    n_dev, T, episode_seed = len(jax.devices()), 16, 7
+    docs = ({"preset": "keye_moe_tiny", "overrides": {"precision": "f32"}},
+            {"overrides": TINY_MIX(n_dev)})
+    done, tokens, actors, params = [], [], [], []
+    for seed in (5, 2400000013):
+        cfg = run.program_config(*docs, seed)
+        agent = make_agent(cfg)
+        try:
+            built = agent.state.actor
+            # the helper's draw is init_state's own: with the run's seed it
+            # gives back the env batch make_agent built, placed the same
+            same = common.with_episode_seed(built, agent.env, n_dev, seed)
+            for a, b in zip(jax.tree.leaves((same.env_state, same.obs, same.keys)),
+                            jax.tree.leaves((built.env_state, built.obs, built.keys))):
+                assert np.array_equal(a, b) and a.sharding == b.sharding
+            actor = common.with_episode_seed(built, agent.env, n_dev, episode_seed)
+            assert all(a is b for a, b in zip(
+                jax.tree.leaves(actor.core), jax.tree.leaves(built.core)))
+            assert jax.tree.leaves(actor.core)  # the policy has a carry
+            actors.append(jax.device_get((actor.env_state, actor.obs, actor.keys)))
+            params.append(jax.device_get(agent.state.params))
+            roll = anakin_keye.unroll_program(agent, cfg)
+            flags, seen = [], []
+            for _ in range(2 * 512 // T):
+                actor, r = roll(agent.state.actor_params, actor)
+                flags.append(np.asarray(r.done))
+                seen.append(np.asarray(r.obs))
+            done.append(np.concatenate(flags))
+            tokens.append(np.concatenate(seen))
+        finally:
+            agent.close()
+    for a, b in zip(jax.tree.leaves(actors[0]), jax.tree.leaves(actors[1])):
+        assert np.array_equal(a, b)
+    assert not all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(params[0]), jax.tree.leaves(params[1])))
+    assert np.array_equal(done[0], done[1]) and 30 < done[0].sum()
+    assert not np.array_equal(tokens[0], tokens[1])
+    simulated = episode_draw.simulate(
+        agent.env, 2 * n_dev, n_dev, episode_seed, 2 * 512)
+    assert np.array_equal(np.asarray(simulated), done[0])
+
+
 # ------------------------------------------- the loop, on the CPU, tiny
+
+
+TINY_MIX = lambda n_dev: {"num_envs": 2 * n_dev, "unroll_len": 16,
+                          "token_task": [64, 12, 32, 1, 2]}
 
 
 @pytest.fixture
@@ -289,7 +386,10 @@ def tiny(tmp_path, monkeypatch):
     shape = keye_moe.SHAPES["keye_moe_tiny"]
     model = dataclasses.asdict(shape)
 
-    def write(how, **more):
+    def write(how, episode_seed=None, **more):
+        (tmp_path / "traffic" / "tiny_tokens.json").write_text(json.dumps({
+            **({} if episode_seed is None else {"episode_seed": episode_seed}),
+            "overrides": TINY_MIX(n_dev)}))
         (tmp_path / "configs" / "tiny_keye.json").write_text(json.dumps({
             "name": "tiny_keye", "loop": "anakin_keye", "preset": "keye_moe_tiny",
             "overrides": {"precision": "f32", "updates_per_call": 1},
@@ -297,9 +397,6 @@ def tiny(tmp_path, monkeypatch):
             "reference_env_block": n_dev // 2 or 1, "warm_in_fragments": 2,
             "reference_how": how, **more}))
 
-    (tmp_path / "traffic" / "tiny_tokens.json").write_text(json.dumps({
-        "overrides": {"num_envs": 2 * n_dev, "unroll_len": 16,
-                      "token_task": [64, 12, 32, 1, 2]}}))
     real = json.load(open(os.path.join(run.ROOT, "BENCHMARK.json")))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps({
         **real, "configs": [],
@@ -326,15 +423,50 @@ def _last_line(capsys) -> dict:
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_the_loop_rehearsed_end_to_end_is_correct(tiny, capsys, trace):
+def test_the_loop_rehearsed_end_to_end_is_correct(tiny, capsys, monkeypatch, trace):
+    """The untraced run on a mix without an ``episode_seed`` (the actor state
+    stays the one ``make_agent`` built), the traced one on a mix with one:
+    the boundaries it then counts, update by update, are those the
+    simulation of that seed counts without any policy."""
     write, args = tiny
-    write({})
+    episode_seed = 7 if trace else None
+    write({}, episode_seed=episode_seed)
+    drawn = []
+    helper = common.with_episode_seed
+    monkeypatch.setattr(common, "with_episode_seed", lambda *a: (
+        drawn.append(a[-1]), helper(*a))[1])
     # a large seed: the driver's are a little over 2**31
     assert run.main([*args, "--seed", "2400000013", "--trace", str(trace)]) == 0
     line = _last_line(capsys)
     stderr = line.pop("stderr")
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert drawn == ([episode_seed] if trace else [])
+    counted = ast.literal_eval(re.search(
+        r"by update, from the warm-up call: episode boundaries (\[.*?\])",
+        stderr).group(1))
+    assert len(counted) == line["attempted"] + 1
+    if trace:
+        n_dev, T = len(jax.devices()), 16
+        env = registry.make("JaxTokenTask-v0", run.program_config(
+            {"preset": "keye_moe_tiny"}, {"overrides": TINY_MIX(n_dev)}, 0))
+        done = np.asarray(episode_draw.simulate(
+            env, 2 * n_dev, n_dev, episode_seed, (2 + len(counted)) * T))
+        # after the warm-in's two fragments, a fragment an update
+        assert counted == done.reshape(-1, T, 2 * n_dev).sum((1, 2))[2:].tolist()
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device",
+                          "compared"]
     assert line["correct"] is True, stderr[-3000:]
+    # every reading that decided it beside its limit: in the line, and as the
+    # last lines on stderr
+    compared = line["compared"]
+    assert {"replay_gap", "rows_before_l0", "rows_after_l1", "select_gap_l0",
+            "select_extra_l1", "select_size_l0", "logp_mean", "logp_rms", "kl",
+            "value_loss", "entropy", "indexer_kl", "dsa_rows_scored",
+            "grad_head", "step_indexer", "loss", "leaves_stuck",
+            "compiles_in_window", "updates_not_executed"} <= set(compared)
+    assert all(value <= limit for value, limit in compared.values())
+    last = stderr.strip().splitlines()[-len(compared):]
+    assert [ln.split()[2].rstrip(":") for ln in last] == list(compared)
+    assert all(ln.startswith("benchmarks: compared ") for ln in last)
     assert line["attempted"] >= 1 and line["failed"] == 0
     assert "after a warm-in of 2 fragments" in stderr
     assert "indexer-key rows up to len" in stderr and "selection" in stderr
@@ -366,6 +498,8 @@ def test_a_wrong_reference_is_not_correct(tiny, capsys, how):
     line = _last_line(capsys)
     assert line["correct"] is False
     assert "not correct" in line["stderr"]
+    over = [k for k, (value, limit) in line["compared"].items() if not value <= limit]
+    assert over and list(line)[-2:] == ["compared", "stderr"]
 
 
 def test_the_reference_in_bfloat16_in_the_programs_place_is_not_correct(

@@ -84,7 +84,9 @@ def _record(dropped=0):
 def handbuilt(monkeypatch):
     def install(record):
         monkeypatch.setattr(introspect, "process_record", lambda: record)
-        return {"window": (100.0, 110.0)}
+        # the harness's own log of the backend's events is never cut
+        return {"window": (100.0, 110.0), "counters": {"backend_compile_stamps": [
+            c[2] for c in _record()["compiles"] if c[0] == BACKEND]}}
 
     return install
 
@@ -119,15 +121,20 @@ def test_program_readers_give_none_where_there_is_nothing_to_read(
     # a phase that ended only after the window opened is another agent's
     assert program_record.phase_seconds({"window": (5.0, 6.0)}, "setup.first_update") is None
     # the cap dropped events and the record no longer reaches back to the
-    # phase's start: its seconds stand, its events cannot be counted
+    # phase's start: its seconds stand, the seconds of its events cannot be
+    # summed, and its programs are counted all the same (Kimi's cell read
+    # nothing there from PR 30 to PR 34): from the harness's own log
     cut = _record(dropped=7)
     cut["compiles"] = cut["compiles"][4:]
     ev = handbuilt(cut)
     assert program_record.phase_seconds(ev, "setup.agent") == 10.0
-    assert program_record.programs_in_phase(ev, "setup.agent") is None
+    assert program_record.programs_in_phase(ev, "setup.agent") == 3
     assert program_record.compile_seconds_in_phase(
         ev, "setup.agent", [BACKEND]) is None
     assert program_record.programs_in_phase(ev, "setup.first_update") == 1
+    # an evidence without that log (a loop that arms none) counts nothing
+    assert program_record.programs_in_phase(
+        {"window": ev["window"]}, "setup.agent") is None
     # a program without the record (the parent commit under this benchmark)
     monkeypatch.delattr(introspect, "process_record")
     for read, params in (
@@ -196,6 +203,12 @@ def test_rehearsal_prints_the_five_program_metrics(throwaway, capsys):
     assert 0 < got["init_state_s"] <= got["make_agent_s"]
     assert got["make_agent_programs"] >= 1
     assert got["make_agent_programs"] == int(got["make_agent_programs"])
+    # the harness's own log and the program's record count the same programs
+    record = introspect.process_record()
+    t0, t1 = [(a, b) for n, a, b in record["phases"] if n == "setup.agent"][-1]
+    if not record["dropped"]:
+        assert got["make_agent_programs"] == sum(
+            1 for c in record["compiles"] if c[0] == BACKEND and t0 <= c[2] <= t1)
     # the first update call traced, lowered and compiled the step inside
     # the harness's warm-up call
     assert got["step_trace_lower_s"] > 0 and got["step_load_s"] > 0

@@ -78,15 +78,20 @@ US = 1_000_000  # picoseconds
 @pytest.fixture
 def hand_built(tmp_path):
     """Two updates in a 100 us window. Chip 0: a while (10..90) spanning
-    rollout 10..30, a Mosaic call 30..32, loss_and_grad 32..70, an
-    all-reduce 70..80 of which 74..78 overlaps a fusion, then idle from 90.
-    Chip 1: busy 0..50."""
+    rollout 10..30 (its last 4 us another kernel's Mosaic call, which names
+    the V-trace kernel's result among its operands), the V-trace kernel's
+    Mosaic call 30..32, loss_and_grad 32..70, an all-reduce 70..80 of which
+    74..78 overlaps a fusion, then idle from 90. Chip 1: busy 0..50."""
     w = "jit(step)/while/body/"
     ops0 = [
         ("%while.1 = (...) while(...)", w[:-1], 10 * US, 80 * US),
-        ("%fusion.1 = f32[8] fusion(...)", w + "rollout/conv", 10 * US, 20 * US),
-        ('%custom-call.2 = f32[8] custom-call(...), custom_call_target="tpu_custom_call"',
-         w + "loss_and_grad/vtrace", 30 * US, 2 * US),
+        ("%fusion.1 = f32[8] fusion(...)", w + "rollout/conv", 10 * US, 16 * US),
+        ("%gqa_step.7 = f32[8] custom-call(f32[8] %jvp_jit_fused_vtrace_pallas__.2), "
+         'custom_call_target="tpu_custom_call"',
+         w + "rollout/gqa/gqa_step/pallas_call", 26 * US, 4 * US),
+        ("%jvp_jit_fused_vtrace_pallas__.2 = f32[8] custom-call(...), "
+         'custom_call_target="tpu_custom_call"',
+         w + "loss_and_grad/jvp(jit(fused_vtrace_pallas))/pallas_call", 30 * US, 2 * US),
         ("%fusion.3 = f32[8] fusion(...)", w + "loss_and_grad/jvp(M)/conv", 32 * US, 38 * US),
         ("%all-reduce.4 = f32[8] all-reduce(...)", w + "psum", 70 * US, 10 * US),
         ("%fusion.5 = f32[8] fusion(...)", w + "optimizer/add", 80 * US, 10 * US),
@@ -123,8 +128,12 @@ def test_hand_built_trace_has_the_known_answers(hand_built):
     assert d0.scope_ps("loss_and_grad") == 40 * US
     assert d0.scope_ps("optimizer") == 10 * US
     assert d0.scope_ps("roll") == 0  # a scope is a whole path component
-    # the kernel
-    assert [e.duration_ps for e in d0.mosaic_calls()] == [2 * US]
+    # the kernels: every Mosaic call, and one kernel's by the name its op
+    # carries (an operand that names another kernel's result is not it)
+    assert [e.duration_ps for e in d0.mosaic_calls()] == [4 * US, 2 * US]
+    assert [e.duration_ps for e in d0.mosaic_calls("fused_vtrace_pallas")] == [2 * US]
+    assert [e.duration_ps for e in d0.mosaic_calls("gqa_step")] == [4 * US]
+    assert d0.mosaic_calls("kda_step") == []
     # collectives: 60..68 (async) and 70..80 (sync) = 18 us in all; the
     # async one runs under the loss_and_grad fusion (32..70), the sync one
     # alone: 10 us exposed
@@ -150,9 +159,15 @@ def test_readers_on_the_hand_built_trace(hand_built):
     # mean over the two chips, per update: (20 + 50) / 2 / 2 us
     assert readers.scope_device_ms(ev, scope="rollout") == pytest.approx(17.5e-3)
     assert readers.scope_device_ms(ev, scope="nothing") is None
-    assert readers.mosaic_device_us(ev) == pytest.approx(2.0)
+    # the V-trace kernel's call alone: the 4 us of the other kernel's would
+    # make the mean 3 us, as the reader read until PR 35
+    vtrace = {"kernel": "fused_vtrace_pallas"}
+    assert readers.mosaic_device_us(ev, **vtrace) == pytest.approx(2.0)
+    assert readers.mosaic_device_us(ev, kernel="gqa_step") == pytest.approx(4.0)
+    assert readers.mosaic_device_us(ev, kernel="kda_step") is None
     # 4 * (8 * 32 * 256 + 256) bytes per chip at 1e12 B/s = 0.263168 us of 2 us
-    assert readers.fused_vtrace_roofline(ev) == pytest.approx(13.1584, rel=1e-4)
+    assert readers.fused_vtrace_roofline(ev, **vtrace) == pytest.approx(13.1584, rel=1e-4)
+    assert readers.fused_vtrace_roofline(ev, kernel="kda_step") is None
     assert readers.collective_ms(ev) == pytest.approx(9e-3)
     assert readers.collective_ms(ev, exposed=True) == pytest.approx(5e-3)
     assert readers.device_idle_share(ev) == pytest.approx(35.0)
@@ -165,6 +180,22 @@ def test_readers_on_the_hand_built_trace(hand_built):
     )
     assert readers.model_flops_util({**ev, "traced_updates": 0}) is None
     assert readers.model_flops_util({**ev, "trace": None}) is None
+
+
+@pytest.mark.parametrize("name", ["fused_vtrace_device_us", "fused_vtrace_roofline"])
+def test_the_vtrace_metrics_name_the_kernel_the_program_builds(name):
+    import inspect
+
+    from asyncrl_tpu.ops import pallas_scan
+    from benchmarks import run
+
+    spec = run.Spec(os.path.join(run.ROOT, "BENCHMARK.json"), [run.BENCH_DIR])
+    read, params = spec.reader(name)
+    assert read is getattr(readers, {"fused_vtrace_device_us": "mosaic_device_us"}.get(name, name))
+    assert params == {"kernel": "fused_vtrace_pallas"}
+    assert f'name="{params["kernel"]}"' in inspect.getsource(pallas_scan.fused_vtrace_pallas)
+    entry = next(m for m in spec.doc["per_layer"] if m["name"] == name)
+    assert "workloads" not in entry and entry["layer"] == "Kernels"
 
 
 def test_a_trace_without_a_chip_reads_as_nothing(tmp_path):
@@ -219,6 +250,8 @@ def test_recorded_trace_reduces_to_what_was_run(recorded):
     # one Mosaic V-trace call per update, each a fraction of a microsecond
     mosaic = d.mosaic_calls()
     assert len(mosaic) == updates
+    assert d.mosaic_calls("fused_vtrace_pallas") == mosaic
+    assert d.mosaic_calls("gqa_step") == []
     assert all(0 < e.duration_ps < 5 * US for e in mosaic)
     # the scopes of learn/learner.py are found, and they are most of the
     # busy time, which is most of the window
