@@ -1026,23 +1026,28 @@ def make_train_step(
         loss = _pmean(loss, axes)
 
         step = state.update_step + 1
-        if (
-            config.algo in ("impala", "qlearn")
-            and config.actor_staleness > 1
-        ):
-            # IMPALA: the stale behaviour-policy copy. Q-learning: the SAME
-            # stale copy doubles as the target network θ⁻ (and the ε-greedy
-            # behaviour net), so actor_staleness is the target-update period.
-            refresh = (step % config.actor_staleness) == 0
-            actor_params = jax.tree.map(
-                lambda new, old: jnp.where(refresh, new, old),
-                params, state.actor_params,
-            )
-        else:
-            # On-policy (and staleness<=1 IMPALA): actors always see the
-            # newest weights next fragment — one full update of lag, the
-            # minimum true-IMPALA staleness.
-            actor_params = params
+        # "publish": the copy of the parameters the actors see. A label and
+        # no barrier: where XLA fuses the optimizer's passes into this
+        # select, the fusion carries one of the two names (PERF.md §5).
+        with jax.named_scope("publish"):
+            if (
+                config.algo in ("impala", "qlearn")
+                and config.actor_staleness > 1
+            ):
+                # IMPALA: the stale behaviour-policy copy. Q-learning: the
+                # SAME stale copy doubles as the target network θ⁻ (and the
+                # ε-greedy behaviour net), so actor_staleness is the
+                # target-update period.
+                refresh = (step % config.actor_staleness) == 0
+                actor_params = jax.tree.map(
+                    lambda new, old: jnp.where(refresh, new, old),
+                    params, state.actor_params,
+                )
+            else:
+                # On-policy (and staleness<=1 IMPALA): actors always see the
+                # newest weights next fragment — one full update of lag, the
+                # minimum true-IMPALA staleness.
+                actor_params = params
 
         obs_stats = state.obs_stats
         if obs_stats is not None:

@@ -310,6 +310,11 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
         parts = _down(act, down, dtype)
         return functools.reduce(jnp.add, list(parts))
 
+    # Each side under a scope of the name ``moe_sites`` counts it by, around
+    # the branch function: its forward, rematerialised and backward ops keep
+    # it, on whichever side of a ``lax.cond`` they are built.
+
+    @jax.named_scope("moe_dense")
     def dense(_):
         # every held expert on every token, 2,048 tokens at a time: at a
         # fragment's size [E, N, F] is never whole
@@ -322,6 +327,7 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
         )
         return out.reshape(n_tokens, width)
 
+    @jax.named_scope("moe_gathered")
     def gathered(_):
         # each expert's tokens, first come first: rows past its load point
         # at row N, which reads zeros and is dropped on the way back
@@ -351,11 +357,13 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
             padded = -(-load // tile) * tile  # an expert's rows, in whole tiles
             ends = jnp.cumsum(padded)
             fits = ends[-1] <= rows
-            out = jax.lax.cond(
-                fits,
-                lambda _: _grouped(x, ids, weights, held, ends - padded, ends,
-                                   rows, tile, gate, up, down, dtype),
-                dense, None)
+
+            @jax.named_scope("moe_grouped")
+            def grouped(_):
+                return _grouped(x, ids, weights, held, ends - padded, ends,
+                                rows, tile, gate, up, down, dtype)
+
+            out = jax.lax.cond(fits, grouped, dense, None)
         else:  # a decode step's few tokens
             x = _site_p.bind(x, path="dense")
             fits = jnp.zeros((), bool)

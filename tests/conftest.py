@@ -370,28 +370,11 @@ QUICK: dict[str, object] = {
 # expected to fail here, strictly: the PR that repairs the assertion
 # makes them pass, which fails until these marks go. What else they held
 # is asserted in tests/benchmarks/test_benchmark_seq.py.
-# ISSUE 32 §7 made the same line false for `make_agent_programs`: its reader
-# finds nothing in Kimi's cell (the record's cap), a new cell that reports
-# `setup_s` would have had to report it, and so it was given the list of the
-# cells it had. The same file's rehearsal runs a throwaway cell under a name
-# of its own ("tiny.job"), which that list does not hold, and expects the
-# metric: it fails for the same reason. What else the two held is asserted in
-# tests/benchmarks/test_benchmark_keye.py (the rehearsal there under an
-# accepted cell's name).
 SUPERSEDED = {
     f"test_benchmark_program_metrics.py::test_metric_resolves_to_its_reader[{m}]":
-    reason
-    for reason, metrics in (
-        ("ISSUE 26 §5: the metric lists the atari cells",
-         ("render_device_ms", "section0_device_ms", "max_pool_device_ms")),
-        ("ISSUE 32 §7: the metric lists the cells it had",
-         ("make_agent_programs",)),
-    )
-    for m in metrics
+    "ISSUE 26 §5: the metric lists the atari cells"
+    for m in ("render_device_ms", "section0_device_ms", "max_pool_device_ms")
 }
-SUPERSEDED[
-    "test_benchmark_program_metrics.py::test_rehearsal_prints_the_five_program_metrics"
-] = "ISSUE 32 §7: make_agent_programs lists the cells it had, not 'tiny.job'"
 
 
 def pytest_collection_modifyitems(config, items):

@@ -258,10 +258,19 @@ def test_the_compiled_step_names_the_scopes_a_profile_reads():
     }
     # as benchmarks/xplane.py in_scope matches them: whole path components
     # (``render`` is the outermost scope inside the vmapped env step)
+    # ``optimizer`` and ``publish`` (the refresh of ``actor_params``): the
+    # step's two scopes after the gradient, side by side and not nested
     for scope in ("rollout", "loss_and_grad", "actor_forward", "env_step",
-                  "vmap(render)", "section0", "section1", "max_pool"):
+                  "vmap(render)", "section0", "section1", "max_pool",
+                  "optimizer", "publish"):
         assert scope in components, scope
     names = re.findall(r'op_name="([^"]+)"', text)
+    assert any("/publish/" in n and "select_n" in n for n in names)
+    # siblings: no op is under two of the step's four scopes, so their times
+    # and ``update_rest_device_ms`` add up to the busy time, none twice
+    step_scopes = ("rollout", "loss_and_grad", "optimizer", "publish")
+    assert not any(sum(f"/{s}/" in f"/{n}/" for s in step_scopes) > 1
+                   for n in names)
     assert any("/rollout/" in n and "/actor_forward/" in n and "/section0/" in n
                for n in names)
     assert any("/env_step/vmap(render)/" in n for n in names)
@@ -297,6 +306,9 @@ def test_the_sequence_policy_step_names_the_scopes_a_profile_reads():
     assert some("/rollout/", "/actor_forward/", "/kda/kda_step/")
     assert some("/rollout/", "/actor_forward/", "/mla/")
     assert some("/rollout/", "/actor_forward/", "/moe/moe_router/")
+    # a decode step's few tokens take the expert layer's dense side, under
+    # the name ``moe_sites`` counts it by
+    assert some("/rollout/", "/actor_forward/", "/moe/moe_experts/moe_dense/")
     assert some("/rollout/", "/actor_forward/", "/lm_head/")
     assert some("/rollout/", "/core_reset/")
     assert not some("/rollout/", "kda_chunk")
@@ -307,6 +319,49 @@ def test_the_sequence_policy_step_names_the_scopes_a_profile_reads():
         assert some("/loss_and_grad/", "transpose(", scope), scope
     after = introspect.process_record()["kda_sites"]
     assert after["step"] > before["step"] and after["chunk"] > before["chunk"]
+
+
+@pytest.mark.parametrize("E, k, N, tile, side", [
+    # the sizes that reach each side in tests/test_kimi_linear.py and
+    # tests/test_lfm2_moe.py: a tiny step's few tokens lower the dense one
+    (64, 2, 4096, None, "gathered"),
+    (32, 4, 2048, 16, "grouped"),
+])
+def test_the_expert_layers_sides_keep_their_scopes_forward_and_backward(
+        E, k, N, tile, side):
+    """``moe_gathered`` / ``moe_grouped`` inside ``moe_experts``, and
+    ``moe_dense`` behind the same ``lax.cond``: on the backward ops too
+    (``moe_gathered_device_ms`` and ``moe_dense_device_ms`` count them)."""
+    from asyncrl_tpu.ops import moe
+
+    D, F, held = 32, 16, tuple(range(8))
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = jax.random.normal(keys[0], (N, D))
+    ids, weights = moe.route(x, jax.random.normal(keys[1], (D, E)), None, k, 1.0)
+    gate, up = (jax.random.normal(key, (8, D, F)) for key in keys[2:4])
+    down = jax.random.normal(keys[4], (8, F, D))
+
+    def loss(x, gate, up, down):
+        # under the layer's scope, as the models call it: autodiff wraps the
+        # outermost name (``jvp(moe)``) and leaves the inner ones whole
+        with jax.named_scope("moe"):
+            out, _, _ = moe.held_experts(
+                x, ids, weights, held, E, gate, up, down, jnp.float32, tile)
+        return jnp.sum(out)
+
+    before = introspect.process_record()["moe_sites"]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        x, gate, up, down).compile().as_text()
+    after = introspect.process_record()["moe_sites"]
+    assert {s for s in after if after[s] > before[s]} == {side}
+    names = re.findall(r'op_name="([^"]+)"', text)
+    for scope in (f"moe_{side}", "moe_dense"):  # the side, and its overflow
+        inside = [n for n in names if "/moe_experts/cond/branch_" in n
+                  and f"/{scope}/" in n]
+        assert inside, scope
+        assert any("transpose(" in n for n in inside), scope
+    other = {"gathered": "moe_grouped", "grouped": "moe_gathered"}[side]
+    assert not any(f"/{other}/" in n for n in names)
 
 
 def test_kda_sites_count_the_one_token_form_once_per_site_and_program():
