@@ -384,7 +384,8 @@ class SeqPolicyBase:
         sparse = [c for c in counters if "indexer_kl" in c]
         if sparse:
             # the selection's counters, means over queries and sparse layers
-            for name in ("dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"):
+            for name in ("dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share",
+                         "dsa_rows_computed"):
                 aux[name] = jax.lax.stop_gradient(
                     sum(c[name] for c in sparse) / (len(sparse) * T * B))
             # the indexers' loss: each layer's mean KL over the fragment's

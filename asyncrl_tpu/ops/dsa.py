@@ -47,9 +47,11 @@ Two forms, one function:
   every row is selected and ``gqa_step`` serves.
 - ``dsa_fragment``: a fragment's queries over ``[cache, fragment]`` rows, in
   blocks of one env and ``query_block`` queries, each rematerialised in the
-  backward pass: every row's scores are computed and the softmax runs under
-  the selection's mask (a gather of 2,048 rows a QUERY would move more than
-  the products it saves). Returns the summed KL term and the selection's
+  backward pass: the scores of the env's rung of the cache (its cached rows
+  rounded up to an eighth, a quarter, a half or the whole capacity) and of
+  the fragment's rows are computed, and the softmax runs under the
+  selection's mask (a gather of 2,048 rows a QUERY would move more than the
+  products it saves). Returns the summed KL term and the selection's
   counters beside the heads' outputs.
 
 ``select`` finds the ``k``-th largest score by bisection on the scores' bits
@@ -67,6 +69,7 @@ lines after, as in ``ops/gqa.py``).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -258,34 +261,105 @@ def _block(q, qi, w, mask, keys, values, ki, top_k, scale, groups,
     return (out, counted, chosen) if with_chosen else (out, counted)
 
 
+def _rungs(L: int, T: int) -> tuple[int, ...]:
+    """The cached rows an env's blocks may be computed over: an eighth, a
+    quarter, a half or all of the cache's ``L`` (rounded up); ``L`` alone
+    where the cache is no longer than the fragment. Few rungs, as each is
+    compiled code in every layer and pass (eight, in eighths, made Keye's
+    step twice the executable and its set-up ~15 s longer), and none
+    between a half and the whole (there, eighths' rungs of 6,144 and 7,168
+    rows took ~200 ms an env and update more than their rows in the cell:
+    PERF.md §6, PR 37)."""
+    if L <= T:
+        return (L,)
+    return tuple(sorted({-(-L // 2 ** k) for k in range(4)}))
+
+
 def dsa_fragment(q, qi, w, mask, keys, values, ki, top_k: int, scale: float,
                  query_block: int, with_chosen: bool = False):
     """A fragment. ``q`` [B, T, H, dh] float32, normed and rotated; ``qi``
-    [B, T, J, dI], ``w`` [B, T, J]; ``mask`` [B, T, P]: the rows of a
-    query's own episode up to itself; ``keys``, ``values`` [B, P, G * dh],
-    ``ki`` [B, P, dI]: the cached rows and the fragment's own. Returns (the
-    heads' weighted values [B, T, H, dh] float32, {"indexer_kl",
-    "dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"}: sums over
-    the B * T queries; with ``with_chosen`` also "chosen" [B, T, P], the
-    rows each query attended)."""
+    [B, T, J, dI], ``w`` [B, T, J]; ``mask`` [B, T, L + T]: the rows of a
+    query's own episode up to itself; ``keys``, ``values`` [B, L + T, G *
+    dh], ``ki`` [B, L + T, dI]: the ``L`` cached rows and the fragment's
+    own. Returns (the heads' weighted values [B, T, H, dh] float32,
+    {"indexer_kl", "dsa_rows_scored", "dsa_rows_selected",
+    "dsa_pruned_share", "dsa_rows_computed"}: sums over the B * T queries;
+    with ``with_chosen`` also "chosen" [B, T, L + T], the rows each query
+    attended).
+
+    An env's blocks are computed over the cache's first rows up to the
+    smallest rung (``_rungs``) that holds every cached row its mask admits,
+    and the fragment's rows, in their order: the rows left out are masked
+    out for every query, so the result is the whole rows' but for the
+    order of float sums. Each rung is a branch of ``lax.switch`` under
+    ``jax.checkpoint`` over the whole rows, slicing inside: the branches
+    save residuals of one shape, which the switch's VJP merges into one
+    set (sliced residuals would be kept for every rung, zero-filled)."""
     B, T, H, dh = q.shape
+    L = keys.shape[1] - T
     G = keys.shape[-1] // dh
     tq = math.gcd(T, query_block)
+    rungs = _rungs(L, T)
+    static = (L, T, tq, top_k, scale, G, with_chosen)
+    if len(rungs) == 1:
+        env = lambda args: _env_over(L, *static)(*args)
+    else:
+        branches = [_rung(c, *static) for c in rungs]
 
-    def env(args):
-        q, qi, w, mask, keys, values, ki = args
-        blocks = lambda a: a.reshape(T // tq, tq, *a.shape[1:])
-        out, counted, *chosen = lax.map(
-            jax.checkpoint(lambda xs: _block(
-                *xs, keys, values, ki, top_k, scale, G, with_chosen)),
-            tuple(blocks(a) for a in (q, qi, w, mask)),
-        )
-        return (out.reshape(T, H, dh), jnp.sum(counted, axis=0),
-                *(c.reshape(T, -1) for c in chosen))
+        def env(args):
+            held = jnp.max(jnp.where(
+                jnp.any(args[3][:, :L], axis=0), jnp.arange(1, L + 1), 0))
+            rung = jnp.sum(held > jnp.asarray(rungs[:-1]))
+            return lax.switch(rung, branches, *args)
 
     out, counted, *chosen = lax.map(env, (q, qi, w, mask, keys, values, ki))
-    names = ("indexer_kl", "dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share")
+    names = ("indexer_kl", "dsa_rows_scored", "dsa_rows_selected",
+             "dsa_pruned_share", "dsa_rows_computed")
     counted = dict(zip(names, jnp.sum(counted, axis=0)))
     if with_chosen:
         counted["chosen"] = chosen[0]
     return out, counted
+
+
+def _env_over(c: int, L: int, T: int, tq: int, top_k: int, scale: float,
+              groups: int, with_chosen: bool):
+    """One env's blocks over the cache's first ``c`` rows and the
+    fragment's ``T``, a function of all ``L + T`` rows."""
+
+    def env(q, qi, w, mask, keys, values, ki):
+        if c < L:
+            kept = lambda a, axis=0: jnp.concatenate(
+                [lax.slice_in_dim(a, 0, c, axis=axis),
+                 lax.slice_in_dim(a, L, L + T, axis=axis)], axis=axis)
+            keys, values, ki, mask = kept(keys), kept(values), kept(ki), kept(mask, 1)
+        blocks = lambda a: a.reshape(T // tq, tq, *a.shape[1:])
+        out, counted, *chosen = lax.map(
+            jax.checkpoint(lambda xs: _block(
+                *xs, keys, values, ki, top_k, scale, groups, with_chosen)),
+            tuple(blocks(a) for a in (q, qi, w, mask)),
+        )
+        counted = jnp.append(jnp.sum(counted, axis=0), F32(T * (c + T)))
+        return (out.reshape(T, *out.shape[2:]), counted,
+                *(_widen(ch.reshape(T, -1), c, L) for ch in chosen))
+
+    return env
+
+
+@functools.lru_cache(maxsize=None)
+def _rung(*static):
+    """A branch of the ladder, ``_env_over``'s: ``jax.checkpoint`` of
+    ``jax.jit``, so that its residuals are its operands, and one function
+    for every layer and call, which JAX traces, transforms and lowers once
+    a program (traced apiece in each of four layers, eight rungs made
+    Keye's step take twice as long to lower)."""
+    return jax.checkpoint(jax.jit(_env_over(*static)))
+
+
+def _widen(chosen, c: int, L: int):
+    """A selection over the cache's first ``c`` rows and the fragment's
+    [T, c + T] -> over all ``L + T`` rows."""
+    if c == L:
+        return chosen
+    T = chosen.shape[0]
+    return jnp.concatenate(
+        [chosen[:, :c], jnp.zeros((T, L - c), bool), chosen[:, c:]], axis=1)

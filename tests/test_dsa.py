@@ -5,7 +5,9 @@ rows a query at a time), the KL term, where its gradient goes and where it
 does not, the form that serves a cache no longer than ``top_k``, and the
 forms that attend the chosen rows of a deeper one: ``ops/gqa.py``'s kernel
 under the selection's mask (in the Pallas interpreter, the platform's choice
-forced) or the masked products, its VJP, and the counter of the choice."""
+forced) or the masked products, its VJP, and the counter of the choice; and
+the fragment form over each env's rung of the cache against the all-rows
+form, what its VJP keeps, and the rows it counts."""
 
 import contextlib
 import functools
@@ -371,3 +373,140 @@ def test_the_kl_term_trains_the_indexer_and_nothing_else():
     ref = jax.grad(plain_kl, argnums=(0, 1, 2))(qi, w, ki)
     for a, b in zip(mine, ref):
         np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+
+
+# ---- the fragment form over each env's rung of the cache (ISSUE 37)
+
+RL, RT, RQ, RK = 32, 16, 8, 8  # keye_moe_tiny's cache, fragment and top-k
+# each env's cached rows and the rung its blocks run over (an eighth, a
+# quarter, a half or all of the 32): none, a rung's edge, one row past it,
+# the whole cache; and an episode that ends after the fragment's sixth
+# query, whose later queries see the fragment's rows only
+LADDER_CASES = {
+    "empty": ([0], [4], None), "edge": ([8], [8], None),
+    "past_edge": ([9], [16], None), "full": ([32], [32], None),
+    "boundary": ([13], [16], 6),
+    "mixed": ([0, 8, 9, 32, 13], [4, 8, 16, 32, 16], 6),
+}
+
+
+def ladder_case(lengths, boundary, L=RL, T=RT):
+    """Operands of ``len(lengths)`` envs over ``L`` cached rows and ``T`` of
+    the fragment; with ``boundary`` the last env's episode ends after its
+    query ``boundary - 1``."""
+    B = len(lengths)
+    rows = operands(B, L + T, jax.random.PRNGKey(8))
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    t = jnp.arange(T)
+    episode = jnp.zeros((B, T), int)
+    if boundary is not None:
+        episode = episode.at[-1].set((t >= boundary).astype(int))
+    cached = jnp.arange(L)[None, :] < jnp.asarray(lengths)[:, None]
+    own = (t[None, :, None] >= t[None, None, :]) & (
+        episode[:, :, None] == episode[:, None, :])
+    mask = jnp.concatenate(
+        [cached[:, None, :] & (episode == 0)[:, :, None], own], axis=-1)
+    return (jax.random.normal(ks[0], (B, T, H, DH)),
+            jax.random.normal(ks[1], (B, T, J, DI)),
+            jax.random.normal(ks[2], (B, T, J)), mask,
+            rows["keys"], rows["values"], rows["ki"])
+
+
+def all_rows(q, qi, w, mask, keys, values, ki, with_chosen=False, tq=RQ):
+    """The form before the ladder: every env's blocks over all ``L + T``
+    rows -> (out, [kl, scored, selected, pruned] summed, chosen)."""
+    T = q.shape[1]
+
+    def env(args):
+        q, qi, w, mask, keys, values, ki = args
+        blocks = lambda a: a.reshape(T // tq, tq, *a.shape[1:])
+        out, counted, *chosen = lax.map(
+            jax.checkpoint(lambda xs: dsa._block(
+                *xs, keys, values, ki, RK, SCALE, G, with_chosen)),
+            tuple(blocks(a) for a in (q, qi, w, mask)))
+        return (out.reshape(T, H, DH), jnp.sum(counted, axis=0),
+                *(c.reshape(T, -1) for c in chosen))
+
+    out, counted, *chosen = lax.map(env, (q, qi, w, mask, keys, values, ki))
+    return out, jnp.sum(counted, axis=0), *chosen
+
+
+@pytest.mark.parametrize("case", list(LADDER_CASES))
+def test_the_fragment_form_over_its_rung_is_the_all_rows_form(case):
+    """Each env's blocks run over its cached rows rounded up to a rung of the
+    cache, and the fragment's: the outputs, the counters, the selection to
+    the row and the gradients of every operand are the all-rows form's, and
+    ``dsa_rows_computed`` counts the rung's rows."""
+    lengths, rung, boundary = LADDER_CASES[case]
+    args = ladder_case(lengths, boundary)
+    assert dsa._rungs(RL, RT) == (4, 8, 16, 32)
+    out, counted = jax.jit(
+        lambda *a: dsa.dsa_fragment(*a, RK, SCALE, RQ, True))(*args)
+    ref_out, ref_counted, ref_chosen = jax.jit(
+        lambda *a: all_rows(*a, with_chosen=True))(*args)
+    np.testing.assert_allclose(out, ref_out, atol=2e-6)
+    np.testing.assert_array_equal(counted["chosen"], ref_chosen)
+    kl, scored, selected, pruned = ref_counted
+    assert float(counted["indexer_kl"]) == pytest.approx(float(kl), rel=1e-5)
+    for name, want in (("dsa_rows_scored", scored), ("dsa_rows_selected", selected),
+                       ("dsa_pruned_share", pruned)):
+        assert float(counted[name]) == float(want), name
+    assert float(counted["dsa_rows_computed"]) == RT * sum(c + RT for c in rung)
+
+    mix = jax.random.normal(jax.random.PRNGKey(10), out.shape)
+    q, qi, w, mask, keys, values, ki = args
+
+    def loss(form):
+        def f(q, qi, w, keys, values, ki):
+            out, kl = form(q, qi, w, mask, keys, values, ki)
+            return jnp.sum(out * mix) + kl
+        return jax.jit(jax.grad(f, argnums=range(6)))
+
+    mine = loss(lambda *a: (lambda o, c: (o, c["indexer_kl"]))(
+        *dsa.dsa_fragment(*a, RK, SCALE, RQ)))(q, qi, w, keys, values, ki)
+    ref = loss(lambda *a: (lambda o, c: (o, c[0]))(*all_rows(*a)))(
+        q, qi, w, keys, values, ki)
+    for name, a, b in zip(("q", "qi", "w", "keys", "values", "ki"), mine, ref):
+        np.testing.assert_allclose(
+            a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7, err_msg=name)
+
+
+def test_the_ladders_residuals_are_the_whole_rows():
+    """What the fragment form's VJP keeps: no array whose shape follows a
+    rung (the switch would keep every rung's, zero-filled, stacked over the
+    envs), and no more bytes than the all-rows form keeps but the rungs'
+    indices. Shapes with no size in common with a rung: 40 cached rows,
+    rungs of 5, 10, 20 and 40, a fragment of 8."""
+    L, T, tq = 40, 8, 4
+    q, qi, w, mask, keys, values, ki = ladder_case([0, 6, 40], None, L, T)
+    rungs = dsa._rungs(L, T)
+    assert rungs == (5, 10, 20, 40)
+    sliced = {n for c in rungs[:-1] for n in (c, c + T)}
+
+    def kept(form):
+        _, vjp = jax.vjp(lambda *a: form(*a[:3], mask, *a[3:])[0],
+                         q, qi, w, keys, values, ki)
+        return [np.asarray(x) for x in jax.tree.leaves(vjp)]
+
+    mine = kept(lambda *a: dsa.dsa_fragment(*a, RK, SCALE, tq))
+    ref = kept(lambda *a: all_rows(*a, tq=tq))
+    assert not [x.shape for x in mine if sliced & set(x.shape)]
+    ints = sum(x.nbytes for x in mine if x.dtype == np.int32)
+    assert 0 < ints <= 4 * len(mine) * q.shape[0]
+    assert sum(x.nbytes for x in mine) - ints <= sum(x.nbytes for x in ref)
+
+
+def test_rows_computed_counted_by_hand():
+    """At the benchmark's hand-counted shape (a cache of 8 rows, a fragment
+    of 4 queries, one block): rungs of 1, 2, 4 and 8 rows, so envs holding
+    0, 3 and 8 rows compute over 1, 4 and 8 cached rows and the fragment's
+    4: 5 + 8 + 12 rows a query, 4 queries an env. A cache no longer than
+    the fragment is one rung, its whole: 4 + 8 rows a query, 8 queries an
+    env."""
+    assert dsa._rungs(8, 4) == (1, 2, 4, 8)
+    assert dsa._rungs(8192, 512) == (1024, 2048, 4096, 8192)
+    _, counted = dsa.dsa_fragment(*ladder_case([0, 3, 8], None, 8, 4), 3, SCALE, 4)
+    assert float(counted["dsa_rows_computed"]) == 4 * (5 + 8 + 12)
+    assert dsa._rungs(4, 8) == (4,) and dsa._rungs(8, 8) == (8,)
+    _, counted = dsa.dsa_fragment(*ladder_case([0, 3], None, 4, 8), 3, SCALE, 4)
+    assert float(counted["dsa_rows_computed"]) == 2 * 8 * (4 + 8)

@@ -124,6 +124,13 @@ def test_step_form_fragment_form_and_reference_agree_past_top_k(policy, fragment
     assert float(aux["indexer_kl"]) == pytest.approx(float(view["indexer_kl"]), rel=1e-4)
     for name in ("dsa_rows_scored", "dsa_rows_selected", "dsa_pruned_share"):
         assert float(aux[name]) == pytest.approx(float(view[name]), rel=1e-6), name
+    # each env's blocks ran over its cached rows rounded up to a rung (an
+    # eighth, a quarter, a half or all of the 32) and the fragment's own
+    held = np.stack([np.asarray(layer["len"]) for layer in r.init_core.layers])
+    rung = np.select([held <= 4, held <= 8, held <= 16], [4, 8, 16], 32)
+    assert float(aux["dsa_rows_computed"]) == pytest.approx(float(np.mean(rung + T)))
+    assert float(aux["dsa_rows_scored"]) < float(aux["dsa_rows_computed"]) <= (
+        TINY.max_positions + T)
     assert float(aux["dsa_pruned_share"]) > 0.25
     assert float(aux["dsa_rows_selected"]) <= TINY.index_top_k < float(aux["dsa_rows_scored"])
     # the rollout's log-prob is the learner's recompute
