@@ -446,6 +446,28 @@ keye_moe_tiny = keye_moe_rl.replace(
     total_env_steps=100_000, learning_rate=1e-3,
 )
 
+# Moonlight-16B-A3B as a token-level policy on the same path: latent
+# attention with decoupled RoPE in every layer over an 8,192-row latent
+# cache, 6-of-64 sigmoid-routed experts with two shared, of which this chip
+# holds 8 (models/moonlight.py SHAPES: one chip's share of layers 0-4, each
+# layer shared by 8 chips). 16 envs x 512 tokens = 8,192 tokens an update;
+# episodes of 2,048-8,192 tokens, the model's whole context. 568 M
+# parameters at 16 bytes (RMSProp, donated) -- see
+# benchmarks/configs/moonlight_rl.json.
+moonlight_rl = kimi_linear_rl.replace(
+    seq_model="moonlight_5l",
+    token_task=(20480, 2048, 8192, 32, 128),
+    num_envs=16,
+    unroll_len=512,
+)
+# Episodes of 12-32 tokens over fragments of 16: caches outlive fragments
+# and positions restart inside them.
+moonlight_tiny = moonlight_rl.replace(
+    seq_model="moonlight_tiny", token_task=(64, 12, 32, 1, 2),
+    num_envs=8, unroll_len=16,
+    total_env_steps=100_000, learning_rate=1e-3,
+)
+
 PRESETS: dict[str, Config] = {
     "cartpole_a3c": cartpole_a3c,
     "cartpole_a3c_cpu": cartpole_a3c_cpu,
@@ -480,6 +502,8 @@ PRESETS: dict[str, Config] = {
     "lfm2_moe_tiny": lfm2_moe_tiny,
     "keye_moe_rl": keye_moe_rl,
     "keye_moe_tiny": keye_moe_tiny,
+    "moonlight_rl": moonlight_rl,
+    "moonlight_tiny": moonlight_tiny,
 }
 
 

@@ -7,9 +7,11 @@ value head (the RL addition).
 The policy's two forms (one token through the carry; a whole fragment from
 the fragment-initial carry), its trunk, heads and counters, and the carry's
 reset-on-read protocol are ``models/seq_common.py``'s, shared with the other
-sequence policy (``models/lfm2_moe.py``). This module holds the shape
-record, the two mixers and the weights. In the fragment form KDA runs
-chunkwise and MLA under an episode mask.
+sequence policy (``models/lfm2_moe.py``); the MLA mixer is
+``models/mla.py``'s, shared with ``models/moonlight.py`` (which rotates
+what this model leaves unrotated). This module holds the shape record, the
+KDA mixer and the weights. In the fragment form KDA runs chunkwise and MLA
+under an episode mask.
 
 The carry's entries: a KDA layer's ``{"S" [B, H, dk, dv] float32, "conv"
 [B, 3, 3*H*dk], "fresh" [B] bool}``, an MLA layer's ``{"kv" [B, L, kv_lora +
@@ -29,18 +31,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from asyncrl_tpu.models import mla
 from asyncrl_tpu.models.seq_common import (  # noqa: F401  (SeqCore: the carry's type, by this name too)
     F32,
     SeqCore,
     SeqPolicyBase,
-    _cache_after,
     _dot,
-    _env_block,
-    _episode_mask,
     _rms_norm,
-    _softmax,
     _short_conv,
-    _to_blocks,
     _zero_where,
     seeded,
 )
@@ -141,103 +139,6 @@ def _kda_mixer(p, x, state, done, shape: SeqShape, dtype):
             "S": S, "conv": conv, "fresh": jnp.zeros_like(fresh)}
 
 
-def _mla_project(p, x, shape: SeqShape, dtype):
-    """Queries [..., H, nope + rope] and the latent row [..., lora + rope]
-    (normed latent, then the shared unrotated key part) the cache holds."""
-    q = _dot(x, p["q"], dtype).reshape(
-        *x.shape[:-1], shape.mla_heads, shape.qk_nope + shape.qk_rope
-    )
-    kv = _dot(x, p["kv_a"], dtype)
-    latent = jnp.concatenate([
-        _rms_norm(kv[..., : shape.kv_lora], p["kv_norm"], shape.eps),
-        kv[..., shape.kv_lora:],
-    ], axis=-1)
-    return q, latent.astype(dtype)
-
-
-def _mla_step(p, x, state, shape: SeqShape, dtype):
-    """One token: write its latent row at ``len``, attend over the rows of
-    the current episode with the up-projection absorbed into the query and
-    the output (no per-position keys or values are formed)."""
-    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
-    with jax.named_scope("mla"):
-        q, latent = _mla_project(p, x, shape, dtype)
-        B = x.shape[0]
-        cache = state["kv"].at[jnp.arange(B), state["len"]].set(latent)
-        kv_b = p["kv_b"].reshape(lora, H, dn + shape.v_head).astype(dtype)
-        q_lat = jnp.einsum(
-            "bhd,lhd->bhl", q[..., :dn].astype(dtype), kv_b[..., :dn],
-            preferred_element_type=F32,
-        )
-        scores = jnp.einsum(
-            "bhl,bpl->bhp",
-            jnp.concatenate([q_lat, q[..., dn:]], axis=-1).astype(dtype), cache,
-            preferred_element_type=F32,
-        ) / math.sqrt(dn + shape.qk_rope)
-        mask = jnp.arange(cache.shape[1])[None, :] <= state["len"][:, None]
-        probs = _softmax(scores, mask[:, None, :])
-        ctx = jnp.einsum(
-            "bhp,bpl->bhl", probs.astype(dtype), cache[..., :lora],
-            preferred_element_type=F32,
-        )
-        out = jnp.einsum(
-            "bhl,lhd->bhd", ctx.astype(dtype), kv_b[..., dn:],
-            preferred_element_type=F32,
-        )
-        return (
-            _dot(out.reshape(B, -1), p["o"], dtype),
-            {"kv": cache, "len": state["len"] + 1},
-        )
-
-
-def _mla_fragment(p, x, state, done, shape: SeqShape, dtype):
-    """A fragment: keys and values materialised for the cached rows of the
-    episode in progress and the fragment's own, causal softmax within the
-    episode, in blocks of envs."""
-    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
-    T, B, _ = x.shape
-    L = state["kv"].shape[1]
-    with jax.named_scope("mla"):
-        q, latent = _mla_project(p, x, shape, dtype)
-        rows = jnp.concatenate(
-            [state["kv"], jnp.moveaxis(latent, 0, 1)], axis=1
-        )  # [B, L + T, lora + rope]
-        mask, ends = _episode_mask(done, state["len"], L)  # [B, T, L + T]
-
-        def attend(args):
-            q, rows, mask = args  # [b, T, H, dn + rope], [b, L+T, .], [b, T, L+T]
-            kv = _dot(rows[..., :lora], p["kv_b"], dtype).reshape(
-                *rows.shape[:2], H, dn + shape.v_head
-            )
-            scores = jnp.einsum(
-                "bthd,bphd->bhtp", q[..., :dn].astype(dtype),
-                kv[..., :dn].astype(dtype), preferred_element_type=F32,
-            ) + jnp.einsum(
-                "bthr,bpr->bhtp", q[..., dn:].astype(dtype), rows[..., lora:],
-                preferred_element_type=F32,
-            )
-            probs = _softmax(
-                scores / math.sqrt(dn + shape.qk_rope), mask[:, None]
-            )
-            return jnp.einsum(
-                "bhtp,bphd->bthd", probs.astype(dtype),
-                kv[..., dn:].astype(dtype), preferred_element_type=F32,
-            )
-
-        n = B // _env_block(B, H * T * (L + T))
-        out = jax.lax.map(
-            jax.checkpoint(attend),
-            tuple(
-                _to_blocks(a, 0, n) for a in (jnp.moveaxis(q, 0, 1), rows, mask)
-            ),
-        ).reshape(B, T, -1)
-        out = _dot(jnp.moveaxis(out, 0, 1), p["o"], dtype)
-
-        src, length = _cache_after(done, ends, state["len"], L)
-        cache = jnp.take_along_axis(rows, src[..., None], axis=1)
-        return out, {"kv": cache, "len": length}
-
-
 # ------------------------------------------------------------------- model
 
 
@@ -306,13 +207,7 @@ class SeqPolicy(SeqPolicyBase):
                     "o": w(n_kda, D),
                 }
             else:
-                layer["mla"] = {
-                    "q": w(D, s.mla_heads * (s.qk_nope + s.qk_rope)),
-                    "kv_a": w(D, s.kv_lora + s.qk_rope),
-                    "kv_norm": jnp.ones((s.kv_lora,), F32),
-                    "kv_b": w(s.kv_lora, s.mla_heads * (s.qk_nope + s.v_head)),
-                    "o": w(s.mla_heads * s.v_head, D),
-                }
+                layer["mla"] = mla.weights(w, D, s)
             if ffn == "dense":
                 layer["ffn"] = swiglu(s.dense_ffn)
             else:
@@ -334,8 +229,8 @@ class SeqPolicy(SeqPolicyBase):
         s, dtype = self.shape, self.compute_dtype
         if mixer == "kda":
             y, state = _kda_mixer(p, x, state, done, s, dtype)
-        elif done is None:
-            y, state = _mla_step(p, x, state, s, dtype)
+        elif done is None:  # NoPE: nothing of MLA is rotated
+            y, state = mla.step(p, x, state, s, dtype)
         else:
-            y, state = _mla_fragment(p, x, state, done, s, dtype)
+            y, state = mla.fragment(p, x, state, done, s, dtype)
         return y, state, {}

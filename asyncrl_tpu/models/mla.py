@@ -1,0 +1,180 @@
+"""Latent attention (MLA, DeepSeek-V2/V3): the layer of ``models/kimi_linear.py``
+(NoPE: no key or query dim is rotated) and of ``models/moonlight.py``
+(decoupled RoPE: the 64-dim rope half of each head's query and the one
+64-dim rope key shared by the heads are rotated at the token's position in
+its episode, ``theta`` the rotary base; ``theta=None`` rotates nothing).
+
+A layer's carry is ``{"kv" [B, L, lora + rope], "len" [B] int32}``: a row
+per position of the episode in progress, the normed latent ``c_kv`` and
+beside it the shared rope key, rotated where the model rotates (so the
+cache holds rotated rows and ``len`` is the next row's position). The
+pairs of the rotation are ``seq_common._rotate``'s rotate-half pairs.
+
+The one-token form (``step``) absorbs the up-projection ``kv_b`` into the
+query and the output and attends over the latent rows (scope ``mla_step``);
+the fragment form (``fragment``) up-projects the cached and fragment rows
+into per-head keys and values (scope ``mla_expand``) and runs a causal
+softmax within the episode (scope ``mla_attend``), in blocks of envs.
+
+``shape`` names ``mla_heads``, ``qk_nope``, ``qk_rope``, ``v_head``,
+``kv_lora`` and ``eps``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from asyncrl_tpu.models.seq_common import (
+    F32,
+    _cache_after,
+    _dot,
+    _env_block,
+    _episode_mask,
+    _rms_norm,
+    _rotate,
+    _softmax,
+    _to_blocks,
+)
+
+
+def project(p, x, pos, shape, dtype, theta=None):
+    """Queries [..., H, nope + rope] and the latent row [..., lora + rope]
+    the cache holds (normed latent, then the shared rope key), the rope
+    parts rotated at ``pos`` [...] where ``theta`` is given."""
+    q = _dot(x, p["q"], dtype).reshape(
+        *x.shape[:-1], shape.mla_heads, shape.qk_nope + shape.qk_rope
+    )
+    if theta is not None:
+        q = jnp.concatenate(
+            [q[..., : shape.qk_nope], _rotate(q[..., shape.qk_nope:], pos, theta)],
+            axis=-1,
+        )
+    kv = _dot(x, p["kv_a"], dtype)
+    c_kv = _rms_norm(kv[..., : shape.kv_lora], p["kv_norm"], shape.eps)
+    k_pe = kv[..., shape.kv_lora:]
+    if theta is not None:
+        k_pe = _rotate(k_pe[..., None, :], pos, theta)[..., 0, :]
+    return q, jnp.concatenate([c_kv, k_pe], axis=-1).astype(dtype)
+
+
+def step(p, x, state, shape, dtype, theta=None):
+    """One token: write its latent row at ``len``, attend over the rows of
+    the current episode with the up-projection absorbed into the query and
+    the output (no per-position keys or values are formed)."""
+    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
+    with jax.named_scope("mla"):
+        q, latent = project(p, x, state["len"], shape, dtype, theta)
+        B = x.shape[0]
+        cache = state["kv"].at[jnp.arange(B), state["len"]].set(latent)
+        with jax.named_scope("mla_step"):
+            kv_b = p["kv_b"].reshape(lora, H, dn + shape.v_head).astype(dtype)
+            q_lat = jnp.einsum(
+                "bhd,lhd->bhl", q[..., :dn].astype(dtype), kv_b[..., :dn],
+                preferred_element_type=F32,
+            )
+            scores = jnp.einsum(
+                "bhl,bpl->bhp",
+                jnp.concatenate([q_lat, q[..., dn:]], axis=-1).astype(dtype), cache,
+                preferred_element_type=F32,
+            ) / math.sqrt(dn + shape.qk_rope)
+            mask = jnp.arange(cache.shape[1])[None, :] <= state["len"][:, None]
+            probs = _softmax(scores, mask[:, None, :])
+            ctx = jnp.einsum(
+                "bhp,bpl->bhl", probs.astype(dtype), cache[..., :lora],
+                preferred_element_type=F32,
+            )
+            out = jnp.einsum(
+                "bhl,lhd->bhd", ctx.astype(dtype), kv_b[..., dn:],
+                preferred_element_type=F32,
+            )
+        return (
+            _dot(out.reshape(B, -1), p["o"], dtype),
+            {"kv": cache, "len": state["len"] + 1},
+        )
+
+
+def fragment(p, x, state, done, shape, dtype, theta=None):
+    """A fragment: keys and values materialised for the cached rows of the
+    episode in progress and the fragment's own, causal softmax within the
+    episode, in blocks of envs."""
+    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
+    T, B, _ = x.shape
+    L = state["kv"].shape[1]
+    with jax.named_scope("mla"):
+        if theta is None:
+            pos = None
+        else:  # a token's position: the rows of its episode before it
+            pos = jnp.sum(_episode_mask(done, state["len"], L)[0], axis=-1).T - 1
+        q, latent = project(p, x, pos, shape, dtype, theta)
+        rows = jnp.concatenate(
+            [state["kv"], jnp.moveaxis(latent, 0, 1)], axis=1
+        )  # [B, L + T, lora + rope]
+        mask, ends = _episode_mask(done, state["len"], L)  # [B, T, L + T]
+
+        def attend(args):
+            q, rows, mask = args  # [b, T, H, dn + rope], [b, L+T, .], [b, T, L+T]
+            with jax.named_scope("mla_expand"):
+                kv = _dot(rows[..., :lora], p["kv_b"], dtype).reshape(
+                    *rows.shape[:2], H, dn + shape.v_head
+                )
+            with jax.named_scope("mla_attend"):
+                scores = jnp.einsum(
+                    "bthd,bphd->bhtp", q[..., :dn].astype(dtype),
+                    kv[..., :dn].astype(dtype), preferred_element_type=F32,
+                ) + jnp.einsum(
+                    "bthr,bpr->bhtp", q[..., dn:].astype(dtype), rows[..., lora:],
+                    preferred_element_type=F32,
+                )
+                probs = _softmax(
+                    scores / math.sqrt(dn + shape.qk_rope), mask[:, None]
+                )
+                return jnp.einsum(
+                    "bhtp,bphd->bthd", probs.astype(dtype),
+                    kv[..., dn:].astype(dtype), preferred_element_type=F32,
+                )
+
+        n = B // _env_block(B, H * T * (L + T))
+        out = jax.lax.map(
+            jax.checkpoint(attend),
+            tuple(
+                _to_blocks(a, 0, n) for a in (jnp.moveaxis(q, 0, 1), rows, mask)
+            ),
+        ).reshape(B, T, -1)
+        out = _dot(jnp.moveaxis(out, 0, 1), p["o"], dtype)
+
+        src, length = _cache_after(done, ends, state["len"], L)
+        cache = jnp.take_along_axis(rows, src[..., None], axis=1)
+        return out, {"kv": cache, "len": length}
+
+
+def counters(state, done) -> dict:
+    """What ``fragment`` did from the carry ``state`` over ``done`` [T, B],
+    summed over the envs: ``mla_rows_attended`` the rows of its episode
+    each query attended, ``mla_rows_expanded`` the latent rows up-projected
+    into keys and values (every env its cache's capacity and the fragment's
+    rows), ``mla_rows_cached`` the cached rows of the episodes in progress
+    (the cached rows a fragment needs)."""
+    T, B = done.shape
+    L = state["kv"].shape[1]
+    mask, _ = _episode_mask(done, state["len"], L)
+    return {
+        "mla_rows_attended": jnp.sum(mask).astype(F32),
+        "mla_rows_expanded": jnp.asarray(B * (L + T), F32),
+        "mla_rows_cached": jnp.sum(state["len"]).astype(F32),
+    }
+
+
+def weights(w, hidden: int, shape) -> dict:
+    """A layer's seeded weights, ``w(*dims)`` the policy's seeded normal
+    (``seq_common.seeded``), the latent's norm at unit scale."""
+    H, lora = shape.mla_heads, shape.kv_lora
+    return {
+        "q": w(hidden, H * (shape.qk_nope + shape.qk_rope)),
+        "kv_a": w(hidden, lora + shape.qk_rope),
+        "kv_norm": jnp.ones((lora,), F32),
+        "kv_b": w(lora, H * (shape.qk_nope + shape.v_head)),
+        "o": w(H * shape.v_head, hidden),
+    }

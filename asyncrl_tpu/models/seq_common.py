@@ -1,5 +1,6 @@
 """What the token-level sequence policies share (``models/kimi_linear.py``,
-``models/lfm2_moe.py``, ``models/keye_moe.py``): the carry and its
+``models/lfm2_moe.py``, ``models/keye_moe.py``, ``models/moonlight.py``; the
+latent-attention mixer two of them run is ``models/mla.py``): the carry and its
 reset-on-read protocol, the trunk (embedding, layers in blocks of whole envs,
 each rematerialised), the feed-forward of a layer (dense, or the routed
 experts this chip holds, scored by sigmoid or by softmax), the blocked
@@ -381,6 +382,15 @@ class SeqPolicyBase:
         attended = [c["rows_attended"] for c in counters if "rows_attended" in c]
         if attended:  # mean rows a query attended, over the attention layers
             aux["gqa_rows_attended"] = sum(attended) / (len(attended) * T * B)
+        latent = [c for c in counters if "mla_rows_attended" in c]
+        if latent:
+            # means over the latent-attention layers: rows a query attended,
+            # and an env's rows up-projected and cached rows it needed
+            n = len(latent)
+            aux["mla_rows_attended"] = sum(
+                c["mla_rows_attended"] for c in latent) / (n * T * B)
+            for name in ("mla_rows_expanded", "mla_rows_cached"):
+                aux[name] = sum(c[name] for c in latent) / (n * B)
         sparse = [c for c in counters if "indexer_kl" in c]
         if sparse:
             # the selection's counters, means over queries and sparse layers

@@ -6,6 +6,7 @@ preset's sizes, in float32."""
 import contextlib
 import dataclasses
 import functools
+import math
 from unittest import mock
 
 import jax
@@ -19,6 +20,16 @@ from asyncrl_tpu.envs import registry
 from asyncrl_tpu.learn import learner as learner_mod
 from asyncrl_tpu.models import kimi_linear
 from asyncrl_tpu.models.networks import build_model, reset_core, settle_core
+from asyncrl_tpu.models.seq_common import (
+    F32,
+    _cache_after,
+    _dot,
+    _env_block,
+    _episode_mask,
+    _rms_norm,
+    _softmax,
+    _to_blocks,
+)
 from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops import distributions, kda, moe
 from asyncrl_tpu.rollout.anakin import actor_init, unroll
@@ -662,3 +673,133 @@ def test_only_policy_gradient_algorithms_over_the_vocabulary_build():
         build_model(CFG.replace(algo="qlearn"), env.spec)
     with pytest.raises(ValueError, match="seq_model"):
         build_model(CFG, registry.make("CartPole-v1").spec)
+
+
+# (h) the latent attention this model shares with models/moonlight.py
+# (models/mla.py): with nothing rotated it is the body this file's model had
+# before the two shared it, to the last bit. The body as it was, frozen:
+def _frozen_project(p, x, shape, dtype):
+    """Queries [..., H, nope + rope] and the latent row [..., lora + rope]
+    (normed latent, then the shared unrotated key part) the cache holds."""
+    q = _dot(x, p["q"], dtype).reshape(
+        *x.shape[:-1], shape.mla_heads, shape.qk_nope + shape.qk_rope
+    )
+    kv = _dot(x, p["kv_a"], dtype)
+    latent = jnp.concatenate([
+        _rms_norm(kv[..., : shape.kv_lora], p["kv_norm"], shape.eps),
+        kv[..., shape.kv_lora:],
+    ], axis=-1)
+    return q, latent.astype(dtype)
+
+
+def _frozen_step(p, x, state, shape, dtype):
+    """One token: write its latent row at ``len``, attend over the rows of
+    the current episode with the up-projection absorbed into the query and
+    the output (no per-position keys or values are formed)."""
+    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
+    with jax.named_scope("mla"):
+        q, latent = _frozen_project(p, x, shape, dtype)
+        B = x.shape[0]
+        cache = state["kv"].at[jnp.arange(B), state["len"]].set(latent)
+        kv_b = p["kv_b"].reshape(lora, H, dn + shape.v_head).astype(dtype)
+        q_lat = jnp.einsum(
+            "bhd,lhd->bhl", q[..., :dn].astype(dtype), kv_b[..., :dn],
+            preferred_element_type=F32,
+        )
+        scores = jnp.einsum(
+            "bhl,bpl->bhp",
+            jnp.concatenate([q_lat, q[..., dn:]], axis=-1).astype(dtype), cache,
+            preferred_element_type=F32,
+        ) / math.sqrt(dn + shape.qk_rope)
+        mask = jnp.arange(cache.shape[1])[None, :] <= state["len"][:, None]
+        probs = _softmax(scores, mask[:, None, :])
+        ctx = jnp.einsum(
+            "bhp,bpl->bhl", probs.astype(dtype), cache[..., :lora],
+            preferred_element_type=F32,
+        )
+        out = jnp.einsum(
+            "bhl,lhd->bhd", ctx.astype(dtype), kv_b[..., dn:],
+            preferred_element_type=F32,
+        )
+        return (
+            _dot(out.reshape(B, -1), p["o"], dtype),
+            {"kv": cache, "len": state["len"] + 1},
+        )
+
+
+def _frozen_fragment(p, x, state, done, shape, dtype):
+    """A fragment: keys and values materialised for the cached rows of the
+    episode in progress and the fragment's own, causal softmax within the
+    episode, in blocks of envs."""
+    H, dn, lora = shape.mla_heads, shape.qk_nope, shape.kv_lora
+    T, B, _ = x.shape
+    L = state["kv"].shape[1]
+    with jax.named_scope("mla"):
+        q, latent = _frozen_project(p, x, shape, dtype)
+        rows = jnp.concatenate(
+            [state["kv"], jnp.moveaxis(latent, 0, 1)], axis=1
+        )  # [B, L + T, lora + rope]
+        mask, ends = _episode_mask(done, state["len"], L)  # [B, T, L + T]
+
+        def attend(args):
+            q, rows, mask = args  # [b, T, H, dn + rope], [b, L+T, .], [b, T, L+T]
+            kv = _dot(rows[..., :lora], p["kv_b"], dtype).reshape(
+                *rows.shape[:2], H, dn + shape.v_head
+            )
+            scores = jnp.einsum(
+                "bthd,bphd->bhtp", q[..., :dn].astype(dtype),
+                kv[..., :dn].astype(dtype), preferred_element_type=F32,
+            ) + jnp.einsum(
+                "bthr,bpr->bhtp", q[..., dn:].astype(dtype), rows[..., lora:],
+                preferred_element_type=F32,
+            )
+            probs = _softmax(
+                scores / math.sqrt(dn + shape.qk_rope), mask[:, None]
+            )
+            return jnp.einsum(
+                "bhtp,bphd->bthd", probs.astype(dtype),
+                kv[..., dn:].astype(dtype), preferred_element_type=F32,
+            )
+
+        n = B // _env_block(B, H * T * (L + T))
+        out = jax.lax.map(
+            jax.checkpoint(attend),
+            tuple(
+                _to_blocks(a, 0, n) for a in (jnp.moveaxis(q, 0, 1), rows, mask)
+            ),
+        ).reshape(B, T, -1)
+        out = _dot(jnp.moveaxis(out, 0, 1), p["o"], dtype)
+
+        src, length = _cache_after(done, ends, state["len"], L)
+        cache = jnp.take_along_axis(rows, src[..., None], axis=1)
+        return out, {"kv": cache, "len": length}
+
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_shared_latent_attention_unrotated_is_the_frozen_body_to_the_bit(dtype):
+    from asyncrl_tpu.models import mla
+
+    s = TINY
+    D, T, B = s.hidden, 12, 3
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+    w = lambda *dims: jax.random.normal(next(keys), dims) * dims[-2] ** -0.5
+    p = {"q": w(D, s.mla_heads * (s.qk_nope + s.qk_rope)),
+         "kv_a": w(D, s.kv_lora + s.qk_rope),
+         "kv_norm": 1.0 + 0.1 * jax.random.normal(next(keys), (s.kv_lora,)),
+         "kv_b": w(s.kv_lora, s.mla_heads * (s.qk_nope + s.v_head)),
+         "o": w(s.mla_heads * s.v_head, D)}
+    x = jax.random.normal(next(keys), (T, B, D))
+    state = {"kv": jax.random.normal(
+                 next(keys), (B, s.max_positions, s.kv_lora + s.qk_rope)).astype(dtype),
+             "len": jnp.asarray([0, 7, 20], jnp.int32)}
+    done = jnp.zeros((T, B), bool).at[4, 1].set(True).at[11, 2].set(True)
+    same = lambda a, b: jax.tree.all(jax.tree.map(
+        lambda u, v: bool(jnp.array_equal(u, v)) and u.dtype == v.dtype, a, b))
+    assert same(jax.jit(lambda *a: mla.fragment(*a, s, dtype))(p, x, state, done),
+                jax.jit(lambda *a: _frozen_fragment(*a, s, dtype))(p, x, state, done))
+    assert same(jax.jit(lambda *a: mla.step(*a, s, dtype))(p, x[0], state),
+                jax.jit(lambda *a: _frozen_step(*a, s, dtype))(p, x[0], state))
+    # and the gradient through the fragment form
+    grad = lambda f: jax.jit(jax.grad(lambda p: jnp.sum(f(p, x, state, done, s, dtype)[0])))(p)
+    assert same(grad(mla.fragment), grad(_frozen_fragment))
