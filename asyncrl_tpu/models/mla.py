@@ -11,7 +11,13 @@ cache holds rotated rows and ``len`` is the next row's position). The
 pairs of the rotation are ``seq_common._rotate``'s rotate-half pairs.
 
 The one-token form (``step``) absorbs the up-projection ``kv_b`` into the
-query and the output and attends over the latent rows (scope ``mla_step``);
+query and the output and attends over the latent rows (scope ``mla_step``)
+by ``ops/latent.py latent_step``: on a TPU, at a latent of whole lane tiles
+and a capacity of whole chunks (both published shapes), a Pallas kernel that
+reads each env's rows up to ``len`` once, the whole row for the scores and
+its latent lanes for the weighted rows; elsewhere the plain lines, both
+products over the cache's whole capacity under a mask, as they were before
+the kernel. The absorbed products and the row's write stay in XLA;
 the fragment form (``fragment``) up-projects the cached and fragment rows
 into per-head keys and values (scope ``mla_expand``) and runs a causal
 softmax within the episode (scope ``mla_attend``), in blocks of envs.
@@ -38,6 +44,7 @@ from asyncrl_tpu.models.seq_common import (
     _softmax,
     _to_blocks,
 )
+from asyncrl_tpu.ops.latent import latent_step
 
 
 def project(p, x, pos, shape, dtype, theta=None):
@@ -75,16 +82,9 @@ def step(p, x, state, shape, dtype, theta=None):
                 "bhd,lhd->bhl", q[..., :dn].astype(dtype), kv_b[..., :dn],
                 preferred_element_type=F32,
             )
-            scores = jnp.einsum(
-                "bhl,bpl->bhp",
+            ctx = latent_step(
                 jnp.concatenate([q_lat, q[..., dn:]], axis=-1).astype(dtype), cache,
-                preferred_element_type=F32,
-            ) / math.sqrt(dn + shape.qk_rope)
-            mask = jnp.arange(cache.shape[1])[None, :] <= state["len"][:, None]
-            probs = _softmax(scores, mask[:, None, :])
-            ctx = jnp.einsum(
-                "bhp,bpl->bhl", probs.astype(dtype), cache[..., :lora],
-                preferred_element_type=F32,
+                state["len"], lora, dn + shape.qk_rope,
             )
             out = jnp.einsum(
                 "bhl,lhd->bhd", ctx.astype(dtype), kv_b[..., dn:],

@@ -217,6 +217,7 @@ class _ProcessRecord:
             ("grouped", "gathered", "dense"), 0)
         self._gqa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._dsa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
+        self._mla_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -268,6 +269,10 @@ class _ProcessRecord:
         with self._lock:
             self._dsa_sites[form] += 1
 
+    def count_mla_site(self, form: str) -> None:
+        with self._lock:
+            self._mla_sites[form] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -279,6 +284,7 @@ class _ProcessRecord:
                 "moe_sites": dict(self._moe_sites),
                 "gqa_sites": dict(self._gqa_sites),
                 "dsa_sites": dict(self._dsa_sites),
+                "mla_sites": dict(self._mla_sites),
             }
 
 
@@ -306,7 +312,7 @@ def process_record() -> dict[str, Any]:
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
     "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
     n}, "gqa_sites": {"step": n, "step_kernel": n}, "dsa_sites": {"step": n,
-    "step_kernel": n}}``: copies,
+    "step_kernel": n}, "mla_sites": {"step": n, "step_kernel": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -361,6 +367,17 @@ def count_dsa_site(form: str) -> None:
     ``ops/dsa.py``, once per site and trace (where the platform chose, once
     per site and program lowered), nothing on a steady call."""
     _RECORD.count_dsa_site(form)
+
+
+def count_mla_site(form: str) -> None:
+    """One one-token latent-attention site of a program (``models/mla.py
+    step``) attended the latent cache by ``ops/gqa.py``'s kernel under its
+    latent option, reading an env's rows up to ``len`` once
+    (``"step_kernel"``), or by the plain lines over the cache's whole
+    capacity (``"step"``): called by ``ops/gqa.py latent_step``, once per
+    site and trace (where the platform chose, once per site and program
+    lowered), nothing on a steady call."""
+    _RECORD.count_mla_site(form)
 
 
 def _sig(obj: Any) -> Any:

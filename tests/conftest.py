@@ -198,6 +198,10 @@ QUICK: dict[str, object] = {
     # decoupled RoPE in every layer) against its plain reference at the tiny
     # preset: forms, carry, the rope pairing, the eight shares.
     "test_moonlight.py": "all",
+    # The one-token latent attention's kernel (ops/latent.py) in the Pallas
+    # interpreter against the plain lines, every edge of a chunk, rows beyond
+    # len, the VJP, the choice of form and its counter, models/mla.py step on it.
+    "test_latent.py": "all",
     # SPMD contract passes (ISSUE 13): pure-AST; fixture corpus,
     # live-tree deletion proofs (axis rename / check_rep flip /
     # host-guarded all_gather / deleted DMA wait), cache soundness for

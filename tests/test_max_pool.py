@@ -394,3 +394,54 @@ def test_the_one_token_sparse_attention_compiles_to_the_kernel_under_the_selecti
     assert_the_cache_is_left_in_place(text, rf"bf16\[{B},8192,512\]")
     # rows beyond len stay in HBM: the heads' scores over the capacity are gone
     assert not re.findall(rf"f32\[{B},32,8192\]", text)
+
+
+def test_the_one_token_latent_attention_compiles_to_one_kernel_that_reads_the_cache_in_place(
+        one_chip):
+    """``moonlight_rl``'s attention widths and cache, one layer, a scan of
+    one-token steps as the rollout runs them: ``ops/latent.py``'s Mosaic
+    kernel named ``mla_step`` under ``/mla/mla_step/``, counted by
+    ``mla_sites``; no product over the cache's 8,192-row capacity is left,
+    and in the loop's body nothing copies a ``[16, 8192, 576]`` array or
+    lays it out again (the program's edges lay the donated cache out for
+    the loop and back, as the whole step does at the parent: its state
+    comes in with the 8,192 rows minor)."""
+    from asyncrl_tpu.models import moonlight
+
+    shape = moonlight.MoonlightShape(
+        hidden=256, vocab=512, layers=("mla+dense",),
+        mla_heads=16, qk_nope=128, qk_rope=64, v_head=128, kv_lora=512,
+        rope_theta=50000.0, dense_ffn=256, expert_ffn=32, shared_ffn=64,
+        num_experts=8, held_experts=(0, 1), top_k=2, routed_scale=2.446,
+        max_positions=8192)
+    model = moonlight.MoonlightPolicy(shape, compute_dtype=jnp.bfloat16)
+    B, T = 16, 4
+    variables, core = jax.eval_shape(
+        lambda: (model.init(jax.random.key(0)), model.initial_core(B)))
+    variables, tokens, core = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables, jax.ShapeDtypeStruct((T, B), jnp.int32), core))
+
+    def rollout(variables, tokens, core):
+        def step(core, token):
+            logits, value, core = model.apply(variables, token, core)
+            return core, (jnp.argmax(logits, axis=-1), value)
+        return jax.lax.scan(step, core, tokens)
+
+    def mla_sites():
+        return introspect.process_record()["mla_sites"]
+
+    before = mla_sites()
+    text = jax.jit(rollout, donate_argnums=2).lower(
+        variables, tokens, core).compile().as_text()
+    assert {k: v - before[k] for k, v in mla_sites().items()} == {
+        "step": 0, "step_kernel": 1}
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 1
+    (call,) = calls
+    assert re.search(r'op_name="[^"]*/mla/mla_step/[^"]*pallas_call', call), call
+    assert re.search(r"%mla_step\S* = \S+ custom-call\(", text)  # the kernel's name
+    (body,) = [c for c in re.split(r"\n\n", text) if re.search(r"%mla_step\S* = ", c)]
+    assert_the_cache_is_left_in_place(body, rf"bf16\[{B},8192,576\]")
+    # rows beyond len stay in HBM: the heads' scores over the capacity are gone
+    assert not re.findall(rf"f32\[{B},16,8192\]", text)

@@ -20,6 +20,7 @@ from asyncrl_tpu.envs import registry
 from asyncrl_tpu.learn import learner as learner_mod
 from asyncrl_tpu.models import moonlight, seq_common
 from asyncrl_tpu.models.networks import build_model, reset_core, settle_core
+from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops import distributions, moe
 from asyncrl_tpu.rollout.anakin import actor_init, unroll
 from benchmarks.reference import moonlight as reference
@@ -246,7 +247,6 @@ def test_the_eight_shares_and_the_shared_experts_once_sum_to_the_uncut_layer(N, 
     uncut = reference.expert_layer(full, x, dims)
     ids, weights = moe.route(x, full["router"], full["router_bias"], k, 2.446)
     layer = jax.jit(lambda *a: moe.held_experts(*a), static_argnums=(3, 4, 8))
-    from asyncrl_tpu.obs import introspect
     before = introspect.process_record()["moe_sites"]
     total = seq_common._swiglu(full["shared"], x, jnp.float32)  # once, on every chip
     for first in range(0, E, 8):
@@ -314,3 +314,30 @@ def test_the_step_names_the_scopes_a_profile_reads():
     assert some("/moe/", "/moe_router/") and some("/moe/", "/moe_experts/")
     components = {c for name in names for c in name.split("/")}
     assert not components & {"kda", "conv_mixer", "gqa", "dsa_index"}
+
+
+def test_the_one_token_form_lowers_to_the_kernel_a_profile_reads():
+    """Lowered for a TPU (no chip and no libtpu: the Mosaic kernel is
+    serialised here), one token at Moonlight's attention widths over its
+    8,192-row cache takes ``ops/latent.py``'s kernel: one Mosaic call named
+    ``mla_step`` under ``/mla/mla_step/`` (``mla_step_device_ms`` and
+    ``moonlight_mla_device_ms`` read that path, ``fused_vtrace_device_us``
+    another name), counted by ``mla_sites``. The compile of a scan of such
+    steps is in ``tests/test_max_pool.py``."""
+    model = moonlight.MoonlightPolicy(dataclasses.replace(
+        moonlight.SHAPES["moonlight_5l"], hidden=256, vocab=512,
+        layers=("mla+dense",), dense_ffn=256), compute_dtype=jnp.bfloat16)
+    B = 16
+    variables, core = jax.eval_shape(
+        lambda: (model.init(jax.random.key(0)), model.initial_core(B)))
+    before = introspect.process_record()["mla_sites"]
+    text = jax.jit(model.apply).trace(
+        variables, jax.ShapeDtypeStruct((B,), jnp.int32), core).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    now = introspect.process_record()["mla_sites"]
+    assert {k: now[k] - before[k] for k in now} == {"step": 0, "step_kernel": 1}
+    (call,) = [line for line in text.splitlines() if "@tpu_custom_call" in line]
+    assert 'kernel_name = "mla_step"' in call
+    (loc,) = re.findall(r"loc\((#loc\d+)\)\s*$", call)
+    name = re.search(rf"^{loc} = loc\(\"([^\"]+)\"", text, flags=re.M).group(1)
+    assert "/mla/mla_step/" in name and name.endswith("mla_step/pallas_call"), name
