@@ -468,6 +468,29 @@ moonlight_tiny = moonlight_rl.replace(
     total_env_steps=100_000, learning_rate=1e-3,
 )
 
+# granite-4.0-h-micro as a token-level policy on the same path: Mamba-2
+# state-space mixers with one NoPE grouped-query attention layer in ten, a
+# dense SwiGLU in every layer, muP multipliers and a tied head
+# (models/granite_h.py SHAPES: one whole period, layers 0-9 of 40, and an
+# eighth of the vocabulary). 16 envs x 256 tokens = 4,096 tokens an update;
+# episodes of 128-2,048 tokens, so the state, the conv tail and the cache
+# outlive fragments and episodes end inside the chunked scan's chunks. 772 M
+# parameters at 16 bytes (RMSProp, donated) -- see
+# benchmarks/configs/granite_h_rl.json.
+granite_h_rl = kimi_linear_rl.replace(
+    seq_model="granite_h_10l",
+    token_task=(12544, 128, 2048, 16, 64),
+    num_envs=16,
+    unroll_len=256,
+)
+# Episodes of 12-32 tokens over fragments of 16 in chunks of 8: the state
+# outlives fragments and resets inside chunks.
+granite_h_tiny = granite_h_rl.replace(
+    seq_model="granite_h_tiny", token_task=(64, 12, 32, 1, 2),
+    num_envs=8, unroll_len=16,
+    total_env_steps=100_000, learning_rate=1e-3,
+)
+
 PRESETS: dict[str, Config] = {
     "cartpole_a3c": cartpole_a3c,
     "cartpole_a3c_cpu": cartpole_a3c_cpu,
@@ -504,6 +527,8 @@ PRESETS: dict[str, Config] = {
     "keye_moe_tiny": keye_moe_tiny,
     "moonlight_rl": moonlight_rl,
     "moonlight_tiny": moonlight_tiny,
+    "granite_h_rl": granite_h_rl,
+    "granite_h_tiny": granite_h_tiny,
 }
 
 

@@ -45,6 +45,7 @@ from asyncrl_tpu.models.seq_common import (
     F32,
     SeqCore,
     SeqPolicyBase,
+    TrunkScales,
     _cache_after,
     _dot,
     _episode_mask,
@@ -56,7 +57,7 @@ from asyncrl_tpu.ops import dsa
 
 
 @dataclasses.dataclass(frozen=True)
-class KeyeShape:
+class KeyeShape(TrunkScales):
     """Published widths and the cut: what ``Config.seq_model`` names."""
 
     hidden: int

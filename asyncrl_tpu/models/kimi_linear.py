@@ -36,6 +36,7 @@ from asyncrl_tpu.models.seq_common import (  # noqa: F401  (SeqCore: the carry's
     F32,
     SeqCore,
     SeqPolicyBase,
+    TrunkScales,
     _dot,
     _rms_norm,
     _short_conv,
@@ -46,7 +47,7 @@ from asyncrl_tpu.ops import kda
 
 
 @dataclasses.dataclass(frozen=True)
-class SeqShape:
+class SeqShape(TrunkScales):
     """Published widths and the cut: what ``Config.seq_model`` names."""
 
     hidden: int

@@ -39,6 +39,7 @@ from asyncrl_tpu.models.seq_common import (
     F32,
     SeqCore,
     SeqPolicyBase,
+    TrunkScales,
     _cache_after,
     _dot,
     _env_block,
@@ -54,7 +55,7 @@ from asyncrl_tpu.ops.gqa import gqa_step
 
 
 @dataclasses.dataclass(frozen=True)
-class Lfm2Shape:
+class Lfm2Shape(TrunkScales):
     """Published widths and the cut: what ``Config.seq_model`` names."""
 
     hidden: int

@@ -38,11 +38,11 @@ import jax
 import jax.numpy as jnp
 
 from asyncrl_tpu.models import mla
-from asyncrl_tpu.models.seq_common import F32, SeqCore, SeqPolicyBase, seeded
+from asyncrl_tpu.models.seq_common import F32, SeqCore, SeqPolicyBase, TrunkScales, seeded
 
 
 @dataclasses.dataclass(frozen=True)
-class MoonlightShape:
+class MoonlightShape(TrunkScales):
     """Published widths and the cut: what ``Config.seq_model`` names."""
 
     hidden: int

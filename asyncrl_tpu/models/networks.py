@@ -334,13 +334,15 @@ def build_model(config, env_spec):
         jnp.bfloat16 if config.precision == "bf16_matmul" else jnp.float32
     )
     if config.seq_model:
-        from asyncrl_tpu.models import keye_moe, kimi_linear, lfm2_moe, moonlight
+        from asyncrl_tpu.models import (
+            granite_h, keye_moe, kimi_linear, lfm2_moe, moonlight)
 
         # each sequence policy keeps the shape records it builds
         policies = ((kimi_linear.SHAPES, kimi_linear.SeqPolicy),
                     (lfm2_moe.SHAPES, lfm2_moe.Lfm2Policy),
                     (keye_moe.SHAPES, keye_moe.KeyePolicy),
-                    (moonlight.SHAPES, moonlight.MoonlightPolicy))
+                    (moonlight.SHAPES, moonlight.MoonlightPolicy),
+                    (granite_h.SHAPES, granite_h.GraniteHPolicy))
         for shapes, policy in policies:
             if config.seq_model in shapes:
                 shape = shapes[config.seq_model]

@@ -1,13 +1,18 @@
 """What the token-level sequence policies share (``models/kimi_linear.py``,
-``models/lfm2_moe.py``, ``models/keye_moe.py``, ``models/moonlight.py``; the
-latent-attention mixer two of them run is ``models/mla.py``): the carry and its
-reset-on-read protocol, the trunk (embedding, layers in blocks of whole envs,
-each rematerialised), the feed-forward of a layer (dense, or the routed
-experts this chip holds, scored by sigmoid or by softmax), the blocked
-output head, the value head, the fragment form's counters and the model's
-own loss term, and the pieces a mixer is made of (norms, the boundary-aware
-short conv, the rotation and the grouped-query projection, the episode mask
-and the cache a fragment leaves).
+``models/lfm2_moe.py``, ``models/keye_moe.py``, ``models/moonlight.py``,
+``models/granite_h.py``; the latent-attention mixer two of them run is
+``models/mla.py``): the carry and its reset-on-read protocol, the trunk
+(embedding, layers in blocks of whole envs, each rematerialised; a shape
+scales the embedding, each residual branch and the logits by its
+``embedding_multiplier``, ``residual_multiplier`` and ``logits_scaling``,
+1 on a record built on ``TrunkScales``), the feed-forward of a layer
+(dense, or the routed experts this chip holds, scored by sigmoid or by
+softmax), the
+blocked output head (``params["head"]``, or the embedding where there is
+none: a tied head), the value head, the fragment form's counters and the
+model's own loss term, and the pieces a mixer is made of (norms, the
+boundary-aware short conv, the rotation and the grouped-query projection,
+the episode mask and the cache a fragment leaves).
 
 A policy is ``SeqPolicyBase`` with a shape record of its own and three
 methods: ``initial_core``, ``init`` and ``_mixer``. One function in two
@@ -26,7 +31,7 @@ forms (``docs/ARCHITECTURE.md`` "Sequence policy"):
   returns the logits instead (tests).
 
 The carry (``SeqCore``) is a tuple with one entry per layer, every leaf with
-the env axis first, and five kinds of state live in it side by side:
+the env axis first, and six kinds of state live in it side by side:
 
 - a KDA layer's ``{"S" [B, H, dk, dv] float32, "conv" [B, W-1, 3 H dk],
   "fresh" [B] bool}``;
@@ -38,16 +43,20 @@ the env axis first, and five kinds of state live in it side by side:
   "len" [B]}``: the same cache and a third kind of row beside it, the
   indexer's key of each position (``ops/dsa.py``). Every array of rows a
   cache holds is emptied by the one ``len`` and re-gathered by the one
-  ``_cache_after``.
+  ``_cache_after``;
+- a Mamba-2 layer's ``{"S" [B, H, P, N] float32, "conv" [B, W-1, H P + 2 N]
+  float32, "fresh" [B] bool}``: a state-space state of a fixed size
+  whatever the episode's length (``ops/ssd.py``), reset as KDA's is.
 
 A model's own loss term (the sparse-attention indexer's KL term, which is
 all that trains the indexer) leaves the fragment form in ``aux`` under
 ``MODEL_LOSS``; ``learn/learner.py`` adds it to the algorithm's loss.
 
 A reset decides by what a layer's state holds: a conv tail is zeroed, a
-cache is emptied by its ``len`` (the rows stay), and a KDA state is not
-passed over at all (134 MB a layer at the published widths): the reset sets
-``fresh``, and the next read of ``S``, in either form, takes zero there.
+cache is emptied by its ``len`` (the rows stay), and a KDA or Mamba-2 state
+is not passed over at all (134 MB a layer at KDA's published widths): the
+reset sets ``fresh``, and the next read of ``S``, in either form, takes zero
+there.
 ``settle()`` spends the pending resets; a carry that leaves
 ``rollout.anakin.unroll`` or the fragment form is settled.
 """
@@ -55,6 +64,7 @@ passed over at all (134 MB a layer at the published widths): the reset sets
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -170,14 +180,25 @@ def _rotate(x, pos, theta: float):
 
 def _gqa_project(p, x, pos, shape, dtype):
     """Queries [..., H, dh] and the key and value rows [..., Hkv * dh] the
-    cache holds: projected, q and k normed over each head, then rotated at
-    ``pos`` [...]. ``shape``: ``heads``, ``kv_heads``, ``head_dim``, ``eps``
-    and ``rope_theta``."""
+    cache holds: projected, q and k normed over each head where ``p`` has
+    the norms, then rotated at ``pos`` [...] where ``shape.rope_theta`` is
+    not None (None: NoPE). ``shape``: ``heads``, ``kv_heads``, ``head_dim``,
+    ``eps``, ``rope_theta`` and ``attention_multiplier``, the softmax's scale
+    where it is not None (``TrunkScales``): folded into the queries, since
+    the attention divides its scores by sqrt(dh)."""
     H, G, dh = shape.heads, shape.kv_heads, shape.head_dim
+
+    def norm_and_rotate(t, norm):
+        if norm in p:
+            t = _rms_norm(t, p[norm], shape.eps)
+        return t if shape.rope_theta is None else _rotate(t, pos, shape.rope_theta)
+
     q = _dot(x, p["q"], dtype).reshape(*x.shape[:-1], H, dh)
     k = _dot(x, p["k"], dtype).reshape(*x.shape[:-1], G, dh)
-    q = _rotate(_rms_norm(q, p["q_norm"], shape.eps), pos, shape.rope_theta)
-    k = _rotate(_rms_norm(k, p["k_norm"], shape.eps), pos, shape.rope_theta)
+    q = norm_and_rotate(q, "q_norm")
+    k = norm_and_rotate(k, "k_norm")
+    if shape.attention_multiplier is not None:
+        q = q * (shape.attention_multiplier * math.sqrt(dh))
     return (q, k.reshape(*x.shape[:-1], G * dh).astype(dtype),
             _dot(x, p["v"], dtype).astype(dtype))
 
@@ -265,6 +286,18 @@ def seeded(key, n: int):
 # ------------------------------------------------------------------- model
 
 
+class TrunkScales:
+    """The trunk's multipliers at their neutral values, the base of a shape
+    record that states none of them: class attributes, not dataclass fields,
+    so such a record's fields are what they were. A record that states them
+    (``models/granite_h.py``) declares the four as fields instead."""
+
+    embedding_multiplier = 1
+    residual_multiplier = 1
+    logits_scaling = 1
+    attention_multiplier = None  # the softmax's scale is 1/sqrt(head_dim)
+
+
 @dataclasses.dataclass(frozen=True)
 class SeqPolicyBase:
     """See the module docstring. Not a flax module: ``init`` / ``apply``
@@ -313,16 +346,24 @@ class SeqPolicyBase:
         mixer, ffn = kind.split("+")
         x = _rms_norm(h, p["norm_mixer"], s.eps)
         y, state, seen = self._mixer(p[mixer], mixer, x, state, done)
-        h = h + y
+        h = h + self._residual(y)
         x = _rms_norm(h, p["norm_ffn"], s.eps)
         y, counted = self._ffn(p["ffn"], ffn, x.reshape(-1, s.hidden))
-        return h + y.reshape(h.shape), state, {**seen, **counted}
+        return h + self._residual(y.reshape(h.shape)), state, {**seen, **counted}
+
+    def _residual(self, y):
+        """What a layer's branch adds to the residual stream: ``y`` scaled by
+        the shape's ``residual_multiplier``."""
+        r = self.shape.residual_multiplier
+        return y if r == 1 else r * y
 
     def _trunk(self, params, tokens, core, done):
         """Embedding and layers -> (final normed hidden, carry, each
         layer's counters summed over its blocks)."""
         s = self.shape
         h = jnp.take(params["embed"], tokens, axis=0)
+        if s.embedding_multiplier != 1:
+            h = h * s.embedding_multiplier
         states, counters = [], []
         for i, kind in enumerate(s.layers):
             p, state = params[f"layer_{i}"], core.layers[i]
@@ -354,10 +395,25 @@ class SeqPolicyBase:
         v = _dot(h, params["value"]["kernel"], self.compute_dtype)
         return v[..., 0] + params["value"]["bias"][0]
 
+    def _logits(self, params, h):
+        """The output head: ``params["head"]``, or where there is none the
+        embedding (a tied head); the logits divided by the shape's
+        ``logits_scaling``."""
+        if "head" in params:
+            logits = _dot(h, params["head"], self.compute_dtype)
+        else:
+            logits = jnp.einsum(
+                "...d,vd->...v", h.astype(self.compute_dtype),
+                params["embed"].astype(self.compute_dtype),
+                preferred_element_type=F32,
+            )
+        scale = self.shape.logits_scaling
+        return logits if scale == 1 else logits / scale
+
     def step(self, params, tokens, core):
         h, core, _ = self._trunk(params, tokens, core, None)
         with jax.named_scope("lm_head"):
-            logits = _dot(h, params["head"], self.compute_dtype)
+            logits = self._logits(params, h)
         return logits, self._value(params, h), core
 
     def fragment(self, params, tokens, done, core, actions=None):
@@ -365,20 +421,24 @@ class SeqPolicyBase:
         h, core, counters = self._trunk(params, tokens, core, done)
         values = self._value(params, h)
         core = core.reset(done[-1]).settle()
-        # [expert layers, held]
-        loads = jnp.stack([c["load"] for c in counters if "load" in c]).astype(F32)
-        aux = {
-            "moe_load_max": jnp.max(loads),
-            "moe_load_mean": jnp.mean(loads),
-            "moe_local_frac": jnp.sum(loads) / (
-                loads.shape[0] * T * B * self.shape.top_k
-            ),
-            "episode_resets": jnp.sum(done.astype(F32)),
+        aux = {}
+        # [expert layers, held]; a model with no expert layer has no loads
+        experts = any("load" in c for c in counters)
+        if experts:
+            loads = jnp.stack([c["load"] for c in counters if "load" in c]).astype(F32)
+            aux.update(
+                moe_load_max=jnp.max(loads),
+                moe_load_mean=jnp.mean(loads),
+                moe_local_frac=jnp.sum(loads) / (
+                    loads.shape[0] * T * B * self.shape.top_k
+                ),
+            )
+        aux["episode_resets"] = jnp.sum(done.astype(F32))
+        if experts:
             # what the expert layers had to compute, and how many of the
             # update's blocks took the dense side to do it
-            "moe_local_assignments": jnp.sum(loads),
-            "moe_dense_blocks": sum(c["dense"] for c in counters if "dense" in c),
-        }
+            aux["moe_local_assignments"] = jnp.sum(loads)
+            aux["moe_dense_blocks"] = sum(c["dense"] for c in counters if "dense" in c)
         attended = [c["rows_attended"] for c in counters if "rows_attended" in c]
         if attended:  # mean rows a query attended, over the attention layers
             aux["gqa_rows_attended"] = sum(attended) / (len(attended) * T * B)
@@ -391,6 +451,11 @@ class SeqPolicyBase:
                 c["mla_rows_attended"] for c in latent) / (n * T * B)
             for name in ("mla_rows_expanded", "mla_rows_cached"):
                 aux[name] = sum(c[name] for c in latent) / (n * B)
+        scans = [c for c in counters if "ssd_chunk_resets" in c]
+        if scans:  # boundaries the chunked scans masked, a chunk (layers alike)
+            aux["ssd_chunk_resets"] = jax.lax.stop_gradient(
+                sum(c["ssd_chunk_resets"] for c in scans)
+                / sum(c["ssd_chunks"] for c in scans))
         sparse = [c for c in counters if "indexer_kl" in c]
         if sparse:
             # the selection's counters, means over queries and sparse layers
@@ -404,7 +469,7 @@ class SeqPolicyBase:
             aux["indexer_kl"] = jax.lax.stop_gradient(aux[MODEL_LOSS])
         if actions is None:
             with jax.named_scope("lm_head"):
-                return _dot(h, params["head"], self.compute_dtype), values, core, aux
+                return self._logits(params, h), values, core, aux
         n = T * B
         b = _env_block(n, 1, 2048)
 
@@ -412,7 +477,7 @@ class SeqPolicyBase:
             # the scope inside the mapped body: its backward ops keep it
             with jax.named_scope("lm_head"):
                 h, a = args
-                logits = _dot(h, params["head"], self.compute_dtype)
+                logits = self._logits(params, h)
                 logp = jax.nn.log_softmax(logits, axis=-1)
                 taken = jnp.take_along_axis(logp, a[:, None], axis=-1)[:, 0]
                 return taken, -jnp.sum(jnp.exp(logp) * logp, axis=-1)

@@ -218,6 +218,7 @@ class _ProcessRecord:
         self._gqa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._dsa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._mla_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
+        self._ssd_sites = {"step": 0, "chunk": 0}  # guarded-by: _lock
         self._listening = False  # guarded-by: _lock
 
     def listen(self) -> None:
@@ -273,6 +274,10 @@ class _ProcessRecord:
         with self._lock:
             self._mla_sites[form] += 1
 
+    def count_ssd_site(self, form: str) -> None:
+        with self._lock:
+            self._ssd_sites[form] += 1
+
     def snapshot(self) -> dict[str, Any]:
         with self._lock:
             return {
@@ -285,6 +290,7 @@ class _ProcessRecord:
                 "gqa_sites": dict(self._gqa_sites),
                 "dsa_sites": dict(self._dsa_sites),
                 "mla_sites": dict(self._mla_sites),
+                "ssd_sites": dict(self._ssd_sites),
             }
 
 
@@ -312,7 +318,8 @@ def process_record() -> dict[str, Any]:
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
     "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
     n}, "gqa_sites": {"step": n, "step_kernel": n}, "dsa_sites": {"step": n,
-    "step_kernel": n}, "mla_sites": {"step": n, "step_kernel": n}}``: copies,
+    "step_kernel": n}, "mla_sites": {"step": n, "step_kernel": n},
+    "ssd_sites": {"step": n, "chunk": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -378,6 +385,14 @@ def count_mla_site(form: str) -> None:
     site and trace (where the platform chose, once per site and program
     lowered), nothing on a steady call."""
     _RECORD.count_mla_site(form)
+
+
+def count_ssd_site(form: str) -> None:
+    """One state-space site of a program being lowered took the one-token
+    recurrence (``"step"``) or the chunked fragment form (``"chunk"``):
+    called by ``ops/ssd.py``, once per site and program lowered, nothing on
+    a steady call."""
+    _RECORD.count_ssd_site(form)
 
 
 def _sig(obj: Any) -> Any:
