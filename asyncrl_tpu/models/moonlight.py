@@ -146,4 +146,4 @@ class MoonlightPolicy(SeqPolicyBase):
         if done is None:
             return (*mla.step(p, x, state, s, dtype, s.rope_theta), {})
         y, after = mla.fragment(p, x, state, done, s, dtype, s.rope_theta)
-        return y, after, jax.lax.stop_gradient(mla.counters(state, done))
+        return y, after, jax.lax.stop_gradient(mla.counters(state, done, s))

@@ -445,11 +445,12 @@ class SeqPolicyBase:
         latent = [c for c in counters if "mla_rows_attended" in c]
         if latent:
             # means over the latent-attention layers: rows a query attended,
-            # and an env's rows up-projected and cached rows it needed
+            # and an env's rows handed to the fragment form, rows computed
+            # (its rung of the cache) and cached rows it needed
             n = len(latent)
             aux["mla_rows_attended"] = sum(
                 c["mla_rows_attended"] for c in latent) / (n * T * B)
-            for name in ("mla_rows_expanded", "mla_rows_cached"):
+            for name in ("mla_rows_expanded", "mla_rows_computed", "mla_rows_cached"):
                 aux[name] = sum(c[name] for c in latent) / (n * B)
         scans = [c for c in counters if "ssd_chunk_resets" in c]
         if scans:  # boundaries the chunked scans masked, a chunk (layers alike)

@@ -264,7 +264,8 @@ def _block(q, qi, w, mask, keys, values, ki, top_k, scale, groups,
 def _rungs(L: int, T: int) -> tuple[int, ...]:
     """The cached rows an env's blocks may be computed over: an eighth, a
     quarter, a half or all of the cache's ``L`` (rounded up); ``L`` alone
-    where the cache is no longer than the fragment. Few rungs, as each is
+    where the cache is no longer than the fragment (``models/mla.py
+    fragment`` climbs the same ladder). Few rungs, as each is
     compiled code in every layer and pass (eight, in eighths, made Keye's
     step twice the executable and its set-up ~15 s longer), and none
     between a half and the whole (there, eighths' rungs of 6,144 and 7,168

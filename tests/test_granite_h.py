@@ -348,7 +348,7 @@ BEFORE = {
     "keye_moe_tiny.step": "1daf3b4dca5f70d43ee511e31c389753c80eca6c54701893b378268d4ad75f81",
     "keye_moe_tiny.fragment": "6eae1cf89fc0aea720e2341216bba19ddb5699e20a9e55699c2d41f4685f2268",
     "moonlight_tiny.step": "36571948f922d327f34b76535f734797be08ed9e85ed5ead505dd47f5e3b3e53",
-    "moonlight_tiny.fragment": "4135c94a826a32f802623510274b79b8c2ce4d2192296d41847691f64b21a3ff",
+    "moonlight_tiny.fragment": "35ac1d8aa7a21e5b54730a2697efa584058483b2a0f99c9f5b27388fad58d266",
 }
 
 

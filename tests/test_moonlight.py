@@ -8,6 +8,7 @@ and positions restart inside them), in float32."""
 
 import dataclasses
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ from asyncrl_tpu import make_agent
 from asyncrl_tpu.configs import presets
 from asyncrl_tpu.envs import registry
 from asyncrl_tpu.learn import learner as learner_mod
-from asyncrl_tpu.models import moonlight, seq_common
+from asyncrl_tpu.models import mla, moonlight, seq_common
 from asyncrl_tpu.models.networks import build_model, reset_core, settle_core
 from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops import distributions, moe
@@ -341,3 +342,182 @@ def test_the_one_token_form_lowers_to_the_kernel_a_profile_reads():
     (loc,) = re.findall(r"loc\((#loc\d+)\)\s*$", call)
     name = re.search(rf"^{loc} = loc\(\"([^\"]+)\"", text, flags=re.M).group(1)
     assert "/mla/mla_step/" in name and name.endswith("mla_step/pallas_call"), name
+
+
+# (g) the fragment form over each block's rung of the cache (models/mla.py):
+# a cache longer than the fragment is computed up to the smallest of an
+# eighth, a quarter, a half or all of its rows that holds what the block's
+# mask admits, and the fragment's rows
+def ladder_case(lengths, boundaries=(), L=TINY.max_positions, T=16, seed=20):
+    """A layer's weights, a fragment of ``T`` tokens and a carry whose envs
+    hold ``lengths`` cached rows, an episode ending at each ``(t, env)``
+    of ``boundaries``."""
+    s = dataclasses.replace(TINY, max_positions=L)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    w = lambda *dims: jax.random.normal(next(keys), dims) * dims[-2] ** -0.5
+    B = len(lengths)
+    x = jax.random.normal(next(keys), (T, B, s.hidden))
+    state = {"kv": jax.random.normal(next(keys), (B, L, s.kv_lora + s.qk_rope)),
+             "len": jnp.asarray(lengths, jnp.int32)}
+    done = jnp.zeros((T, B), bool)
+    for t, b in boundaries:
+        done = done.at[t, b].set(True)
+    return s, mla.weights(w, s.hidden, s), x, state, done
+
+
+def one_rung(L, T):
+    """The ladder of a cache no longer than the fragment: every row, the
+    form as it was before the ladder."""
+    return (L,)
+
+
+# Envs holding 0 rows (an empty cache), 3 and 4 (an eighth of 32, the edge
+# included), 7 (a quarter), 12 and 9 (a half; the 9 with an episode ending
+# inside the fragment) and 30 (the whole), and one env whose episode ends
+# on the fragment's first token.
+LADDER_LENGTHS = [0, 3, 4, 7, 12, 9, 30, 16]
+LADDER_RUNGS = [4, 4, 4, 8, 16, 16, 32, 16]
+LADDER_BOUNDARIES = ((5, 5), (11, 5), (0, 7))
+
+
+@pytest.mark.parametrize("theta", [TINY.rope_theta, None])
+@pytest.mark.parametrize("per_env", [True, False])
+def test_the_fragment_form_over_its_rung_is_the_whole_rows_form(theta, per_env):
+    """Outputs, carry, the gradient of every operand and the rows counted,
+    with one env a block (each env on its own rung: all four of them) and
+    with the preset's one block of all envs (the longest env's rung)."""
+    lengths = LADDER_LENGTHS if per_env else [0, 3, 9]
+    s, p, x, state, done = ladder_case(
+        lengths, LADDER_BOUNDARIES if per_env else ((4, 2),))
+    T, B = done.shape
+    assert mla._rungs(s.max_positions, T) == (4, 8, 16, 32)
+    blocks = (lambda B, T, L, heads: B) if per_env else mla._blocks
+    form = lambda *a: mla.fragment(*a, s, jnp.float32, theta)
+
+    def run(ladder):
+        with mock.patch.object(mla, "_rungs", ladder), \
+                mock.patch.object(mla, "_blocks", blocks):
+            mix = jax.random.normal(jax.random.PRNGKey(21), (T, B, s.hidden))
+            out, after = jax.jit(form)(p, x, state, done)
+            grads = jax.jit(jax.grad(
+                lambda p, x, kv: jnp.sum(form(p, x, {**state, "kv": kv}, done)[0] * mix),
+                argnums=(0, 1, 2)))(p, x, state["kv"])
+            return out, after, grads, jax.jit(
+                lambda st, d: mla.counters(st, d, s))(state, done)
+
+    out, after, grads, counted = run(mla._rungs)
+    ref_out, ref_after, ref_grads, ref_counted = run(one_rung)
+    np.testing.assert_allclose(out, ref_out, atol=1e-5 * float(jnp.max(jnp.abs(ref_out))))
+    for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(ref_after)):
+        np.testing.assert_array_equal(a, b)
+    for path, g in jax.tree_util.tree_flatten_with_path(ref_grads)[0]:
+        got = dict(jax.tree_util.tree_flatten_with_path(grads)[0])[path]
+        np.testing.assert_allclose(got, g, atol=1e-5 * float(jnp.max(jnp.abs(g))) + 1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+    # the rows past a rung get no gradient, as they got none before
+    dkv = np.asarray(grads[2])
+    for b, n in enumerate(lengths):
+        assert not np.any(dkv[b, n:])
+    rungs = LADDER_RUNGS if per_env else [16] * B
+    assert float(counted["mla_rows_computed"]) == sum(c + T for c in rungs)
+    assert float(ref_counted["mla_rows_computed"]) == B * (s.max_positions + T)
+    assert float(counted["mla_rows_expanded"]) == B * (s.max_positions + T)
+    for k in ("mla_rows_attended", "mla_rows_cached"):
+        assert float(counted[k]) == float(ref_counted[k])
+
+
+def test_the_rung_follows_the_blocks_mask():
+    """The rung of a block is the smallest that holds the cached rows its
+    mask admits for any query, whichever env and query admits them: two
+    envs a block, each block landing on a rung's edge or past it."""
+    L, T = 32, 8
+    lengths = jnp.asarray([0, 0, 3, 4, 5, 0, 16, 2, 0, 17, 32, 1], jnp.int32)
+    done = jnp.zeros((T, 12), bool).at[0, 9].set(True)  # ends on its first token
+    mask, _ = seq_common._episode_mask(done, lengths, L)
+    rungs = mla._rungs(L, T)
+    got = mla._rung_index(mask.reshape(6, 2, T, L + T), rungs)
+    np.testing.assert_array_equal(got, [0, 0, 1, 2, 3, 3])
+    # a mask that admits no cached row for any query needs the first rung,
+    # and a ladder of one rung is always its rung
+    np.testing.assert_array_equal(
+        mla._rung_index(mask.at[..., :L].set(False).reshape(6, 2, T, L + T), rungs), 0)
+    np.testing.assert_array_equal(mla._rung_index(mask.reshape(6, 2, T, L + T), (L,)), 0)
+    # only rows the mask admits count: a cache row past len is not a row held
+    wide = mask.at[4, :, 20].set(True)  # env 4 (5 rows) with row 20 admitted
+    np.testing.assert_array_equal(
+        mla._rung_index(wide.reshape(6, 2, T, L + T), rungs), [0, 0, 3, 2, 3, 3])
+
+
+def test_the_update_counts_the_rows_each_block_computed(policy, fragments):
+    """``aux["mla_rows_computed"]``: the mean over envs and layers of the
+    rung + T of each env's block, from the fragment's masks as the trunk
+    blocks its envs; no more than the rows the form is handed. The first
+    fragment starts from empty caches (the first rung), the last from
+    caches of up to 18 rows (the whole)."""
+    _, model, variables = policy
+    T, B = fragments[0].done.shape
+    L = TINY.max_positions
+    rungs = np.asarray(mla._rungs(L, T))
+    b = seq_common._env_block(B, T, TINY.block_tokens)  # envs a layer block
+    assert mla._blocks(b, T, L, TINY.mla_heads) == 1  # one attention block in it
+    means = []
+    for r in fragments:
+        aux = model.apply(variables, r.obs, r.done, r.init_core, method="fragment")[3]
+        computed = []
+        for layer in r.init_core.layers:
+            mask = np.asarray(seq_common._episode_mask(r.done, layer["len"], L)[0])
+            for block in mask.reshape(B // b, b, T, L + T):
+                held = max([i + 1 for i in range(L) if block[..., i].any()], default=0)
+                computed += [rungs[np.argmax(rungs >= held)] + T] * b
+        assert float(aux["mla_rows_computed"]) == pytest.approx(float(np.mean(computed)))
+        assert float(aux["mla_rows_computed"]) <= float(aux["mla_rows_expanded"]) == L + T
+        means.append(float(aux["mla_rows_computed"]))
+    assert means[0] == rungs[0] + T and means[-1] == L + T
+
+
+def test_the_rungs_are_traced_once_a_program_not_once_a_layer(policy, fragments):
+    """The branch functions are shared by the layers: lowering the learner's
+    loss through three latent-attention layers traces each rung's body once
+    (four traces, not twelve), from four cached functions."""
+    _, model, variables = policy
+    r = fragments[-1]
+    traced = []
+    body = mla._attend
+
+    def counting(*a, **k):
+        traced.append(a[1].shape[1])  # the rows a trace computes over
+        return body(*a, **k)
+
+    mla._branch.cache_clear()
+    with mock.patch.object(mla, "_attend", counting):
+        jax.jit(jax.value_and_grad(
+            lambda v: learner_mod._algo_loss(CFG, model.apply, v, r)[0])).lower(variables)
+    T = r.obs.shape[0]
+    assert sorted(traced) == [c + T for c in mla._rungs(TINY.max_positions, T)]
+    info = mla._branch.cache_info()
+    assert (info.currsize, info.misses, info.hits) == (4, 4, 4 * (len(TINY.layers) - 1))
+
+
+def test_the_ladders_residuals_are_the_whole_rows():
+    """What the fragment form's VJP keeps: no array whose shape follows a
+    rung (the switch would keep every rung's, zero-filled), and no more
+    bytes than the whole rows' form keeps but the rungs' indices. Shapes
+    with no size in common with a rung: 40 cached rows, rungs of 5, 10, 20
+    and 40, a fragment of 8."""
+    s, p, x, state, done = ladder_case([0, 6, 40], ((3, 1),), L=40, T=8)
+    T = done.shape[0]
+    rungs = mla._rungs(40, T)
+    assert rungs == (5, 10, 20, 40)
+    sliced = {n for c in rungs[:-1] for n in (c, c + T)}
+
+    def kept(ladder):
+        with mock.patch.object(mla, "_rungs", ladder):
+            _, vjp = jax.vjp(lambda p, x, kv: mla.fragment(
+                p, x, {**state, "kv": kv}, done, s, jnp.float32, s.rope_theta)[0],
+                p, x, state["kv"])
+        return [np.asarray(a) for a in jax.tree.leaves(vjp)]
+
+    mine, whole = kept(mla._rungs), kept(one_rung)
+    assert not [a.shape for a in mine if sliced & set(a.shape)]
+    ints = sum(a.nbytes for a in mine if a.dtype == np.int32)
+    assert sum(a.nbytes for a in mine) - ints <= sum(a.nbytes for a in whole)
