@@ -321,9 +321,12 @@ class SeqPolicyBase:
         (y, the layer's state, counters {name: float32 scalar})."""
         raise NotImplementedError
 
-    def _ffn(self, p, kind, x):
+    def _ffn(self, p, kind, x, steps=None):
         """The layer's feed-forward on rows ``x`` [N, D]: (y, counters: the
-        held experts' loads and whether the block was computed densely)."""
+        held experts' loads and whether the block was computed densely;
+        where ``steps`` is given, ``x``'s rows are [steps, envs] and
+        ``step_hits`` [steps, held] counts the tokens of each step that
+        chose each held expert)."""
         s, dtype = self.shape, self.compute_dtype
         if kind == "dense":
             return _swiglu(p, x, dtype), {}
@@ -339,16 +342,24 @@ class SeqPolicyBase:
             )
             if "shared" in p:
                 y = y + _swiglu(p["shared"], x, dtype)
-            return y, {"load": load, "dense": dense.astype(F32)}
+            counted = {"load": load, "dense": dense.astype(F32)}
+            if steps is not None:
+                hit = jnp.any(ids[:, :, None] == jnp.asarray(
+                    s.held_experts, ids.dtype), axis=1)
+                counted["step_hits"] = jnp.sum(
+                    hit.reshape(steps, -1, hit.shape[-1]), axis=1, dtype=jnp.int32)
+            return y, counted
 
-    def _layer(self, p, kind, h, state, done):
+    def _layer(self, p, kind, h, state, done, steps=None):
+        """``steps``: where given, ``h`` is [steps, envs, D] and the expert
+        layer counts ``step_hits`` (``_ffn``)."""
         s = self.shape
         mixer, ffn = kind.split("+")
         x = _rms_norm(h, p["norm_mixer"], s.eps)
         y, state, seen = self._mixer(p[mixer], mixer, x, state, done)
         h = h + self._residual(y)
         x = _rms_norm(h, p["norm_ffn"], s.eps)
-        y, counted = self._ffn(p["ffn"], ffn, x.reshape(-1, s.hidden))
+        y, counted = self._ffn(p["ffn"], ffn, x.reshape(-1, s.hidden), steps)
         return h + self._residual(y.reshape(h.shape)), state, {**seen, **counted}
 
     def _residual(self, y):
@@ -372,12 +383,16 @@ class SeqPolicyBase:
             else:
                 # in blocks of whole envs, each rematerialised in the
                 # backward pass: what is kept of a layer is its input
-                n = tokens.shape[1] // _env_block(
-                    tokens.shape[1], tokens.shape[0], s.block_tokens
-                )
+                T, B = tokens.shape
+                n = B // _env_block(B, T, s.block_tokens)
+                # where the rollout's decode steps of B tokens compute only
+                # the experts some token chose, count what they skip
+                steps = T if kind.endswith("+moe") and moe.skips_idle(
+                    B, s.top_k, s.num_experts) else None
                 h, state, counted = jax.lax.map(
                     jax.checkpoint(
-                        lambda a, p=p, kind=kind: self._layer(p, kind, *a)
+                        lambda a, p=p, kind=kind, steps=steps: self._layer(
+                            p, kind, *a, steps=steps)
                     ),
                     (_to_blocks(h, 1, n),
                      jax.tree.map(lambda c: _to_blocks(c, 0, n), state),
@@ -439,6 +454,11 @@ class SeqPolicyBase:
             # update's blocks took the dense side to do it
             aux["moe_local_assignments"] = jnp.sum(loads)
             aux["moe_dense_blocks"] = sum(c["dense"] for c in counters if "dense" in c)
+        hits = [c["step_hits"] for c in counters if "step_hits" in c]
+        if hits:
+            # the share of (expert layer, held expert, step) that no env's
+            # token chose: what a decode step of the rollout skips
+            aux["moe_step_idle_share"] = jnp.mean((jnp.stack(hits) == 0).astype(F32))
         attended = [c["rows_attended"] for c in counters if "rows_attended" in c]
         if attended:  # mean rows a query attended, over the attention layers
             aux["gqa_rows_attended"] = sum(attended) / (len(attended) * T * B)

@@ -214,7 +214,7 @@ class _ProcessRecord:
         self._kda_sites = dict.fromkeys(  # guarded-by: _lock
             ("step", "step_kernel", "chunk", "pair", "pair_kernel"), 0)
         self._moe_sites = dict.fromkeys(  # guarded-by: _lock
-            ("grouped", "gathered", "dense"), 0)
+            ("grouped", "gathered", "dense", "dense_skip"), 0)
         self._gqa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._dsa_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
         self._mla_sites = {"step": 0, "step_kernel": 0}  # guarded-by: _lock
@@ -317,9 +317,9 @@ def process_record() -> dict[str, Any]:
     duration_s)], "dropped": n, "pool_sites": {"kernel": n, "fallback":
     n}, "kda_sites": {"step": n, "step_kernel": n, "chunk": n, "pair": n,
     "pair_kernel": n}, "moe_sites": {"grouped": n, "gathered": n, "dense":
-    n}, "gqa_sites": {"step": n, "step_kernel": n}, "dsa_sites": {"step": n,
-    "step_kernel": n}, "mla_sites": {"step": n, "step_kernel": n},
-    "ssd_sites": {"step": n, "chunk": n}}``: copies,
+    n, "dense_skip": n}, "gqa_sites": {"step": n, "step_kernel": n},
+    "dsa_sites": {"step": n, "step_kernel": n}, "mla_sites": {"step": n,
+    "step_kernel": n}, "ssd_sites": {"step": n, "chunk": n}}``: copies,
     oldest first, ``perf_counter`` stamps (a compile event started at ``t_end -
     duration_s``). A compile event belongs to the phases whose ``[t0, t1]``
     hold its ``t_end``."""
@@ -352,8 +352,10 @@ def count_moe_site(path: str) -> None:
     fragment's tokens), a buffer an expert (``"gathered"``: sparse routing),
     or every held expert on every token (``"dense"``: a decode step's few
     tokens; the first two keep that side behind a ``lax.cond``, which the
-    update's ``moe_dense_blocks`` counts): called by ``ops/moe.py``, once
-    per site and program lowered, nothing on a steady call."""
+    update's ``moe_dense_blocks`` counts), or a decode step's held experts
+    that some token chose and no other (``"dense_skip"``: ``ops/moe.py``'s
+    kernel ``moe_chosen``): called by ``ops/moe.py``, once per site and
+    program lowered, nothing on a steady call."""
     _RECORD.count_moe_site(path)
 
 

@@ -30,6 +30,16 @@ is traced, says how:
   the held experts overflows it: that block is computed densely
   (``lax.cond``), the same result.
 
+A decode step's few tokens leave many held experts unchosen (16 tokens
+choosing 8 of 128 experts leave 36% of a chip's 16 idle, on average), and
+an unchosen expert's part is an exact zero: the dense side reads its
+weights for nothing. Where that expected idle share, (1 - k/E)^N, is
+``IDLE_SHARE_MIN`` or more (``skips_idle``: the rollout's 16 tokens of
+Keye's and Moonlight's cells; not Kimi's 64, nor LFM2's 128, nor any block
+of a fragment), a TPU computes only the chosen ones in the kernel ``moe_chosen``
+(``"dense_skip"``), the same sum; every other platform keeps the dense
+lines. The kernel's VJP is the dense lines'.
+
 ``process_record()["moe_sites"]`` counts, per site and program lowered,
 which side a call was built with.
 """
@@ -41,17 +51,34 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.ops.site import site_primitive
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+_LANE = 128
 # Rows of a tile of the grouped side. A tile's products read its expert's
 # weights once: at 512 rows they are bound by the MXU, not by those bytes
 # (2 x 512 flop a weight byte at bfloat16 against the v5e's 240), and the
 # padding to whole tiles is half a tile an expert.
 TILE = 512
+# A decode step computes only the held experts some token chose where the
+# expected share of those no token chose is this or more: Keye's 0.356 and
+# Moonlight's 0.207 are in; Kimi's 0.131 is out, since at its shape (64
+# tokens) the kernel with no expert idle is slower than XLA's dense lines
+# (PERF.md §6), and so is LFM2's ~4e-8.
+IDLE_SHARE_MIN = 0.15
+# Columns of F a copy of the kernel takes (of the gate, the up and the down
+# projection at once): chosen on a v5e (PERF.md §6).
+F_TILE = 256
+# The rows, the result and the copies in flight must fit this; the v5e's
+# VMEM is 128 MiB and the rest is the compiler's.
+_VMEM_BLOCK_BUDGET = 64 * 1024 * 1024
+_VMEM_HEADROOM = 16 * 1024 * 1024
 
 _site_p = site_primitive("moe_site", introspect.count_moe_site)
 
@@ -102,6 +129,19 @@ def _down(act, down, dtype):
     """A product an expert: [E, n, F] x [E, F, D] -> [E, n, D] float32."""
     return jnp.einsum(
         "enf,efd->end", act.astype(dtype), down, preferred_element_type=F32)
+
+
+def _dense_lines(x, tw, gate, up, down, dtype):
+    """Every held expert on every row of ``x`` [n, D], each part times the
+    rows' weights ``tw`` [n, E] (0 where a row did not choose the expert):
+    a product an expert, then the experts' parts added one after the other
+    in held order. As ONE product with two contracted axes ("enf,efd->nd")
+    XLA tiles the sum by what else the program holds in VMEM, so a rollout
+    inside the step and the same rollout alone summed in another order and
+    sampled other tokens (PERF.md §6)."""
+    act = _expert_act(x, gate, up, dtype) * tw.T[..., None]
+    parts = _down(act, down, dtype)
+    return functools.reduce(jnp.add, list(parts))
 
 
 def held_token_weights(ids, weights, held):
@@ -284,6 +324,169 @@ def _grouped(x, ids, weights, held, starts, ends, rows, tile, gate, up, down,
     return _from_rows(y, weights, source, dest)
 
 
+# ------------------------------------------------- a decode step's chosen experts
+
+
+def idle_share(n_tokens: int, top_k: int, num_experts: int) -> float:
+    """The expected share of held experts that no token of a call chose:
+    each of ``n_tokens`` tokens chooses ``top_k`` of ``num_experts``."""
+    return (1.0 - top_k / num_experts) ** n_tokens
+
+
+def skips_idle(n_tokens: int, top_k: int, num_experts: int) -> bool:
+    """Whether a decode step of ``n_tokens`` computes only the held experts
+    some token chose (``_chosen``): where the expected idle share is
+    ``IDLE_SHARE_MIN`` or more."""
+    return idle_share(n_tokens, top_k, num_experts) >= IDLE_SHARE_MIN
+
+
+def _chosen_order(tw):
+    """The held experts some token chose (a weight not 0 in ``tw`` [n, E])
+    first, in held order, then the rest: (ids [E] int32, their count)."""
+    chosen = jnp.any(tw != 0, axis=0)
+    return (jnp.argsort(~chosen, stable=True).astype(jnp.int32),
+            jnp.sum(chosen).astype(jnp.int32))
+
+
+def _chosen_kernel(ids_ref, count_ref, x_ref, w_ref, stacked_hbm, o_ref,
+                   buf, acc_ref, sem):
+    """ids_ref [E] (SMEM) the chosen experts first, count_ref [1] their
+    count; x_ref [n, D] and w_ref [E, n, 1] (the rows' weights an expert)
+    whole in VMEM; stacked_hbm [E, 3, D, F] an expert's gate, up and down
+    (transposed) in HBM, one copy a chosen expert's F tile of the three, the
+    next tile's copy in flight while this one is computed; o_ref [n, D]
+    float32; acc_ref [n, D] float32 an expert's part over its F tiles."""
+    tile = buf.shape[-1]
+    tiles = stacked_hbm.shape[-1] // tile
+    total = count_ref[0] * tiles
+
+    def copy(j, slot):
+        cols = pl.ds(pl.multiple_of((j % tiles) * tile, tile), tile)
+        return pltpu.make_async_copy(
+            stacked_hbm.at[ids_ref[j // tiles], :, :, cols], buf.at[slot], sem.at[slot])
+
+    o_ref[...] = jnp.zeros(o_ref.shape, F32)
+
+    @pl.when(total > 0)
+    def _():
+        copy(0, 0).start()
+
+    def step(j, carry):
+        slot, f = j % 2, j % tiles
+
+        @pl.when(j + 1 < total)
+        def _():
+            copy(j + 1, 1 - slot).start()
+
+        copy(j, slot).wait()
+
+        @pl.when(f == 0)
+        def _():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+        x = x_ref[...]
+        a = jnp.dot(x, buf[slot, 0], preferred_element_type=F32)
+        b = jnp.dot(x, buf[slot, 1], preferred_element_type=F32)
+        h = (jax.nn.silu(a) * b * w_ref[ids_ref[j // tiles]]).astype(x.dtype)
+        acc_ref[...] += lax.dot_general(
+            h, buf[slot, 2], (((1,), (1,)), ((), ())), preferred_element_type=F32)
+
+        @pl.when(f == tiles - 1)
+        def _():
+            o_ref[...] += acc_ref[...]
+
+        return carry
+
+    lax.fori_loop(0, total, step, 0)
+
+
+def _f_tile(F: int) -> int:
+    """Columns of an expert's F a copy takes: F where it is ``F_TILE`` or
+    less, else the largest divisor of F in whole multiples of 128 up to it."""
+    if F <= F_TILE:
+        return F
+    return max((t for t in range(_LANE, F_TILE + 1, _LANE) if F % t == 0), default=F)
+
+
+def _kernel_vmem(n, D, F, dtype) -> int:
+    """The call's VMEM: the rows, their weights an expert (lanes padded to a
+    tile), two slots of the three weights' tiles; the result and the
+    expert's part, float32."""
+    size = jnp.dtype(dtype).itemsize
+    return n * D * size + n * _LANE * 4 + 2 * 3 * D * _f_tile(F) * size + 2 * n * D * 4
+
+
+def _kernel_fits(x_shape, w_shape, dtype) -> bool:
+    """What the kernel asks of the static shapes (the platform is asked when
+    the program is lowered): weights in bfloat16 or float32, D and a copy's
+    columns in whole lane tiles, the call inside the VMEM budget."""
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
+        return False
+    (n, D), F = x_shape, w_shape[-1]
+    return (D % _LANE == 0 and _f_tile(F) % _LANE == 0
+            and _kernel_vmem(n, D, F, dtype) <= _VMEM_BLOCK_BUDGET)
+
+
+def _chosen_call(x, tw, gate, up, down, dtype, share, interpret=False):
+    """One ``pallas_call`` named ``moe_chosen`` and no grid: the rows and
+    the result whole in VMEM, the weights in HBM as ONE operand [E, 3, D,
+    F] (gate, up, down transposed; a loop-invariant stack, which XLA forms
+    once outside the rollout's loop). XLA's memory-space assignment
+    prefetches an operand that fits VMEM whole across the token loop, pin
+    or no pin to HBM, and a prefetch reads every expert: the stack of three
+    does not fit. ``share``: the share of the held experts' bytes a call is
+    expected to read, for the compiler's estimate. Outputs declare the
+    inputs' varying mesh axes, as in ``ops/kda.py``."""
+    n, D = x.shape
+    E, _, F = gate.shape
+    tile = _f_tile(F)
+    order, count = _chosen_order(tw)
+    stacked = jnp.stack([gate, up, jnp.swapaxes(down, 1, 2)], axis=1)
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (x, tw, stacked)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    size = jnp.dtype(dtype).itemsize
+    return pl.pallas_call(
+        _chosen_kernel,
+        name="moe_chosen",
+        in_specs=[smem, smem, whole, whole, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=whole,
+        out_shape=jax.ShapeDtypeStruct((n, D), F32, vma=vma),
+        scratch_shapes=[
+            pltpu.VMEM((2, 3, D, tile), dtype), pltpu.VMEM((n, D), F32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_kernel_vmem(n, D, F, dtype) + _VMEM_HEADROOM),
+        cost_estimate=pl.CostEstimate(
+            flops=int(6 * n * D * F * E * share),
+            transcendentals=int(n * F * E * share),
+            bytes_accessed=int(3 * D * F * E * size * share)),
+        interpret=interpret,
+    )(order, count.reshape(1), x.astype(dtype), tw.T[..., None].astype(F32),
+      stacked)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _chosen(x, tw, gate, up, down, dtype, share, interpret=False):
+    """The kernel, with the dense lines' VJP (the learner differentiates
+    the fragment form; only its bootstrap token comes by here)."""
+    return _chosen_call(x, tw, gate, up, down, dtype, share, interpret)
+
+
+def _chosen_fwd(x, tw, gate, up, down, dtype, share, interpret=False):
+    return (_chosen_call(x, tw, gate, up, down, dtype, share, interpret),
+            (x, tw, gate, up, down))
+
+
+def _chosen_bwd(dtype, share, interpret, residuals, cotangent):
+    _, vjp = jax.vjp(lambda *a: _dense_lines(*a, dtype), *residuals)
+    return vjp(cotangent)
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
 def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
                  tile: int | None = None):
     """sum over held experts of ``w E(x)``: [N, D] float32, the number of
@@ -300,16 +503,6 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
     # cast once, outside the branches below
     gate, up, down = (w.astype(dtype) for w in (gate, up, down))
 
-    def dense_block(x, tw):
-        # a product an expert, then the experts' parts added one after the
-        # other. As ONE product with two contracted axes ("enf,efd->nd")
-        # XLA tiles the sum by what else the program holds in VMEM, so a
-        # rollout inside the step and the same rollout alone summed in
-        # another order and sampled other tokens (PERF.md, PR 30)
-        act = _expert_act(x, gate, up, dtype) * tw.T[..., None]
-        parts = _down(act, down, dtype)
-        return functools.reduce(jnp.add, list(parts))
-
     # Each side under a scope of the name ``moe_sites`` counts it by, around
     # the branch function: its forward, rematerialised and backward ops keep
     # it, on whichever side of a ``lax.cond`` they are built.
@@ -320,9 +513,9 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
         # fragment's size [E, N, F] is never whole
         b = math.gcd(n_tokens, 2048)
         if b == n_tokens:
-            return dense_block(x, tw)
+            return _dense_lines(x, tw, gate, up, down, dtype)
         out = jax.lax.map(
-            jax.checkpoint(lambda args: dense_block(*args)),
+            jax.checkpoint(lambda args: _dense_lines(*args, gate, up, down, dtype)),
             (x.reshape(-1, b, width), tw.reshape(-1, b, len(held))),
         )
         return out.reshape(n_tokens, width)
@@ -365,7 +558,22 @@ def held_experts(x, ids, weights, held, num_experts, gate, up, down, dtype,
 
             out = jax.lax.cond(fits, grouped, dense, None)
         else:  # a decode step's few tokens
-            x = _site_p.bind(x, path="dense")
             fits = jnp.zeros((), bool)
-            out = dense(None)
+            k = ids.shape[1]
+            # only the held experts some token chose, where many are
+            # expected idle
+            if skips_idle(n_tokens, k, num_experts) and _kernel_fits(
+                    x.shape, gate.shape, dtype):
+                share = 1.0 - idle_share(n_tokens, k, num_experts)
+                with jax.named_scope("moe_dense"):
+                    out = lax.platform_dependent(
+                        x, tw, gate, up, down,
+                        tpu=lambda x, *a: _chosen(
+                            _site_p.bind(x, path="dense_skip"), *a, dtype, share),
+                        default=lambda x, *a: _dense_lines(
+                            _site_p.bind(x, path="dense"), *a, dtype),
+                    )
+            else:
+                x = _site_p.bind(x, path="dense")
+                out = dense(None)
     return out, load, ~fits

@@ -202,6 +202,10 @@ QUICK: dict[str, object] = {
     # interpreter against the plain lines, every edge of a chunk, rows beyond
     # len, the VJP, the choice of form and its counter, models/mla.py step on it.
     "test_latent.py": "all",
+    # The expert layer's decode side (ops/moe.py): the chosen experts'
+    # kernel under the Pallas interpreter against the dense lines, its VJP,
+    # the rule at each MoE cell's shape, moe_step_idle_share by hand.
+    "test_moe.py": "all",
     # The Granite 4.0-H sequence policy (Mamba-2 mixers on ops/ssd.py, one
     # NoPE attention layer, muP multipliers, a tied head) against its plain
     # reference at the tiny preset: forms, carry, the chunked scan against

@@ -339,16 +339,18 @@ def test_the_step_names_the_scopes_a_profile_reads():
 
 # (g) the trunk's multipliers, the tied head and the skipped expert counters
 # left the other sequence policies' programs as they were: the lowered text
-# of each one's two forms at its tiny preset, before this model was added
+# of each one's two forms at its tiny preset, before this model was added;
+# the fragments' since the fragment form counts ``moe_step_idle_share``
+# (2 tokens a step choosing 2 of 8 experts engage ``ops/moe.py skips_idle``)
 BEFORE = {
     "kimi_linear_tiny.step": "104b034a2d989a2e09b2a0a4c1caf64369b1fdbd82851cfa79711d312e5ab1c8",
-    "kimi_linear_tiny.fragment": "542744868530cbea7394466e2f9caba38adc1796f1542d793088a804bc930375",
+    "kimi_linear_tiny.fragment": "f1b754323cc134c6ed337a86568eb8ea51be8dba444570008814dfe2d05679ef",
     "lfm2_moe_tiny.step": "5789b23e8401d8aef106c5925749ef7872863c01788b0816246bb716468c3172",
-    "lfm2_moe_tiny.fragment": "d6298ba0c375e87e4febf8e34e59e30a91c4a20080eeb7ed31f4391538a6e00a",
+    "lfm2_moe_tiny.fragment": "2d20da6defdc3a3469c57df9ce690b4c63c88afc06f01354484320989c6c6009",
     "keye_moe_tiny.step": "1daf3b4dca5f70d43ee511e31c389753c80eca6c54701893b378268d4ad75f81",
-    "keye_moe_tiny.fragment": "6eae1cf89fc0aea720e2341216bba19ddb5699e20a9e55699c2d41f4685f2268",
+    "keye_moe_tiny.fragment": "bb23a75a39650a3d0d7d76a7c1797fb53c55304dafe75aa5e3527d2ebc0a634d",
     "moonlight_tiny.step": "36571948f922d327f34b76535f734797be08ed9e85ed5ead505dd47f5e3b3e53",
-    "moonlight_tiny.fragment": "35ac1d8aa7a21e5b54730a2697efa584058483b2a0f99c9f5b27388fad58d266",
+    "moonlight_tiny.fragment": "751934849243ae899294e5ca055d397255f5130f1c42cf53948df3219017f087",
 }
 
 

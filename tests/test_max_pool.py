@@ -445,3 +445,48 @@ def test_the_one_token_latent_attention_compiles_to_one_kernel_that_reads_the_ca
     assert_the_cache_is_left_in_place(body, rf"bf16\[{B},8192,576\]")
     # rows beyond len stay in HBM: the heads' scores over the capacity are gone
     assert not re.findall(rf"f32\[{B},16,8192\]", text)
+
+
+def test_the_expert_layers_decode_step_compiles_to_one_kernel_over_the_chosen_experts(
+        one_chip):
+    """``keye_moe_rl``'s expert layer (16 of 128 experts held, D 2,048, F
+    768, float32 weights cast to bfloat16 as the policy's are), a scan of
+    decode steps of 16 tokens as the rollout runs them: ``ops/moe.py
+    held_experts`` takes the Mosaic kernel ``moe_chosen`` under
+    ``/moe_dense/``, counted ``"dense_skip"`` by ``moe_sites``; its weights
+    come in as one stacked operand formed outside the loop, and in the
+    loop's body nothing prefetches, converts or stacks an expert's weights
+    (XLA prefetches an operand that fits VMEM whole: every expert)."""
+    from asyncrl_tpu.ops import moe
+
+    T, N, D, F, E, held = 4, 16, 2048, 768, 128, tuple(range(16))
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    args = (sds(T, N, D), sds(D, E), sds(len(held), D, F), sds(len(held), D, F),
+            sds(len(held), F, D))
+
+    def rollout(xs, router, gate, up, down):
+        def step(carry, x):
+            ids, weights = moe.route(x, router, None, 8, 1.0)
+            y, _, _ = moe.held_experts(x, ids, weights, held, E, gate, up, down,
+                                       jnp.bfloat16)
+            return carry + y, None
+        return jax.lax.scan(step, jnp.zeros((N, D)), xs)[0]
+
+    def moe_sites():
+        return introspect.process_record()["moe_sites"]
+
+    before = moe_sites()
+    text = jax.jit(rollout).lower(*args).compile().as_text()
+    assert {k: v - before[k] for k, v in moe_sites().items()} == {
+        "grouped": 0, "gathered": 0, "dense": 0, "dense_skip": 1}
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*/moe_dense/[^"]*moe_chosen/pallas_call', calls[0])
+    (body,) = [c for c in re.split(r"\n\n", text) if re.search(r"%moe_chosen\S* = ", c)]
+    weights = (rf"\[16,{D},{F}\]", rf"\[16,{F},{D}\]", rf"\[16,3,{D},{F}\]")
+    for line in body.split("\n"):
+        if re.search(r"(slice|copy)-start\(|convert\(|fusion\(", line):
+            result = line.split("=", 2)[1][:48]
+            assert not any(re.search(w, result) for w in weights), line
+    # no expert's activations for every held expert: the dense lines are gone
+    assert not re.findall(rf"f32\[16,{N},{F}\]", text)
