@@ -46,6 +46,11 @@ from asyncrl_tpu.parallel.mesh import (
     dp_size,
     shard_map,
 )
+from asyncrl_tpu.parallel.timeshard import (
+    gae_timesharded,
+    n_step_returns_timesharded,
+    vtrace_timesharded,
+)
 from asyncrl_tpu.rollout.anakin import ActorState, actor_init, unroll
 from asyncrl_tpu.rollout.buffer import Rollout
 from asyncrl_tpu.utils.config import Config
@@ -374,9 +379,8 @@ def _forward_fragment(apply_fn, params, rollout: Rollout):
 
 
 def qlearn_bootstrap(config: Config, online_boot_q, target_boot_q):
-    """THE target-network bootstrap selection for the Q-learning family
-    (shared by the unsharded and time-sharded loss paths): ``max_a
-    Q_target``, or the double-Q selection — argmax under the ONLINE net,
+    """THE target-network bootstrap selection for the Q-learning family:
+    ``max_a Q_target``, or the double-Q selection — argmax under the ONLINE net,
     evaluated under the target — to damp the max bias."""
     target_boot_q = jax.lax.stop_gradient(target_boot_q)
     if config.double_q:
@@ -405,10 +409,28 @@ def entropy_coef_at(config: Config, update_step) -> jax.Array | float:
     )
 
 
+def _gae(config: Config, rewards, discounts, values_t, bootstrap_value,
+         time_axis: str | None):
+    """GAE under the pre-update values: the local scan, or the distributed
+    one over ``time_axis``."""
+    values_t, bootstrap_value = jax.lax.stop_gradient(
+        (values_t, bootstrap_value)
+    )
+    if time_axis is None:
+        return gae(
+            rewards, discounts, values_t, bootstrap_value, config.gae_lambda,
+            scan_impl=config.scan_impl, fused=config.fused_scan,
+        )
+    return gae_timesharded(
+        rewards, discounts, values_t, bootstrap_value, config.gae_lambda,
+        axis_name=time_axis,
+    )
+
+
 def _algo_loss(
     config: Config, apply_fn, params, rollout: Rollout,
     axis_name: str | None = None, dist=None, target_params=None,
-    entropy_coef=None,
+    entropy_coef=None, time_axis: str | None = None,
 ):
     """Forward the learner net over [T+1, B] obs and apply the configured
     algorithm's loss. Returns (loss, metrics). ``axis_name`` is the dp mesh
@@ -417,21 +439,42 @@ def _algo_loss(
     policy head (ops.distributions). ``target_params`` is the Q-learning
     family's target network (required for algo='qlearn', unused otherwise).
     ``entropy_coef`` overrides config.entropy_coef (the annealed traced
-    value, entropy_coef_at); None = the constant."""
+    value, entropy_coef_at); None = the constant.
+
+    ``time_axis`` (the host learner on an sp mesh, SURVEY.md §5.7): every
+    input is the LOCAL [T_local, B] segment, the reverse recurrences run as
+    the distributed scans of ``parallel/timeshard.py`` and reach the losses
+    precomputed, and ``axis_name`` spans the time axis too."""
     if entropy_coef is None:
         entropy_coef = config.entropy_coef
-    fragment = fragment_form(apply_fn)
-    if fragment is not None and config.algo != "qlearn":
-        logits_t, values, aux = _forward_evaluated(
-            fragment, apply_fn, params, rollout
-        )
-        dist = Evaluated()
+    if time_axis is not None:
+        # No fragment form: its whole-T recurrence has no sharded form.
+        # ``bootstrap_obs`` is replicated over the time axis: every shard
+        # computes the (tiny) bootstrap forward, only the last one's is used.
+        logits_t, values_t = apply_fn(params, rollout.obs)
+        boot_logits, bootstrap_value = apply_fn(params, rollout.bootstrap_obs)
+        aux = {}
     else:
-        logits, values = _forward_fragment(apply_fn, params, rollout)
-        logits_t, aux = logits[:-1], {}
-    values_t = values[:-1]
-    bootstrap_value = values[-1]
+        fragment = fragment_form(apply_fn)
+        if fragment is not None and config.algo != "qlearn":
+            logits_t, values, aux = _forward_evaluated(
+                fragment, apply_fn, params, rollout
+            )
+            dist = Evaluated()
+        else:
+            logits, values = _forward_fragment(apply_fn, params, rollout)
+            logits_t, aux = logits[:-1], {}
+        values_t = values[:-1]
+        bootstrap_value = values[-1]
     discounts = rollout.discounts(config.gamma)
+
+    def n_step_returns_sharded(boot):
+        # None: the loss runs its own local recurrence
+        if time_axis is None:
+            return None
+        return n_step_returns_timesharded(
+            rollout.rewards, discounts, boot, axis_name=time_axis
+        )
 
     def with_aux(loss_and_metrics):
         loss, metrics = loss_and_metrics
@@ -459,39 +502,59 @@ def _algo_loss(
             q_target = _forward_fragment(
                 apply_fn, target_params, rollout
             )[0][-1]
-        boot = qlearn_bootstrap(config, logits[-1], q_target)
+        boot = qlearn_bootstrap(
+            config, logits[-1] if time_axis is None else boot_logits,
+            q_target,
+        )
         return qlearn_loss(
             logits_t, rollout.actions, rollout.rewards, discounts, boot,
             scan_impl=config.scan_impl, fused_scan=config.fused_scan,
+            returns=n_step_returns_sharded(boot),
             huber_delta=config.huber_delta,
         )
     if config.algo == "a3c":
+        boot = jax.lax.stop_gradient(bootstrap_value)
         return with_aux(a3c_loss(
             logits_t, values_t, rollout.actions, rollout.rewards, discounts,
-            jax.lax.stop_gradient(bootstrap_value),
-            value_coef=config.value_coef, entropy_coef=entropy_coef,
+            boot, value_coef=config.value_coef, entropy_coef=entropy_coef,
             dist=dist, scan_impl=config.scan_impl,
             fused_scan=config.fused_scan,
+            returns=n_step_returns_sharded(boot),
             diagnostics=config.introspect,
         ))
     if config.algo == "impala":
+        boot = jax.lax.stop_gradient(bootstrap_value)
+        vt = None
+        if time_axis is not None:
+            vt = vtrace_timesharded(
+                rollout.behaviour_logp, dist.logp(logits_t, rollout.actions),
+                rollout.rewards, discounts, jax.lax.stop_gradient(values_t),
+                boot, rho_clip=config.vtrace_rho_clip,
+                c_clip=config.vtrace_c_clip, axis_name=time_axis,
+            )
+            # The clip fractions come back already pmean'd over the time
+            # axis (sp-invariant); re-mark them sp-varying so the caller's
+            # uniform pmean over (dp axes + sp) is legal under vma tracking.
+            rho_frac, c_frac = jax.lax.pcast(
+                (vt.rho_clip_frac, vt.c_clip_frac), time_axis, to="varying"
+            )
+            vt = vt._replace(rho_clip_frac=rho_frac, c_clip_frac=c_frac)
         return with_aux(impala_loss(
             logits_t, values_t, rollout.actions, rollout.behaviour_logp,
-            rollout.rewards, discounts, jax.lax.stop_gradient(bootstrap_value),
+            rollout.rewards, discounts, boot,
             value_coef=config.value_coef, entropy_coef=entropy_coef,
             rho_clip=config.vtrace_rho_clip, c_clip=config.vtrace_c_clip,
             dist=dist, scan_impl=config.scan_impl,
-            fused_scan=config.fused_scan,
+            fused_scan=config.fused_scan, vtrace_out=vt,
             diagnostics=config.introspect,
         ))
     if config.algo == "ppo":
         # Single-pass PPO over the fresh fragment (used when
         # ppo_epochs == ppo_minibatches == 1; the multi-epoch minibatched
         # path is _ppo_multipass below).
-        adv = gae(
-            rollout.rewards, discounts, jax.lax.stop_gradient(values_t),
-            jax.lax.stop_gradient(bootstrap_value), config.gae_lambda,
-            scan_impl=config.scan_impl, fused=config.fused_scan,
+        adv = _gae(
+            config, rollout.rewards, discounts, values_t, bootstrap_value,
+            time_axis,
         )
         return with_aux(ppo_loss(
             logits_t, values_t, rollout.actions, rollout.behaviour_logp,
@@ -533,21 +596,17 @@ def _ppo_multipass(
     structure the behaviour policy generated. Feed-forward keeps the flat
     [T*B] sample shuffle (strictly more decorrelated, and cheaper).
 
-    ``time_axis`` (host-fragment learner on an sp mesh): the fragment's T
-    dim is sequence-parallel, so GAE runs as the two-level distributed
-    reverse scan and every per-sample quantity is a LOCAL [T_local, B]
-    slice. Minibatching needs nothing else: PPO's per-sample loss has no
-    cross-time coupling (the only time recursion is the one-shot GAE), so
-    each (dp, sp) shard shuffles its local samples independently — the
-    global minibatch is time-stratified, the same decorrelation argument
-    as the dp-local shuffle above. ``axes`` must then be the FULL reduce
-    set (dp axes + time axis), making the loss scaling / advantage moments
-    / shuffle-key folding span the time shards like any other data axis.
-    Recurrent cores stay excluded from sp meshes (rollout_learner's
-    eager check; docs/ARCHITECTURE.md).
+    ``time_axis`` (as in ``_algo_loss``): GAE runs as the distributed scan
+    and every per-sample quantity is a LOCAL [T_local, B] slice. PPO's
+    per-sample loss has no cross-time coupling, so each (dp, sp) shard
+    shuffles its local samples independently (time-stratified global
+    minibatches); ``axes`` is then the FULL reduce set, dp axes + time.
     """
     fragment = fragment_form(apply_fn)
-    if time_axis is None:
+    if time_axis is not None:
+        _, values_t = apply_fn(params, rollout.obs)
+        _, bootstrap_value = apply_fn(params, rollout.bootstrap_obs)
+    else:
         if fragment is not None:
             _, values_all, _ = _forward_evaluated(
                 fragment, apply_fn, params, rollout
@@ -555,32 +614,10 @@ def _ppo_multipass(
         else:
             _, values_all = _forward_fragment(apply_fn, params, rollout)
         values_t, bootstrap_value = values_all[:-1], values_all[-1]
-        adv = gae(
-            rollout.rewards,
-            rollout.discounts(config.gamma),
-            jax.lax.stop_gradient(values_t),
-            jax.lax.stop_gradient(bootstrap_value),
-            config.gae_lambda,
-            scan_impl=config.scan_impl,
-            fused=config.fused_scan,
-        )
-    else:
-        from asyncrl_tpu.parallel.timeshard import gae_timesharded
-
-        # ``bootstrap_obs`` is replicated over the time axis (same calling
-        # contract as rollout_learner._algo_loss_timesharded): every shard
-        # computes the tiny bootstrap forward, the distributed scan
-        # consumes it on the last shard only.
-        _, values_t = apply_fn(params, rollout.obs)
-        _, bootstrap_value = apply_fn(params, rollout.bootstrap_obs)
-        adv = gae_timesharded(
-            rollout.rewards,
-            rollout.discounts(config.gamma),
-            jax.lax.stop_gradient(values_t),
-            jax.lax.stop_gradient(bootstrap_value),
-            config.gae_lambda,
-            axis_name=time_axis,
-        )
+    adv = _gae(
+        config, rollout.rewards, rollout.discounts(config.gamma), values_t,
+        bootstrap_value, time_axis,
+    )
 
     T, B = rollout.actions.shape[:2]
     recurrent = rollout.init_core is not None
@@ -801,21 +838,27 @@ def init_params(model, env: Environment, pkey: jax.Array):
     return model.init(pkey, dummy_obs)
 
 
-def fuse_updates(body: Callable, updates_per_call: int) -> Callable:
+def fuse_updates(
+    body: Callable, updates_per_call: int, *, stacked: bool = False
+) -> Callable:
     """Fuse K sequential train-step updates into ONE XLA program via
     ``lax.scan`` — zero host dispatch between them (the amortization that
     matters when a call's dispatch outweighs its compute; how much that is
-    on this runtime is unmeasured, ROADMAP A5). Metrics leaves stack to
-    [K].
-
-    Shared by Learner (single-run) and PopulationTrainer (vmapped members —
-    VERDICT r2 Next #4): extra positional args (e.g. the member seed) pass
-    through to every fused step unchanged.
-    """
-    if updates_per_call <= 1:
+    on this runtime is unmeasured: no cell sets ``updates_per_call``).
+    Metrics leaves stack to [K]. Extra positional args (the population's
+    member seed) pass to every fused step unchanged; with ``stacked`` the
+    one argument after the state is a [K, ...] stack (the host learner's
+    queued fragments), and update k consumes the k-th."""
+    if updates_per_call < 1:
+        raise ValueError(
+            f"updates_per_call={updates_per_call} must be >= 1"
+        )
+    if updates_per_call == 1:
         return body
 
-    def multi_step(state: TrainState, *args):
+    def multi_step(state, *args):
+        if stacked:
+            return jax.lax.scan(body, state, *args)
         return jax.lax.scan(
             lambda s, _: body(s, *args), state, None,
             length=updates_per_call,
@@ -885,10 +928,8 @@ def accumulate_grads(scaled_loss, params, rollout, n_accum: int):
     (equal chunks + the caller's 1/n_accum loss scaling). Losses/metrics
     are per-env means, so the chunk mean recovers the batch mean. Chunk
     count is identical on every shard, so per-chunk collectives (e.g.
-    time-sharded V-trace psums) stay in lockstep across the mesh.
-
-    Shared by the Anakin train step and the host-fragment RolloutLearner —
-    the two must never diverge. Returns ``(grads, loss, metrics)``."""
+    time-sharded V-trace psums) stay in lockstep across the mesh. Returns
+    ``(grads, loss, metrics)``."""
 
     def accum_body(g_acc, frag):
         (_, aux), g = jax.value_and_grad(scaled_loss, has_aux=True)(
@@ -908,6 +949,99 @@ def accumulate_grads(scaled_loss, params, rollout, n_accum: int):
     )
 
 
+def scale_rewards(config: Config, rollout: Rollout, ret_stats) -> Rollout:
+    """Reward normalization: scale the fragment's rewards by the PRE-update
+    return std (the mean is NOT subtracted — that would change the MDP)."""
+    if not config.normalize_returns:
+        return rollout
+    ret_var = ret_stats.m2 / ret_stats.count
+    return rollout.replace(
+        rewards=rollout.rewards * jax.lax.rsqrt(jnp.maximum(ret_var, 1e-8))
+    )
+
+
+def fold_stats(obs_stats, ret_stats, rollout: Rollout, axes):
+    """Fold the fragment's obs and discounted returns into the running
+    normalization stats (ops/normalize.py) — after the update, whose
+    forwards all saw the pre-update stats. None stays None."""
+    if obs_stats is not None:
+        with jax.named_scope("obs_stats"):
+            obs_stats = update_stats(obs_stats, rollout.obs, axes)
+    if ret_stats is not None:
+        ret_stats = update_stats(ret_stats, rollout.disc_returns, axes)
+    return obs_stats, ret_stats
+
+
+def stale_copy(step, period, new, old):
+    """The stale-copy select: ``new`` on every ``period``-th update, ``old``
+    on the others (IMPALA's behaviour copy, the Q target network, the
+    IMPACT anchor, the self-play rival)."""
+    refresh = (step % period) == 0
+    return jax.tree.map(lambda n, o: jnp.where(refresh, n, o), new, old)
+
+
+def fragment_update(
+    config: Config, apply_fn, optimizer, dist, params, opt_state,
+    rollout: Rollout, update_step, target_params,
+    axes: tuple[str, ...], time_axis: str | None,
+    *, member_seed: jax.Array | None = None,
+):
+    """The update half both learners run on a fragment: PPO's multipass,
+    or one (``grad_accum``-chunked) gradient step of the configured loss,
+    the cross-shard gradient sum and the optimizer. ``axes``: every axis
+    the fragment is sharded over (the time axis too on an sp mesh; () for
+    a population member). ``target_params``: the Q-learning target. Returns
+    ``(params, opt_state, loss, grad_norm, metrics)``, loss and metrics
+    averaged over ``axes``."""
+    if config.algo == "ppo" and (
+        config.ppo_epochs > 1 or config.ppo_minibatches > 1
+    ):
+        with jax.named_scope("ppo_multipass"):
+            params, opt_state, loss, grad_norm, metrics = _ppo_multipass(
+                config, apply_fn, optimizer, dist, params, opt_state,
+                rollout, update_step, axes=axes, member_seed=member_seed,
+                time_axis=time_axis,
+            )
+    else:
+        # shard_map autodiff semantics (jax>=0.8 vma tracking): the
+        # gradient of a REPLICATED input (params) w.r.t. a device-varying
+        # loss is automatically psum'd across the mesh axes during
+        # transposition. So we scale the per-shard loss by 1/axis_size —
+        # the implicit psum of local-mean gradients then yields exactly
+        # the global-batch-mean gradient, with no explicit pmean(grads)
+        # (which would double-count: verified 8x inflation on the
+        # 8-device CPU mesh, tests/test_learner).
+        n_accum = max(config.grad_accum, 1)
+
+        def scaled_loss(p, frag):
+            loss, metrics = _algo_loss(
+                config, apply_fn, p, frag,
+                axis_name=axes or None, dist=dist,
+                target_params=target_params,
+                entropy_coef=entropy_coef_at(config, update_step),
+                time_axis=time_axis,
+            )
+            return loss / (_axis_size(axes) * n_accum), (loss, metrics)
+
+        if n_accum == 1:
+            with jax.named_scope("loss_and_grad"):
+                (_, (loss, metrics)), grads = jax.value_and_grad(
+                    scaled_loss, has_aux=True
+                )(params, rollout)
+        else:
+            with jax.named_scope("loss_and_grad_accum"):
+                grads, loss, metrics = accumulate_grads(
+                    scaled_loss, params, rollout, n_accum
+                )
+        grads = reduce_grads(grads, axes, config)
+        with jax.named_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+    metrics = _pmean(metrics, axes)
+    return params, opt_state, _pmean(loss, axes), grad_norm, metrics
+
+
 def make_train_step(
     config: Config,
     env: Environment,
@@ -925,12 +1059,6 @@ def make_train_step(
     from asyncrl_tpu.ops import distributions
 
     dist = distributions.for_config(config, env.spec)
-
-    # Static choice: PPO with epochs/minibatches > 1 takes the multipass
-    # update path; everything else is one fused gradient step.
-    ppo_multipass = config.algo == "ppo" and (
-        config.ppo_epochs > 1 or config.ppo_minibatches > 1
-    )
     qlearn = config.algo == "qlearn"
 
     if axes is None:
@@ -967,63 +1095,12 @@ def make_train_step(
                     state.opponent_params if config.selfplay else None
                 ),
             )
-        if config.normalize_returns:
-            # Scale this fragment's rewards by the PRE-update return std
-            # (mean is NOT subtracted — shifting rewards changes the MDP);
-            # fold the fragment's discounted-return stream in afterwards.
-            ret_var = state.ret_stats.m2 / state.ret_stats.count
-            rollout = rollout.replace(
-                rewards=rollout.rewards
-                * jax.lax.rsqrt(jnp.maximum(ret_var, 1e-8))
-            )
-
-        if ppo_multipass:
-            with jax.named_scope("ppo_multipass"):
-                params, opt_state, loss, grad_norm, metrics = _ppo_multipass(
-                    config, napply, optimizer, dist,
-                    state.params, state.opt_state, rollout, state.update_step,
-                    axes=axes, member_seed=member_seed,
-                )
-        else:
-            # shard_map autodiff semantics (jax>=0.8 vma tracking): the
-            # gradient of a REPLICATED input (params) w.r.t. a device-varying
-            # loss is automatically psum'd across the mesh axis during
-            # transposition. So we scale the per-shard loss by 1/axis_size —
-            # the implicit psum of local-mean gradients then yields exactly
-            # the global-batch-mean gradient, with no explicit pmean(grads)
-            # (which would double-count: verified 8x inflation on the
-            # 8-device CPU mesh, tests/test_learner).
-            n_accum = max(config.grad_accum, 1)
-
-            def scaled_loss(p, frag):
-                loss, metrics = _algo_loss(
-                    config, napply, p, frag,
-                    axis_name=axes or None, dist=dist,
-                    target_params=state.actor_params,
-                    entropy_coef=entropy_coef_at(config, state.update_step),
-                )
-                return loss / (_axis_size(axes) * n_accum), (loss, metrics)
-
-            if n_accum == 1:
-                with jax.named_scope("loss_and_grad"):
-                    (_, (loss, metrics)), grads = jax.value_and_grad(
-                        scaled_loss, has_aux=True
-                    )(state.params, rollout)
-            else:
-                with jax.named_scope("loss_and_grad_accum"):
-                    grads, loss, metrics = accumulate_grads(
-                        scaled_loss, state.params, rollout, n_accum
-                    )
-            grads = reduce_grads(grads, axes, config)
-            with jax.named_scope("optimizer"):
-                grad_norm = optax.global_norm(grads)
-                updates, opt_state = optimizer.update(
-                    grads, state.opt_state, state.params
-                )
-                params = optax.apply_updates(state.params, updates)
-
-        metrics = _pmean(metrics, axes)
-        loss = _pmean(loss, axes)
+        rollout = scale_rewards(config, rollout, state.ret_stats)
+        params, opt_state, loss, grad_norm, metrics = fragment_update(
+            config, napply, optimizer, dist, state.params, state.opt_state,
+            rollout, state.update_step, state.actor_params, axes, None,
+            member_seed=member_seed,
+        )
 
         step = state.update_step + 1
         # "publish": the copy of the parameters the actors see. A label and
@@ -1038,10 +1115,8 @@ def make_train_step(
                 # SAME stale copy doubles as the target network θ⁻ (and the
                 # ε-greedy behaviour net), so actor_staleness is the
                 # target-update period.
-                refresh = (step % config.actor_staleness) == 0
-                actor_params = jax.tree.map(
-                    lambda new, old: jnp.where(refresh, new, old),
-                    params, state.actor_params,
+                actor_params = stale_copy(
+                    step, config.actor_staleness, params, state.actor_params
                 )
             else:
                 # On-policy (and staleness<=1 IMPALA): actors always see the
@@ -1049,31 +1124,26 @@ def make_train_step(
                 # minimum true-IMPALA staleness.
                 actor_params = params
 
-        obs_stats = state.obs_stats
-        if obs_stats is not None:
-            with jax.named_scope("obs_stats"):
-                obs_stats = update_stats(obs_stats, rollout.obs, axes)
-        ret_stats = state.ret_stats
-        if ret_stats is not None:
-            ret_stats = update_stats(ret_stats, rollout.disc_returns, axes)
+        obs_stats, ret_stats = fold_stats(
+            state.obs_stats, state.ret_stats, rollout, axes
+        )
 
         if config.selfplay:
             # Ladder refresh: the frozen rival becomes the CURRENT policy
-            # every selfplay_refresh updates (same select pattern as the
-            # actor_params staleness refresh).
-            promote = (step % max(config.selfplay_refresh, 1)) == 0
-            opponent_params = jax.tree.map(
-                lambda new, old: jnp.where(promote, new, old),
-                params, state.opponent_params,
+            # every selfplay_refresh updates.
+            period = max(config.selfplay_refresh, 1)
+            opponent_params = stale_copy(
+                step, period, params, state.opponent_params
             )
             if actor.opp_core is not None:
                 # The rival's recurrent carry belongs to the OLD snapshot;
                 # on promotion zero it (mid-episode amnesia beats feeding
                 # the new params a foreign hidden state).
-                keep = 1.0 - promote.astype(jnp.float32)
                 actor = actor.replace(
-                    opp_core=jax.tree.map(
-                        lambda c: c * keep, actor.opp_core
+                    opp_core=stale_copy(
+                        step, period,
+                        jax.tree.map(jnp.zeros_like, actor.opp_core),
+                        actor.opp_core,
                     )
                 )
         else:
@@ -1126,10 +1196,6 @@ class Learner:
         validate_recurrent_config(config, model)
         validate_qlearn_config(config)
         validate_selfplay_config(config, env, model)
-        if config.updates_per_call < 1:
-            raise ValueError(
-                f"updates_per_call={config.updates_per_call} must be >= 1"
-            )
         dp = dp_size(mesh)
         if config.num_envs % dp:
             raise ValueError(

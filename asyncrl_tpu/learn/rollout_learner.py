@@ -5,11 +5,13 @@ program because its envs live in HBM. The Sebulba and ``cpu_async`` backends
 instead produce ``Rollout`` fragments on the host (C++ env pools / gymnasium /
 Python actor threads — SURVEY.md §7.2 M3-M4), so this learner exposes the
 other half only: ``update(state, rollout)`` — one jitted ``shard_map`` over
-the mesh that recomputes learner logits/values, applies the configured
-algorithm loss (A3C / IMPALA V-trace / PPO), all-reduces gradients over the
-``dp`` axis, and steps Adam. The rollout arrives batch-sharded (``[T, B]``
-with B split over dp), mirroring how the reference's learner consumed
-queue-batched fragments (BASELINE.json:5; SURVEY.md §3.2).
+the mesh that runs the Anakin step's own update half
+(``learner.fragment_update``: the learner forward, the configured loss, the
+gradient all-reduce, the optimizer) around what is this learner's alone: its
+target network, the IMPACT anchor and the NaN guard. The rollout arrives
+batch-sharded (``[T, B]`` with B split over dp), mirroring how the
+reference's learner consumed queue-batched fragments (BASELINE.json:5;
+SURVEY.md §3.2).
 """
 
 from __future__ import annotations
@@ -19,21 +21,19 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from asyncrl_tpu.envs.core import EnvSpec
 from asyncrl_tpu.learn.learner import (
-    _algo_loss,
-    _ppo_multipass,
-    accumulate_grads,
-    entropy_coef_at,
+    fold_stats,
+    fragment_update,
+    fuse_updates,
     fused_smap_opts,
     make_optimizer,
-    qlearn_bootstrap,
-    reduce_grads,
     resolve_scan_impl,
+    scale_rewards,
+    stale_copy,
     validate_grad_accum_config,
     validate_qlearn_config,
     validate_recurrent_config,
@@ -44,28 +44,12 @@ from asyncrl_tpu.obs import introspect
 from asyncrl_tpu.obs import spans as span_names
 from asyncrl_tpu.obs import trace
 from asyncrl_tpu.ops import distributions
-from asyncrl_tpu.ops.losses import (
-    a3c_loss,
-    impala_loss,
-    ppo_loss,
-    qlearn_loss,
-)
-from asyncrl_tpu.ops.normalize import (
-    init_stats,
-    normalizing_apply,
-    update_stats,
-)
+from asyncrl_tpu.ops.normalize import init_stats, normalizing_apply
 from asyncrl_tpu.parallel.mesh import (
     TIME_AXIS,
-    axis_size,
     dp_axes,
     dp_size,
     shard_map,
-)
-from asyncrl_tpu.parallel.timeshard import (
-    gae_timesharded,
-    n_step_returns_timesharded,
-    vtrace_timesharded,
 )
 from asyncrl_tpu.rollout.buffer import Rollout
 from asyncrl_tpu.utils.config import Config
@@ -161,93 +145,6 @@ def rollout_sharding(
     )
 
 
-def _algo_loss_timesharded(
-    config: Config, apply_fn, params, rollout: Rollout, *, reduce_axes, dist,
-    target_params=None, entropy_coef=None,
-):
-    """Time-sharded variant of ``learner._algo_loss``: runs inside shard_map
-    with the fragment's T dim sharded over ``TIME_AXIS`` (SURVEY.md §5.7).
-    Every input is the LOCAL [T_local, B_local] segment; the reverse
-    recurrences run as two-level distributed scans with one-hop ``ppermute``
-    boundary exchanges (parallel/timeshard.py). Returned loss/metrics are
-    local means — the caller pmean's them over ``reduce_axes`` (which
-    includes the time axis), and equal-sized shards make that the global
-    mean."""
-    if entropy_coef is None:
-        entropy_coef = config.entropy_coef
-    logits_t, values_t = apply_fn(params, rollout.obs)
-    # ``bootstrap_obs`` is replicated over the time axis; every shard
-    # computes the (tiny) bootstrap forward, only the last consumes it.
-    boot_logits, bootstrap_value = apply_fn(params, rollout.bootstrap_obs)
-    bootstrap_value = jax.lax.stop_gradient(bootstrap_value)
-    discounts = rollout.discounts(config.gamma)
-
-    if config.algo == "qlearn":
-        # Same construction as the unsharded branch, via the same shared
-        # pieces: online Q locally per time shard, the shared
-        # ``qlearn_bootstrap`` target selection, the distributed
-        # n-step-return solve, and the canonical ``qlearn_loss`` fed the
-        # precomputed returns (its ``returns=`` kwarg, like a3c's).
-        q_target = apply_fn(target_params, rollout.bootstrap_obs)[0]
-        boot = qlearn_bootstrap(config, boot_logits, q_target)
-        returns = n_step_returns_timesharded(
-            rollout.rewards, discounts, boot
-        )
-        return qlearn_loss(
-            logits_t, rollout.actions, rollout.rewards, discounts, boot,
-            returns=returns, huber_delta=config.huber_delta,
-        )
-    if config.algo == "a3c":
-        returns = n_step_returns_timesharded(
-            rollout.rewards, discounts, bootstrap_value
-        )
-        return a3c_loss(
-            logits_t, values_t, rollout.actions, rollout.rewards, discounts,
-            bootstrap_value, value_coef=config.value_coef,
-            entropy_coef=entropy_coef, dist=dist, returns=returns,
-            diagnostics=config.introspect,
-        )
-    if config.algo == "impala":
-        target_logp = dist.logp(logits_t, rollout.actions)
-        vt = vtrace_timesharded(
-            rollout.behaviour_logp, target_logp, rollout.rewards, discounts,
-            jax.lax.stop_gradient(values_t), bootstrap_value,
-            rho_clip=config.vtrace_rho_clip, c_clip=config.vtrace_c_clip,
-        )
-        # The clip fractions come back already pmean'd over the time axis
-        # (sp-invariant); re-mark them sp-varying so the caller's uniform
-        # pmean over (dp axes + sp) is legal under vma tracking.
-        vt = vt._replace(
-            rho_clip_frac=jax.lax.pcast(
-                vt.rho_clip_frac, TIME_AXIS, to="varying"
-            ),
-            c_clip_frac=jax.lax.pcast(
-                vt.c_clip_frac, TIME_AXIS, to="varying"
-            ),
-        )
-        return impala_loss(
-            logits_t, values_t, rollout.actions, rollout.behaviour_logp,
-            rollout.rewards, discounts, bootstrap_value,
-            value_coef=config.value_coef, entropy_coef=entropy_coef,
-            rho_clip=config.vtrace_rho_clip, c_clip=config.vtrace_c_clip,
-            dist=dist, vtrace_out=vt,
-            diagnostics=config.introspect,
-        )
-    if config.algo == "ppo":
-        adv = gae_timesharded(
-            rollout.rewards, discounts, jax.lax.stop_gradient(values_t),
-            bootstrap_value, config.gae_lambda,
-        )
-        return ppo_loss(
-            logits_t, values_t, rollout.actions, rollout.behaviour_logp,
-            adv.advantages, adv.returns, clip_eps=config.ppo_clip_eps,
-            value_coef=config.value_coef, entropy_coef=entropy_coef,
-            axis_name=reduce_axes, dist=dist,
-            diagnostics=config.introspect,
-        )
-    raise ValueError(f"unknown algo {config.algo!r} for time sharding")
-
-
 class RolloutLearner:
     """Compiled ``update(state, rollout)`` step + state lifecycle.
 
@@ -276,9 +173,6 @@ class RolloutLearner:
                 "selfplay is Anakin-only (backend='tpu'): host actor "
                 "threads have no opponent-snapshot channel"
             )
-        ppo_multipass = config.algo == "ppo" and (
-            config.ppo_epochs > 1 or config.ppo_minibatches > 1
-        )
         time_sharded = TIME_AXIS in mesh.axis_names and mesh.shape[TIME_AXIS] > 1
         if time_sharded:
             sp = mesh.shape[TIME_AXIS]
@@ -298,16 +192,10 @@ class RolloutLearner:
                     "deliberately NOT time-shardable'). Use a dp-only mesh "
                     "for core='lstm'"
                 )
-            # Multipass PPO time-shards fine (PPO's per-sample loss has no
-            # cross-time coupling; only the one-shot GAE recurses —
-            # _ppo_multipass's time_axis path). Minibatch geometry is NOT
-            # eager-checked here: this learner never knows the fragment's
-            # env batch (SebulbaTrainer feeds per-actor fragments) — the
-            # trainer runs the sp-aware eager check with the real B, and
-            # _ppo_multipass re-validates the local slice at trace time.
-            # (qlearn time-shards via n_step_returns_timesharded; its
-            # recurrent DRQN variant is excluded by the is_recurrent check
-            # above like every recurrent core.)
+            # (qlearn's DRQN is refused with every recurrent core.) PPO's
+            # minibatch geometry is not checked here: this learner never
+            # knows the fragment's env batch — the trainer checks it with
+            # the real B, and _ppo_multipass the local slice at trace time.
         config = resolve_scan_impl(config, mesh)
         self.config = config
         self.spec = spec
@@ -319,6 +207,7 @@ class RolloutLearner:
         optimizer = self.optimizer
 
         axes = dp_axes(mesh)
+        time_axis = TIME_AXIS if time_sharded else None
         # Gradient/metric reduction spans every axis the fragment is
         # sharded over: batch axes always, plus the time axis when the
         # fragment's T dim is sequence-parallel.
@@ -341,12 +230,7 @@ class RolloutLearner:
             # fold in afterwards. Reward normalization likewise scales this
             # fragment by the PRE-update return std.
             napply = normalizing_apply(apply_fn, state.obs_stats)
-            if config.normalize_returns:
-                ret_var = state.ret_stats.m2 / state.ret_stats.count
-                rollout = rollout.replace(
-                    rewards=rollout.rewards
-                    * jax.lax.rsqrt(jnp.maximum(ret_var, 1e-8))
-                )
+            rollout = scale_rewards(config, rollout, state.ret_stats)
             target_kl = None
             if replay_mode:
                 # IMPACT-style ratio anchoring: the slowly-updated
@@ -376,101 +260,38 @@ class RolloutLearner:
                         target_logp - math.log(config.replay_rho_clip),
                     )
                 )
-            if ppo_multipass:
-                # ``axes=reduce_axes``: on an sp mesh the shuffle keys,
-                # loss scaling, and advantage moments must span the time
-                # shards too (== axes on a dp-only mesh).
-                params, opt_state, loss, grad_norm, metrics = _ppo_multipass(
-                    config, napply, optimizer, dist,
-                    state.params, state.opt_state, rollout, state.update_step,
-                    axes=reduce_axes,
-                    time_axis=TIME_AXIS if time_sharded else None,
-                )
-            else:
-                # Same implicit-psum gradient scaling as the Anakin step:
-                # replicated-param grads are psum'd across every sharded
-                # axis during transposition, so local loss is scaled by
-                # 1/axis_size of ALL of them.
-                n_accum = max(config.grad_accum, 1)
-
-                def scaled_loss(p, frag):
-                    ec = entropy_coef_at(config, state.update_step)
-                    # fused_scan reaches the non-timesharded branch through
-                    # _algo_loss/config; the timesharded variants keep the
-                    # two-level lax decomposition — the fused kernel's
-                    # whole-T recurrence has no sp-sharded form, so
-                    # fused_scan applies only to an unsharded time axis.
-                    if time_sharded:
-                        loss, metrics = _algo_loss_timesharded(
-                            config, napply, p, frag,
-                            reduce_axes=reduce_axes, dist=dist,
-                            target_params=state.target_params,
-                            entropy_coef=ec,
-                        )
-                    else:
-                        loss, metrics = _algo_loss(
-                            config, napply, p, frag,
-                            axis_name=axes, dist=dist,
-                            target_params=state.target_params,
-                            entropy_coef=ec,
-                        )
-                    return (
-                        loss / (axis_size(reduce_axes) * n_accum),
-                        (loss, metrics),
-                    )
-
-                if n_accum == 1:
-                    (_, (loss, metrics)), grads = jax.value_and_grad(
-                        scaled_loss, has_aux=True
-                    )(state.params, rollout)
-                else:
-                    grads, loss, metrics = accumulate_grads(
-                        scaled_loss, state.params, rollout, n_accum
-                    )
-                grads = reduce_grads(grads, reduce_axes, config)
-                grad_norm = optax.global_norm(grads)
-                updates, opt_state = optimizer.update(
-                    grads, state.opt_state, state.params
-                )
-                params = optax.apply_updates(state.params, updates)
-
-            metrics = dict(jax.lax.pmean(metrics, reduce_axes))
-            metrics["loss"] = jax.lax.pmean(loss, reduce_axes)
+            # (``fused_scan``'s whole-T kernel has no sharded form: with a
+            # time axis the loss takes the distributed recurrences.)
+            params, opt_state, loss, grad_norm, metrics = fragment_update(
+                config, napply, optimizer, dist, state.params,
+                state.opt_state, rollout, state.update_step,
+                state.target_params, reduce_axes, time_axis,
+            )
+            metrics = dict(metrics)
+            metrics["loss"] = loss
             metrics["grad_norm"] = grad_norm
             if target_kl is not None:
                 metrics["target_kl"] = jax.lax.pmean(
                     target_kl, reduce_axes
                 )
             step = state.update_step + 1
-            if config.algo == "qlearn":
-                # Target-network refresh every actor_staleness updates
-                # (same recipe as the Anakin learner's actor_params).
-                refresh = (step % config.actor_staleness) == 0
-                target_params = jax.tree.map(
-                    lambda new, old: jnp.where(refresh, new, old),
-                    params, state.target_params,
+            target_params = state.target_params  # None subtree unless held
+            if target_params is not None:
+                # The Q target net refreshes every actor_staleness updates
+                # (the Anakin learner's actor_params recipe); the IMPACT
+                # anchor on its own period, so it is never more than
+                # target_update_period updates stale.
+                period = (
+                    config.actor_staleness
+                    if config.algo == "qlearn"
+                    else config.target_update_period
                 )
-            elif replay_mode:
-                # The IMPACT anchor refreshes on its own period — the
-                # qlearn recipe with the replay knob, so the anchor is
-                # never more than target_update_period updates stale.
-                refresh = (step % config.target_update_period) == 0
-                target_params = jax.tree.map(
-                    lambda new, old: jnp.where(refresh, new, old),
-                    params, state.target_params,
+                target_params = stale_copy(
+                    step, period, params, target_params
                 )
-            else:
-                target_params = state.target_params  # None subtree
-            obs_stats = state.obs_stats
-            if obs_stats is not None:
-                obs_stats = update_stats(
-                    obs_stats, rollout.obs, reduce_axes
-                )
-            ret_stats = state.ret_stats
-            if ret_stats is not None:
-                ret_stats = update_stats(
-                    ret_stats, rollout.disc_returns, reduce_axes
-                )
+            obs_stats, ret_stats = fold_stats(
+                state.obs_stats, state.ret_stats, rollout, reduce_axes
+            )
             new_state = LearnerState(
                 params=params,
                 opt_state=opt_state,
@@ -490,20 +311,12 @@ class RolloutLearner:
                 metrics["nonfinite_skip"] = 1.0 - finite.astype(jnp.float32)
             return new_state, metrics
 
+        # updates_per_call > 1: the trainer stacks K queued fragments
+        # [K, T, B, ...] and one dispatch applies them in arrival order —
+        # identical training semantics, one host->device round trip
+        # instead of K. Metrics come back stacked [K].
         K = config.updates_per_call
-        if K < 1:
-            raise ValueError(f"updates_per_call={K} must be >= 1")
-        if K > 1:
-            # Fuse K sequential updates into ONE dispatch: the trainer
-            # stacks K queued fragments [K, T, B, ...] and the scan applies
-            # them in arrival order — identical training semantics, one
-            # host->device round trip instead of K (the dominant cost on a
-            # high-latency device link; VERDICT.md round 1, Weak #4).
-            # Metrics come back stacked [K].
-            single_body = update_body
-
-            def update_body(state: LearnerState, stacked: Rollout):
-                return jax.lax.scan(single_body, state, stacked)
+        update_body = fuse_updates(update_body, K, stacked=True)
 
         sspec = learner_state_spec()
         # NEVER donate the STATE, regardless of config.donate_buffers: the
@@ -522,10 +335,7 @@ class RolloutLearner:
                 mesh=mesh,
                 in_specs=(
                     sspec,
-                    rollout_partition_spec(
-                        axes, TIME_AXIS if time_sharded else None,
-                        stacked=K > 1,
-                    ),
+                    rollout_partition_spec(axes, time_axis, stacked=K > 1),
                 ),
                 out_specs=(sspec, P()),
                 **fused_smap_opts(config),

@@ -84,6 +84,10 @@ QUICK: dict[str, object] = {
     "test_timeshard.py": {
         "test_vtrace_timesharded_matches_single_device",
         "test_gae_timesharded_matches_single_device",
+        # the only tests of _algo_loss's time-axis side (~16 s together)
+        "test_rollout_learner_timesharded_equals_dp_only",
+        "test_rollout_learner_timesharded_multipass_equals_dp_only",
+        "test_rollout_learner_timesharded_multipass_minibatched",
     },
     "test_qlearn.py": {"test_huber_td_loss_fixture"},
     "test_sebulba.py": {

@@ -1,12 +1,13 @@
 """Tier-1 perf smoke: the overlapped zero-copy pipeline (config.overlap_h2d)
 vs the legacy copy-and-stack path on a tiny pong_impala-shaped sebulba run.
 
-Two guarantees, one A/B:
+Two guarantees, one test each:
 - SEMANTICS: both paths produce identical losses on a fixed seed (the
   slab drain feeds the learner the same bytes in the same order).
 - PERFORMANCE: the overlapped path is not slower. Wall-clock on a shared
   1-core CI box is noisy, so the in-tree assertion keeps a generous margin
-  (a strict comparison is a benchmark cell's to make: ROADMAP A2);
+  (a strict comparison is a benchmark cell's to make, and no cell runs
+  the host path yet);
   a structural regression (overlap path serializing, slab waits on every
   fragment) still fails it.
 """
@@ -50,15 +51,9 @@ def _run(overlap: bool):
     return losses, elapsed, history
 
 
-def test_overlap_matches_legacy_losses_and_is_not_slower():
-    losses_on, t_on, hist_on = _run(overlap=True)
-    losses_off, t_off, hist_off = _run(overlap=False)
-    # Second overlap run: the FIRST measurement in a process is
-    # systematically slow (XLA/threadpool warm-up outliving the per-agent
-    # jit warm-up), so the on-first ordering above would bias against the
-    # overlap path; best-of-two removes the order effect.
-    _, t_on2, _ = _run(overlap=True)
-    t_on = min(t_on, t_on2)
+def test_overlap_matches_legacy_losses():
+    losses_on, _, hist_on = _run(overlap=True)
+    losses_off, _, hist_off = _run(overlap=False)
 
     # Identical losses, fixed seed: same fragments, same update sequence.
     assert len(losses_on) == len(losses_off) > 0
@@ -71,6 +66,18 @@ def test_overlap_matches_legacy_losses_and_is_not_slower():
         assert "h2d_bytes" in window and window["h2d_bytes"] > 0
         assert 0.0 <= window["learner_stall_frac"] <= 1.0
     assert "slab_reuse_waits" in hist_on[-1]
+
+
+def test_overlap_is_not_slower():
+    # Interleaved on, off, on, off, best of two a side: the FIRST
+    # measurement in a process is systematically slow (XLA/threadpool
+    # warm-up outliving the per-agent jit warm-up), and whatever else the
+    # machine runs drifts over seconds — alternating spreads both over the
+    # two sides instead of charging them to one.
+    times = {True: [], False: []}
+    for overlap in (True, False, True, False):
+        times[overlap].append(_run(overlap)[1])
+    t_on, t_off = min(times[True]), min(times[False])
 
     # Not slower, with CI-noise slack (see module docstring).
     assert t_on <= 1.5 * t_off, (

@@ -243,8 +243,8 @@ def _assert_sp_matches_dp(cfg, ro):
 def test_rollout_learner_timesharded_equals_dp_only(algo, devices):
     """The HOST-FRAGMENT learner on a (dp x sp) mesh must produce the same
     post-update params as on a dp-only mesh — the end-to-end check that the
-    time-sharded loss glue (rollout_learner._algo_loss_timesharded) matches
-    the unsharded path (regression: this glue was once referenced but
+    time-axis side of learner._algo_loss (the distributed recurrences)
+    matches the unsharded path (regression: this glue was once referenced but
     undefined, so any sp>1 mesh crashed with NameError at trace time)."""
     from asyncrl_tpu.envs.cartpole import CartPole
     from asyncrl_tpu.learn.rollout_learner import RolloutLearner
